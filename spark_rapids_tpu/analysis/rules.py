@@ -163,7 +163,7 @@ class UnboundedWaitRule(Rule):
 class SwallowBaseExceptionRule(Rule):
     """R4: no handler that can swallow KeyboardInterrupt/SystemExit.
 
-    The fault taxonomy (fault/errors.py) promises KI/SE are never
+    The fault classification (fault/errors.py) promises KI/SE are never
     retried or absorbed by recovery; a ``except:`` or ``except
     BaseException:`` that neither re-raises nor exits the process breaks
     that promise.  (Plain ``except Exception`` cannot catch KI/SE and is

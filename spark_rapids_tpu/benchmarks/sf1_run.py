@@ -4,7 +4,7 @@ engine AND the CPU engine from the files, verify agreement, and emit a
 timing table (the BenchUtils.runBench role,
 integration_tests/.../common/BenchUtils.scala:109-240).
 
-    python -m spark_rapids_tpu.benchmarks.sf1_run [--sf 1.0] [--out BENCH_SF1.md]
+    python -m spark_rapids_tpu.benchmarks.sf1_run [--sf 1.0] [--out sf1_report.md]
 
 Correctness: row counts must match exactly; numeric columns are
 checksummed (sums rounded to 2dp) and compared within float-agg
@@ -16,7 +16,11 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import time
+import sys
+from typing import Optional
+
+TABLE_NAMES = ("lineitem", "orders", "customer", "supplier", "nation",
+               "part", "partsupp", "region")
 
 # TPC-H SF1 row counts; the synthetic generator's own `sf` knob is
 # rows = sf * 60_000 for lineitem, so generator_sf = 100 * true_sf
@@ -29,57 +33,77 @@ def _dataset_dir(true_sf: float) -> str:
                         f"rapids_tpu_tpch_sf{true_sf:g}")
 
 
-def generate_dataset(true_sf: float, num_partitions: int = 4) -> str:
+def generate_dataset(true_sf: float, num_partitions: int = 1,
+                     seed: Optional[int] = None,
+                     root: Optional[str] = None) -> str:
     """Write the TPC-H-like tables as parquet once; returns the dir.
-    The completion marker records a schema fingerprint, so a schema or
-    scale change regenerates instead of reusing stale files (a pure
-    value-distribution change with the same columns still needs a manual
-    directory wipe)."""
+    The completion marker records a schema fingerprint, so a schema,
+    scale or seed change regenerates instead of reusing stale files (a
+    pure value-distribution change with the same columns still needs a
+    manual directory wipe).  ``seed`` offsets every table generator's
+    RandomState (None keeps the generators' own defaults); ``root``
+    places the dataset (default: a per-scale dir under the tempdir).
+    Each table is written as ``num_partitions`` files of contiguous rows
+    (tiny tables: one file per row at most), and a scan yields one
+    partition per file."""
+    from spark_rapids_tpu.batch import HostBatch
     from spark_rapids_tpu.benchmarks import datagen
     from spark_rapids_tpu.config import RapidsConf
+    from spark_rapids_tpu.dataframe import DataFrame
+    from spark_rapids_tpu.plan.logical import InMemoryScan
     from spark_rapids_tpu.session import TpuSparkSession
 
-    root = _dataset_dir(true_sf)
+    root = root or _dataset_dir(true_sf)
     marker = os.path.join(root, "_COMPLETE")
     gen_sf = true_sf * _GEN_PER_TRUE_SF
+
+    def seeded(gen, i):
+        if seed is None:
+            return gen
+        return lambda sf: gen(sf, seed=seed + i)
+
     tables = [
-        ("lineitem", datagen.gen_lineitem),
-        ("orders", datagen.gen_orders),
-        ("customer", datagen.gen_customer),
-        ("supplier", datagen.gen_supplier),
+        ("lineitem", seeded(datagen.gen_lineitem, 0)),
+        ("orders", seeded(datagen.gen_orders, 1)),
+        ("customer", seeded(datagen.gen_customer, 2)),
+        ("supplier", seeded(datagen.gen_supplier, 3)),
         ("nation", lambda _sf: datagen.gen_nation()),
-        ("part", datagen.gen_part),
-        ("partsupp", datagen.gen_partsupp),
+        ("part", seeded(datagen.gen_part, 4)),
+        ("partsupp", seeded(datagen.gen_partsupp, 5)),
         ("region", lambda _sf: datagen.gen_region()),
     ]
     # cheap fingerprint: every table's column names + dtypes (from a
     # tiny-scale probe of the same generators) + the scale
     cols = {n: sorted((k, str(dt)) for k, (dt, _) in g(0.001).items())
             for n, g in tables}
-    fingerprint = json.dumps({"cols": cols, "gen_sf": gen_sf},
-                             sort_keys=True)
+    fingerprint = json.dumps({"cols": cols, "gen_sf": gen_sf, "seed": seed,
+                              "files": num_partitions}, sort_keys=True)
     if os.path.exists(marker) and open(marker).read() == fingerprint:
         return root
     s = TpuSparkSession(RapidsConf({"spark.rapids.sql.enabled": False}))
     for name, gen in tables:
-        df = s.create_dataframe(gen(gen_sf),
-                                num_partitions=num_partitions)
+        # contiguous row ranges, one host batch (= one written file) each
+        whole = HostBatch.from_pydict(gen(gen_sf))
+        step = -(-whole.num_rows // num_partitions)
+        chunks = [whole.slice(lo, step)
+                  for lo in range(0, whole.num_rows, step)]
+        df = DataFrame(InMemoryScan(chunks, whole.schema, len(chunks)), s)
         df.write_parquet(os.path.join(root, name), mode="overwrite")
-        print(f"wrote {name}", flush=True)
+        print(f"wrote {name}", file=sys.stderr, flush=True)
     open(marker, "w").write(fingerprint)
     return root
 
 
-def _session(tpu: bool, root: str):
+def _session(tpu: bool, root: str, extra_conf: Optional[dict] = None):
     from spark_rapids_tpu.config import RapidsConf
     from spark_rapids_tpu.session import TpuSparkSession
     s = TpuSparkSession(RapidsConf({
         "spark.rapids.sql.enabled": tpu,
         "spark.sql.shuffle.partitions": 4,
         "spark.rapids.sql.variableFloatAgg.enabled": True,
+        **(extra_conf or {}),
     }))
-    for name in ("lineitem", "orders", "customer", "supplier", "nation",
-                 "part", "partsupp", "region"):
+    for name in TABLE_NAMES:
         df = s.read.parquet(os.path.join(root, name))
         # BOTH engines cache inputs after the first read so the timing
         # table compares engine steady-state, not cache-vs-reread
@@ -100,6 +124,22 @@ def _checksum(rows):
                 not isinstance(v[0], bool):
             sums.append(round(float(sum(v)), 2))
     return (len(rows), tuple(sums))
+
+
+def values_agree(a, b) -> bool:
+    """Float-agg tolerance for one value pair (non-floats: equality)."""
+    if isinstance(a, float) or isinstance(b, float):
+        if a is None or b is None:
+            return a is b
+        return abs(a - b) <= 1e-4 * max(1.0, abs(a), abs(b))
+    return a == b
+
+
+def checksums_agree(tc, cc) -> bool:
+    """Two :func:`_checksum` results agree: same row count, and every
+    numeric column sum within the float-agg tolerance."""
+    return tc[0] == cc[0] and len(tc[1]) == len(cc[1]) and all(
+        values_agree(a, b) for a, b in zip(tc[1], cc[1]))
 
 
 def run(true_sf: float, out_path: str) -> dict:
@@ -149,9 +189,7 @@ def _write_report(true_sf: float, results: dict, out_path: str) -> dict:
         if "tpu_check" not in r or "cpu_check" not in r:
             continue  # mid-query interruption
         tc, cc = r["tpu_check"], r["cpu_check"]
-        ok = tc[0] == cc[0] and len(tc[1]) == len(cc[1]) and all(
-            abs(a - b) <= 1e-4 * max(1.0, abs(a), abs(b))
-            for a, b in zip(tc[1], cc[1]))
+        ok = checksums_agree(tc, cc)
         all_ok = all_ok and ok
         sp = r["cpu_s"] / r["tpu_s"] if r["tpu_s"] else float("inf")
         lines.append(f"| {qname} | {r['tpu_s']} | {r['cpu_s']} | "
@@ -174,7 +212,7 @@ def _write_report(true_sf: float, results: dict, out_path: str) -> dict:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--sf", type=float, default=1.0)
-    ap.add_argument("--out", default="BENCH_SF1.md")
+    ap.add_argument("--out", default="sf1_report.md")
     args = ap.parse_args(argv)
     rep = run(args.sf, args.out)
     print(json.dumps({"sf": args.sf, "all_agree": rep["all_agree"],

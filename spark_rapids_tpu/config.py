@@ -530,7 +530,11 @@ COMPILE_CACHE_DIR = conf_str(
     "spark.rapids.sql.tpu.compileCacheDir", "",
     "Directory for JAX's persistent XLA compilation cache.  When set, "
     "compiled executables survive the process so re-runs (and "
-    "session.prewarm()) skip recompilation; empty disables persistence.")
+    "session.prewarm()) skip recompilation; empty leaves persistence "
+    "to the entry point (bench.py, chip_smoke.py and the tests use "
+    "<checkout>/.jax_cache).  Ignored, with one log line, where the "
+    "JAX_COMPILATION_CACHE_DIR environment variable is set: the "
+    "operator has placed the cache from outside.")
 RETRY_MAX_ATTEMPTS = conf_int(
     "spark.rapids.sql.tpu.retry.maxAttempts", 3,
     "Total attempts (first try included) the unified RetryPolicy allows "
@@ -833,35 +837,40 @@ PALLAS_STRINGS_ENABLED = conf_bool(
     "(kernels.pallas_strings): one fused pass over the byte buffer "
     "replacing the shifted-gather + searchsorted XLA formulation.  "
     "Engages on a real TPU backend only (or under pallas.interpret); "
-    "anywhere else the bit-identical XLA fallback runs and "
-    "pallasFallbackCount increments.  The deprecated "
-    "SPARK_RAPIDS_PALLAS_STRINGS env var (0/false=off, interp=interpret) "
-    "is honored for one release when this conf is not explicitly set.")
+    "anywhere else the bit-identical XLA formulation runs and "
+    "pallasFallbackCount increments.  Default on: the v5e compiler "
+    "accepts it at TPC-H SF1 shapes (tests/test_chip_compile.py).")
 PALLAS_GATHER_SCATTER_ENABLED = conf_bool(
-    "spark.rapids.sql.tpu.pallas.gatherScatter.enabled", True,
+    "spark.rapids.sql.tpu.pallas.gatherScatter.enabled", False,
     "Kernel-tier gate for the segmented k-way gather/scatter Pallas "
     "kernel: one pass per output block walking the per-input segment "
     "table replaces the k drop-mode scatter chain inside concat_kway / "
     "gather_segments_kway (rows and bytes, honoring the live-bytes "
     "window so take_head-truncated inputs cannot leak stale tail "
-    "bytes).  TPU-only with automatic bit-identical XLA fallback; "
-    "unsupported element dtypes always take the fallback silently.")
+    "bytes).  Unsupported element dtypes always take the XLA chain.  "
+    "Default OFF: the v5e compiler refuses the kernel's 1-D vector "
+    "gathers (docs/kernels.md has its message); enabling it on a chip "
+    "fails the stage's compile.")
 PALLAS_JOIN_PROBE_ENABLED = conf_bool(
-    "spark.rapids.sql.tpu.pallas.joinProbe.enabled", True,
+    "spark.rapids.sql.tpu.pallas.joinProbe.enabled", False,
     "Kernel-tier gate for the hash-join probe Pallas kernel: when the "
     "sorted build-side arrays fit pallas.vmemBudgetBytes, one fused "
     "kernel performs both searchsorted passes, candidate expansion and "
     "the exact-match word verify of join_pairs_static, emitting the "
     "same capacity-bucketed pair buffers (hash_join_static and the "
-    "mesh-fused pipeline consume it unchanged).  TPU-only with "
-    "automatic bit-identical XLA fallback.")
+    "mesh-fused pipeline consume it unchanged).  Default OFF: the v5e "
+    "compiler refuses the kernel's 1-D vector gathers "
+    "(docs/kernels.md); enabling it on a chip fails the stage's "
+    "compile.")
 PALLAS_STRING_HASH_ENABLED = conf_bool(
-    "spark.rapids.sql.tpu.pallas.stringHash.enabled", True,
+    "spark.rapids.sql.tpu.pallas.stringHash.enabled", False,
     "Kernel-tier gate for the string key-hash Pallas kernel: a "
     "row-blocked Horner pass over the byte buffer with segment "
     "boundaries from the offsets replaces the pow-table + segment-sum "
     "XLA formulation of string_hash2 (sort/join key hashing).  "
-    "TPU-only with automatic bit-identical XLA fallback.")
+    "Default OFF: the v5e compiler refuses the kernel's 1-D vector "
+    "gathers (docs/kernels.md); enabling it on a chip fails the "
+    "stage's compile.")
 PALLAS_INTERPRET = conf_bool(
     "spark.rapids.sql.tpu.pallas.interpret", False,
     "Debug: run every engaged kernel-tier Pallas kernel in interpret "
@@ -873,7 +882,7 @@ PALLAS_VMEM_BUDGET = conf_bytes(
     "spark.rapids.sql.tpu.pallas.vmemBudgetBytes", 8 << 20,
     "VMEM residency budget shared by the kernel tier: a kernel whose "
     "resident working set (e.g. the join probe's sorted build arrays) "
-    "exceeds this many bytes falls back to the XLA formulation and "
+    "exceeds this many bytes takes the XLA formulation and "
     "counts into pallasFallbackCount.  Sized well under a TPU core's "
     "~16 MB VMEM to leave room for per-block buffers.")
 

@@ -6,7 +6,7 @@ answer (SURVEY.md section 5 delegates failure *detection* to Spark task
 retry + lineage).  This package gives the TPU engine the same posture,
 organized in five pieces:
 
-* :mod:`~spark_rapids_tpu.fault.errors` — error taxonomy.  Every raised
+* :mod:`~spark_rapids_tpu.fault.errors` — error classification.  Every raised
   error classifies as ``RETRYABLE_OOM`` (RESOURCE_EXHAUSTED allocation
   failures), ``DEVICE_LOST`` (XLA worker crashed/restarted, kernel
   faults, DATA_LOSS/INTERNAL status codes, partition deadline expiry) or
@@ -23,7 +23,7 @@ organized in five pieces:
   (conf ``spark.rapids.sql.tpu.partition.timeoutSec``): a monitor
   thread raises a classified :class:`PartitionTimeout` into the driving
   thread instead of letting a wedged dot hang the suite for 40 minutes
-  (round-5 VERDICT evidence).
+  (round-5 on-chip evidence).
 * :mod:`~spark_rapids_tpu.fault.recovery` — device-lost recovery:
   reset the :class:`DeviceRuntime`, invalidate the spill catalog's
   device tier (host/disk copies survive and re-upload lazily), replay

@@ -1,4 +1,4 @@
-"""Error taxonomy: every raised error maps to one retry class.
+"""Error classes: every raised error maps to one retry class.
 
 The reference engine distinguishes retryable allocation failures
 (RmmRapidsRetryIterator's RetryOOM/SplitAndRetryOOM) from fatal device
@@ -9,6 +9,16 @@ by status code + message shape, with an explicit escape hatch: an error
 object carrying a ``rapids_error_class`` attribute (set by the fault
 injector and by the donated-dispatch fail-fast path) classifies as
 exactly that.
+
+A compile refusal is NOT a lost device.  XLA reports a kernel the chip's
+compiler rejects as ``INTERNAL: Mosaic failed to compile TPU kernel``,
+which by status code alone would read as DEVICE_LOST and be replayed
+``retry.maxAttempts`` times before the partition quietly finishes on the
+CPU.  Replaying a program that cannot compile can never succeed, so
+anything raised while a program is being lowered or compiled — a
+``MosaicError``, an XLA error that says it failed to compile, or any
+error ``utils.compile_registry`` saw unwinding a compile phase (it pins
+those via :func:`mark_non_retryable`) — is NON_RETRYABLE.
 """
 
 from __future__ import annotations
@@ -25,9 +35,10 @@ class ErrorClass(enum.Enum):
     #: + device-tier invalidation + replay, then per-partition CPU
     #: fallback.
     DEVICE_LOST = "device_lost"
-    #: User errors, donated-dispatch OOM (inputs consumed at dispatch — a
-    #: retry cannot re-present them), KeyboardInterrupt/SystemExit.
-    #: Never retried.
+    #: User errors, compile refusals (a program the compiler rejects is
+    #: rejected on every replay), donated-dispatch OOM (inputs consumed
+    #: at dispatch — a retry cannot re-present them),
+    #: KeyboardInterrupt/SystemExit.  Never retried.
     NON_RETRYABLE = "non_retryable"
 
 
@@ -60,6 +71,13 @@ _DEVICE_LOST_FRAGMENTS = ("worker crashed", "worker restarted",
 #: jaxlib modules that move between versions).
 _XLA_ERROR_TYPES = ("XlaRuntimeError", "JaxRuntimeError")
 
+#: Pallas re-raises a kernel the TPU compiler refuses as MosaicError
+#: (VerificationError subclasses it); the XLA-level text of the same
+#: refusal, should it arrive unconverted, names the compile.
+_COMPILE_REFUSAL_TYPES = ("MosaicError", "VerificationError")
+_COMPILE_REFUSAL_FRAGMENTS = ("failed to compile", "mosaic",
+                              "during compilation")
+
 
 def classify_error(err: BaseException) -> ErrorClass:
     """Map a raised error to its :class:`ErrorClass`."""
@@ -69,11 +87,15 @@ def classify_error(err: BaseException) -> ErrorClass:
     explicit = getattr(err, "rapids_error_class", None)
     if isinstance(explicit, ErrorClass):
         return explicit
+    if type(err).__name__ in _COMPILE_REFUSAL_TYPES:
+        return ErrorClass.NON_RETRYABLE
     if type(err).__name__ in _XLA_ERROR_TYPES:
         msg = str(err)
+        low = msg.lower()
+        if any(frag in low for frag in _COMPILE_REFUSAL_FRAGMENTS):
+            return ErrorClass.NON_RETRYABLE
         if "RESOURCE_EXHAUSTED" in msg:
             return ErrorClass.RETRYABLE_OOM
-        low = msg.lower()
         if any(code in msg for code in _DEVICE_LOST_CODES) or \
                 any(frag in low for frag in _DEVICE_LOST_FRAGMENTS):
             return ErrorClass.DEVICE_LOST

@@ -1,7 +1,8 @@
 """Per-partition deadline watchdog.
 
-Round-5 on-chip evidence (VERDICT.md): test_hashagg / test_tpch_like
-hung 40+ minutes on a single dot with no watchdog.  This module arms a
+Round-5 on-chip evidence (pre-round records, in git history):
+test_hashagg / test_tpch_like hung 40+ minutes on a single dot with no
+watchdog.  This module arms a
 deadline around each driven partition (conf
 ``spark.rapids.sql.tpu.partition.timeoutSec``; 0 = off, the tier-1
 default — the bench driver turns it on): a monitor thread waits on an

@@ -1,6 +1,6 @@
 """Pallas TPU kernel for the string contains/LIKE '%needle%' scan
-(VERDICT r4 item 8; reference: stringFunctions.scala's dedicated native
-contains kernel over libcudf).
+(reference: stringFunctions.scala's dedicated native contains kernel
+over libcudf).
 
 The XLA path (exprs/strings.py:_find_matches + _rows_with_match) costs:
 L shifted gathers over the byte buffer, a per-byte ``searchsorted`` over
@@ -22,12 +22,15 @@ Layout: the byte buffer rides as 1-D u8 blocks; each program reads its
 block AND the next block (a second BlockSpec shifted by one — Pallas
 blocks cannot overlap, so the halo is expressed as a duplicate input)
 and emits BLOCK match flags via L static slices of the concatenation.
+The u8 halves are widened to i32 BEFORE the concatenate: the v5e Mosaic
+compiler refuses ``tpu.concatenate`` of two ``vector<16384xi8>``
+("Invalid input layout"), and accepts the i32 form.
 
 Used automatically for Contains/Like-contains when the backend is a real
 TPU: exprs/strings.py routes through the kernel tier's ``strings`` entry
 (kernels.pallas_tier — conf gate ``spark.rapids.sql.tpu.pallas.strings.
 enabled``, interpret mode under ``pallas.interpret``); the XLA
-formulation remains both the CPU-backend path and the fallback.
+formulation remains the CPU-backend path.
 """
 
 from __future__ import annotations
@@ -36,39 +39,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from spark_rapids_tpu.utils.compile_registry import instrumented_jit
 
 BLOCK = 16384  # bytes of match output per program (128-aligned)
 
 
-def use_pallas_strings() -> bool:
-    """Deprecated: the decision now lives in the kernel tier
-    (``spark.rapids.sql.tpu.pallas.strings.enabled`` + backend predicate;
-    the env var survives one release as an alias).  Kept for callers that
-    only need the boolean."""
-    from spark_rapids_tpu.kernels import pallas_tier
-    return pallas_tier.decide("strings").engaged
-
-
-def _interpret() -> bool:
-    """Deprecated alias resolution (tier ``pallas.interpret`` conf or the
-    old env value) — the default for direct :func:`rows_with_match`
-    callers; production traffic passes ``interpret`` explicitly through
-    the tier."""
-    from spark_rapids_tpu.kernels import pallas_tier
-    return pallas_tier.decide("strings").interpret
-
-
 def _match_kernel(cur_ref, nxt_ref, scur_ref, snxt_ref, out_ref, *,
                   needle: tuple, block: int):
-    x = jnp.concatenate([cur_ref[...], nxt_ref[...]])
-    m = x[0:block] == np.uint8(needle[0])
+    i32 = jnp.int32
+    x = jnp.concatenate([cur_ref[...].astype(i32), nxt_ref[...].astype(i32)])
+    m = x[0:block] == i32(needle[0])
     for k in range(1, len(needle)):
-        m = m & (x[k:k + block] == np.uint8(needle[k]))
+        m = m & (x[k:k + block] == i32(needle[k]))
     if len(needle) > 1:
-        s = jnp.concatenate([scur_ref[...], snxt_ref[...]])
+        s = jnp.concatenate([scur_ref[...].astype(i32),
+                             snxt_ref[...].astype(i32)])
         cross = s[1:1 + block] != 0
         for k in range(2, len(needle)):
             cross = cross | (s[k:k + block] != 0)
@@ -115,14 +101,12 @@ def contains_match(data, offsets, needle: tuple, interpret: bool = False):
 
 
 def rows_with_match(data, offsets, validity, cap: int, needle: bytes,
-                    interpret: bool = None):
+                    interpret: bool):
     """bool[cap]: row contains ``needle`` — the Pallas-backed analogue of
-    exprs.strings._rows_with_match.  ``interpret`` defaults to the tier
-    decision (conf / deprecated env alias) for direct callers."""
+    exprs.strings._rows_with_match (``interpret`` is the tier's
+    decision)."""
     if len(needle) == 0:
         return jnp.ones(cap, dtype=jnp.bool_)
-    if interpret is None:
-        interpret = _interpret()
     match = contains_match(data, offsets, tuple(needle), interpret)
     # exclusive cumsum -> per-row match counts via two O(cap) gathers
     c = jnp.concatenate([jnp.zeros(1, jnp.int32),

@@ -5,11 +5,15 @@ Each kernel declares, in ONE place (:func:`register`):
 * a name and its conf gate (``spark.rapids.sql.tpu.pallas.<kernel>.enabled``),
 * a backend predicate — compiled on a real TPU backend only, interpret
   mode under ``spark.rapids.sql.tpu.pallas.interpret`` so CPU tests can
-  pin bit-identity (the generalization of the old
-  ``use_pallas_strings()`` env switch),
-* an automatic fallback to the existing XLA formulation (the
-  splitV2/donation conf-gate pattern: the fallback IS the semantics, the
-  kernel is only a faster lowering and must be bit-identical),
+  pin bit-identity,
+* the XLA formulation it replaces (the splitV2/donation conf-gate
+  pattern: the XLA formulation IS the semantics, the kernel is only a
+  faster lowering and must be bit-identical) — taken by POLICY only
+  (gate off, non-TPU backend, residency over budget), never because an
+  engaged kernel failed: an enabled kernel that cannot trace raises
+  :class:`PallasKernelError` with the kernel's name, and one the chip's
+  compiler refuses fails the enclosing stage's compile (Mosaic lowers
+  when the ENCLOSING program is lowered, outside any ``try`` here),
 * a per-kernel obs span (site ``pallas``) so ``rapidsprof --critpath``
   attributes each win, and
 * a shared VMEM residency budget (``pallas.vmemBudgetBytes``): a kernel
@@ -44,7 +48,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -58,10 +61,11 @@ from spark_rapids_tpu.config import (
     PALLAS_STRING_HASH_ENABLED, PALLAS_VMEM_BUDGET, RapidsConf,
 )
 
-#: Deprecated alias for the ``strings`` kernel gate (one release):
-#: 0/false = off, interp = engage in interpret mode.  Honored only while
-#: ``spark.rapids.sql.tpu.pallas.strings.enabled`` is not explicitly set.
-_DEPRECATED_STRINGS_ENV = "SPARK_RAPIDS_PALLAS_STRINGS"
+
+class PallasKernelError(RuntimeError):
+    """An ENABLED kernel failed while tracing.  Never retried and never
+    replaced by the XLA formulation: a kernel whose gate is on must work
+    (fault.errors classifies this NON_RETRYABLE)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,8 +120,8 @@ def _conf() -> RapidsConf:
 
 def fallback_count() -> int:
     """Process-wide count of kernel-tier fallbacks taken at trace time
-    (backend/budget/lowering-failure; conf-off does NOT count — a
-    disabled kernel is policy, not a fallback)."""
+    (backend/budget; conf-off does NOT count — a disabled kernel is
+    policy, not a fallback)."""
     return _fallbacks
 
 
@@ -134,12 +138,6 @@ def decide(name: str, resident_bytes: int = 0) -> Decision:
     conf = _conf()
     enabled = bool(spec.entry.get(conf))
     interp = bool(PALLAS_INTERPRET.get(conf))
-    if name == "strings" and not conf.explicitly_set(spec.entry.key):
-        flag = os.environ.get(_DEPRECATED_STRINGS_ENV)
-        if flag in ("0", "false"):
-            enabled = False
-        elif flag == "interp":
-            interp = True
     if not enabled:
         return Decision(False, False, "off")
     if resident_bytes and resident_bytes > PALLAS_VMEM_BUDGET.get(conf):
@@ -148,11 +146,7 @@ def decide(name: str, resident_bytes: int = 0) -> Decision:
         return Decision(False, False, "budget")
     if interp:
         return Decision(True, True, "")
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        on_tpu = False
-    if on_tpu:
+    if jax.default_backend() == "tpu":
         return Decision(True, False, "")
     return Decision(False, False, "backend")
 
@@ -162,9 +156,8 @@ def run(name: str, pallas_fn: Callable, fallback_fn: Callable,
     """Dispatch one kernel invocation through the tier.
 
     ``pallas_fn(interpret: bool)`` builds the Pallas lowering;
-    ``fallback_fn()`` builds the XLA formulation.  Runs at trace time:
-    a lowering failure falls back (and counts) instead of failing the
-    query, mirroring the splitV2 conf-gate pattern."""
+    ``fallback_fn()`` builds the XLA formulation.  Runs at trace time;
+    an engaged kernel that fails to trace raises with its name."""
     d = decide(name, resident_bytes)
     if not d.engaged:
         if d.reason != "off":
@@ -174,9 +167,11 @@ def run(name: str, pallas_fn: Callable, fallback_fn: Callable,
     t0 = time.monotonic_ns()
     try:
         out = pallas_fn(d.interpret)
-    except Exception:
-        _note_fallback()
-        return fallback_fn()
+    except Exception as e:
+        raise PallasKernelError(
+            f"Pallas kernel '{name}' is enabled "
+            f"({_KERNELS[name].entry.key}) but "
+            f"failed to trace: {type(e).__name__}: {e}") from e
     emit_span("pallas", name, t0=t0, t1=time.monotonic_ns(),
               interpret=d.interpret, resident_bytes=resident_bytes)
     return out

@@ -10,6 +10,7 @@ default, mirroring how the reference's host runtime is native
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -21,22 +22,38 @@ _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_HERE, "native", "batch_runtime.cc")
 _SO = os.path.join(_HERE, "native", "libbatch_runtime.so")
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
 def _build() -> Optional[str]:
+    """Path of an up-to-date library, building it from ``_SRC`` if
+    needed; None (python path) when it cannot be built — logged once,
+    with the compiler's own stderr.  The compiler writes to a
+    per-process temporary name and ``os.replace`` publishes it, so
+    concurrent builders (six test workers) never load a half-written
+    file."""
     if os.path.exists(_SO) and \
             os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
         return _SO
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", _SO,
+            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp,
              _SRC],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return _SO
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = getattr(e, "stderr", b"") or b""
+        _log.warning(
+            "native host runtime not built (%s: %s); taking the pure-"
+            "python path.  Compiler stderr:\n%s", type(e).__name__, e,
+            stderr.decode(errors="replace")[-2000:])
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return None
 
 

@@ -50,10 +50,7 @@ def make_distributed_agg_step(mesh: Mesh, cap: int):
     n = mesh.shape[DATA_AXIS]
     exchange = make_exchange_fn(mesh, n_cols=2, cap=cap)
 
-    try:
-        from jax import shard_map  # jax >= 0.6 top-level export
-    except ImportError:  # jax 0.4.x keeps it in experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def local_agg(keys, values, validity, num_rows):
         k, v, val, nr = keys[0], values[0], validity[0], num_rows[0]
@@ -61,13 +58,12 @@ def make_distributed_agg_step(mesh: Mesh, cap: int):
         gk, gs, ng = _local_sum_by_key(k, v, val, nr, out_cap)
         return gk[None], gs[None], ng[None]
 
-    from spark_rapids_tpu.parallel.mesh_shuffle import shard_map_kwargs
     local_agg_fn = jax.jit(shard_map(
         local_agg, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None),
                   P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None), P(DATA_AXIS)),
-        **shard_map_kwargs()))
+        check_vma=False))
 
     def step(keys, values, validity, num_rows):
         pids = (jnp.abs(keys) % n).astype(jnp.int32)

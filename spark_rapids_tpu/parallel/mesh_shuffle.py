@@ -25,12 +25,12 @@ plan's shuffle rather than a standalone demo.
 
 from __future__ import annotations
 
-import logging
 from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_tpu import types as T
@@ -42,64 +42,34 @@ from spark_rapids_tpu.kernels.layout import (
 
 DATA_AXIS = "data"
 
+# Every shard_map here and in mesh_spmd/distributed passes
+# ``check_vma=False``: the static replication checker has no rule for
+# ``pallas_call`` (kernel-tier kernels traced inside mesh programs raise
+# NotImplementedError) and mis-tracks ``lax.scan`` carries mixing a
+# replicated build side with sharded probe rows.  It is advisory only —
+# output specs are verified structurally by plan_verify.
+
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     """n-device 1-D mesh on the ``data`` axis.
 
-    When the default platform has fewer than ``n_devices`` chips (e.g. a
-    single real TPU during development), fall back to the CPU backend's
-    virtual devices (``--xla_force_host_platform_device_count``) so mesh
-    logic is exercised without hardware — the same trick tests/conftest.py
-    uses.  Raises if no backend can supply ``n_devices`` devices.
+    The mesh is built from the DEFAULT platform's devices only, and
+    raises when it has fewer than ``n_devices``: a process on a TPU never
+    switches a mesh to CPU virtual devices (that is how a run mislabels
+    CPU virtual-device scaling as ICI scaling).  Mesh logic is exercised
+    without hardware by making the CPU the default platform
+    (``JAX_PLATFORMS=cpu`` + ``--xla_force_host_platform_device_count``,
+    as tests/conftest.py does).
     """
     devs = jax.devices()
     if n_devices is not None and len(devs) < n_devices:
-        try:
-            cpu = jax.devices("cpu")
-        except RuntimeError:
-            cpu = []
-        if len(cpu) >= n_devices:
-            if devs and devs[0].platform != cpu[0].platform:
-                # Through the explain sink (PR 10), not a bare print: a
-                # silent backend switch is how a bench run mislabels CPU
-                # virtual-device scaling as TPU scaling.
-                logging.getLogger("spark_rapids_tpu.explain").warning(
-                    "make_mesh: default platform %r has only %d device(s); "
-                    "falling back to %d CPU virtual devices — the mesh "
-                    "runs on cpu, NOT on %r",
-                    devs[0].platform, len(devs), n_devices,
-                    devs[0].platform)
-            devs = cpu
-        else:
-            raise RuntimeError(
-                f"need {n_devices} devices, default platform has "
-                f"{len(devs)} and cpu has {len(cpu)}; set JAX_PLATFORMS=cpu "
-                f"and --xla_force_host_platform_device_count={n_devices}")
+        raise RuntimeError(
+            f"need {n_devices} devices, default platform "
+            f"{devs[0].platform!r} has {len(devs)}; for a CPU rehearsal "
+            f"set JAX_PLATFORMS=cpu and "
+            f"--xla_force_host_platform_device_count={n_devices}")
     n = n_devices or len(devs)
     return Mesh(np.array(devs[:n]), (DATA_AXIS,))
-
-
-def shard_map_kwargs() -> dict:
-    """kwargs disabling shard_map's static replication checker.
-
-    The checker has no rule for ``pallas_call`` (kernels/pallas_tier.py
-    kernels traced inside mesh programs raise NotImplementedError) and
-    mis-tracks ``lax.scan`` carries mixing a replicated build side with
-    sharded probe rows.  It is advisory only — correctness never depends
-    on it; output specs are verified structurally by plan_verify.  The
-    kwarg is probed by name: jax 0.4.x calls it ``check_rep``, newer
-    releases renamed it ``check_vma``.
-    """
-    import inspect
-    try:
-        from jax import shard_map  # jax >= 0.6 top-level export
-    except ImportError:  # jax 0.4.x keeps it in experimental
-        from jax.experimental.shard_map import shard_map
-    params = inspect.signature(shard_map).parameters
-    for kw in ("check_rep", "check_vma"):
-        if kw in params:
-            return {kw: False}
-    return {}
 
 
 def _local_partition_buckets(data_cols, validity_cols, num_rows, pids,
@@ -177,10 +147,6 @@ def make_exchange_fn(mesh: Mesh, n_cols: int, cap: int):
         return ([c[None] for c in o_data], [v[None] for v in o_valid],
                 o_rows[None])
 
-    try:
-        from jax import shard_map  # jax >= 0.6 top-level export
-    except ImportError:  # jax 0.4.x keeps it in experimental
-        from jax.experimental.shard_map import shard_map
     in_specs = (
         [P(DATA_AXIS, None)] * n_cols,
         [P(DATA_AXIS, None)] * n_cols,
@@ -191,7 +157,7 @@ def make_exchange_fn(mesh: Mesh, n_cols: int, cap: int):
                  [P(DATA_AXIS, None)] * n_cols,
                  P(DATA_AXIS))
     return jax.jit(shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **shard_map_kwargs()))
+                             out_specs=out_specs, check_vma=False))
 
 
 # --------------------------------------------------------------------------
@@ -449,10 +415,6 @@ def _make_mesh_payload_fn(mesh: Mesh, sig, cap: int, ecaps: tuple,
             cols, nr, pid, sig, n, cap, ecaps, out_cap, out_ecaps)
         return [o[None] for o in outs] + [total[None]]
 
-    try:
-        from jax import shard_map  # jax >= 0.6 top-level export
-    except ImportError:  # jax 0.4.x keeps it in experimental
-        from jax.experimental.shard_map import shard_map
     in_specs = []
     for is_varlen in sig:
         k = 3 if is_varlen else 2
@@ -464,7 +426,7 @@ def _make_mesh_payload_fn(mesh: Mesh, sig, cap: int, ecaps: tuple,
         out_specs += [P(DATA_AXIS, None)] * k
     out_specs.append(P(DATA_AXIS))
     return jax.jit(shard_map(spmd, mesh=mesh, in_specs=(in_specs,),
-                             out_specs=out_specs, **shard_map_kwargs()))
+                             out_specs=out_specs, check_vma=False))
 
 
 # Compiled exchange programs, keyed by (mesh, schema signature, capacities).

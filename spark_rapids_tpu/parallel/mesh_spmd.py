@@ -368,17 +368,13 @@ def run_mesh_stage(root, ctx, variant: str,
             scache[key] = schemas
             return flat_out, ovf
 
-        try:
-            from jax import shard_map  # jax >= 0.6 top-level export
-        except ImportError:  # jax 0.4.x keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         # replication checker off unconditionally (not just for the
         # replicated-build fused join): pallas-tier kernels traced inside
-        # the stage body have no replication rule — see shard_map_kwargs
-        from spark_rapids_tpu.parallel.mesh_shuffle import shard_map_kwargs
+        # the stage body have no replication rule — see mesh_shuffle
         program = instrumented_jit(
             shard_map(body, mesh=mesh, in_specs=(tuple(in_specs),),
-                      out_specs=P(DATA_AXIS), **shard_map_kwargs()),
+                      out_specs=P(DATA_AXIS), check_vma=False),
             label=f"meshStage:{root.name}")
         cache[key] = program
 

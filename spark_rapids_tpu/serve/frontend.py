@@ -19,7 +19,7 @@ over the newline-delimited JSON protocol of
 * **sentinel-driven admission control**: before executing, the front
   door consults the history store's median/MAD wall-time aggregate for
   the query's fingerprint; a query whose *predicted* latency already
-  misses its deadline is shed immediately (DeadlineExceeded taxonomy,
+  misses its deadline is shed immediately (DeadlineExceeded classification,
   counted per tenant) instead of burning device time on a doomed run —
   the serving analogue of the PR-15 regression sentinel, pointed
   forward instead of backward.
@@ -51,7 +51,7 @@ _WAIT_SLICE_S = 0.25
 
 
 def _error_class(e: BaseException) -> str:
-    """The fault-taxonomy name for the wire (fault/errors discipline):
+    """The fault-classification name for the wire (fault/errors discipline):
     prefer the exception's declared rapids_error_class context, fall
     back to the exception type name."""
     if isinstance(e, DeadlineExceeded):

@@ -21,7 +21,7 @@ Requests (``op`` field): ``submit`` (``sql`` text or ``template`` name
 ``ok``; a submit response adds ``result`` (encoded batch) and
 ``metrics`` (the query's camelCase metrics dict plus the front door's
 ``resultCacheHits``/``admissionShed``), or on failure ``error`` +
-``error_class`` (the fault taxonomy name — ``DeadlineExceeded`` for
+``error_class`` (the fault classification name — ``DeadlineExceeded`` for
 deadline/admission sheds).
 
 Blocking discipline: every socket read waits in bounded <=0.25s slices
@@ -51,7 +51,7 @@ class ProtocolError(RuntimeError):
 class FrontDoorError(RuntimeError):
     """A server-side failure relayed to the client.
 
-    ``error_class`` carries the server's fault-taxonomy class name so
+    ``error_class`` carries the server's fault-classification class name so
     callers can branch without string-matching messages."""
 
     def __init__(self, message: str, error_class: str = ""):

@@ -16,9 +16,10 @@ round trip.  This module makes both quantities *measured*:
   ``session.execute`` into ``last_metrics`` (``compileCount``,
   ``compileWallNs``, ``dispatchCount``, ``compiledShapes``) and surfaced
   by ``bench.py`` as ``compile_s``.
-* :func:`enable_persistent_cache` turns on JAX's persistent compilation
-  cache (conf ``spark.rapids.sql.tpu.compileCacheDir``) so repeated
-  processes skip recompilation entirely.
+* :func:`enable_persistent_cache` owns the placement of JAX's persistent
+  compilation cache (``JAX_COMPILATION_CACHE_DIR`` wins, else conf
+  ``spark.rapids.sql.tpu.compileCacheDir``, else ``<checkout>/.jax_cache``)
+  so repeated processes skip recompilation entirely.
 
 Data-plane accounting rides the same snapshot/delta machinery:
 
@@ -42,6 +43,9 @@ excluding the first-run execution that the wall number includes.
 from __future__ import annotations
 
 import functools
+import logging
+import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Optional
@@ -55,6 +59,7 @@ _LOCK = threading.Lock()
 _STATS: Dict[str, int] = {
     # cumulative process-wide; per-query deltas come from snapshot() pairs
     "compiles": 0,          # executable-cache misses observed at call sites
+    "cache_bypass_compiles": 0,  # ... of which donating (never persisted)
     "compile_wall_ns": 0,   # wall ns of calls that triggered a compile
     "dispatches": 0,        # jitted program invocations
     "backend_compile_ns": 0,  # jax.monitoring backend compile durations
@@ -94,7 +99,7 @@ def per_label_compiles() -> Dict[str, int]:
 
 
 def _record(label: str, compiled: bool, wall_ns: int,
-            donated_bytes: int = 0) -> None:
+            donated_bytes: int = 0, bypassed_cache: bool = False) -> None:
     with _LOCK:
         _STATS["dispatches"] += 1
         _STATS["donated_bytes"] += donated_bytes
@@ -102,6 +107,8 @@ def _record(label: str, compiled: bool, wall_ns: int,
             _STATS["compiles"] += 1
             _STATS["compile_wall_ns"] += wall_ns
             _LABEL_COMPILES[label] = _LABEL_COMPILES.get(label, 0) + 1
+            if bypassed_cache:
+                _STATS["cache_bypass_compiles"] += 1
     # credit the executing query's scope as well: under concurrent
     # serving the global delta mixes queries, so session.execute reads
     # these per-scope counters instead
@@ -344,7 +351,8 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
         t1 = time.monotonic_ns()
         after = _cache_size(jitted)
         compiled = after >= 0 and after != before
-        _record(name, compiled, t1 - t0, donated_bytes)
+        _record(name, compiled, t1 - t0, donated_bytes,
+                bypassed_cache=bool(donate))
         if compiled:
             _obs_events.emit_span("dispatch", name, t0=t0, t1=t1,
                                   compiled=True)
@@ -364,9 +372,45 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
 _MONITORING_HOOKED = False
 
 
+#: Per-thread stack of the exception that was being handled when each
+#: open compile phase started (normally None).  jax brackets tracing,
+#: MLIR lowering and the backend compile with a start scalar and an end
+#: duration that fires even while the phase unwinds by exception.
+_COMPILE_PHASES = threading.local()
+
+
+def _on_compile_phase_start(event: str, value: float, **kw) -> None:
+    if "/compile/" not in event:
+        return
+    stack = getattr(_COMPILE_PHASES, "ambient", None)
+    if stack is None:
+        stack = _COMPILE_PHASES.ambient = []
+    stack.append(sys.exception())
+
+
+def _pin_compile_failure(fun_name: str) -> None:
+    """Called as a compile phase ends: an exception in flight that was
+    not already being handled when the phase began was raised BY the
+    trace/lower/compile — a refusal that no replay can fix, whatever
+    its status text says (Mosaic refusals read ``INTERNAL``)."""
+    stack = getattr(_COMPILE_PHASES, "ambient", None)
+    ambient = stack.pop() if stack else None
+    err = sys.exception()
+    if err is None or err is ambient or \
+            getattr(err, "rapids_error_class", None) is not None:
+        return
+    from spark_rapids_tpu.fault.errors import mark_non_retryable
+    mark_non_retryable(err)
+    err.add_note(f"raised while lowering/compiling program "
+                 f"'{fun_name}': a compile refusal, not a device loss "
+                 f"(never retried, never completed on the CPU)")
+
+
 def _on_event_duration(event: str, duration_secs: float, **kw) -> None:
     if "compil" not in event:
         return
+    if "/compile/" in event:
+        _pin_compile_failure(kw.get("fun_name", "?"))
     with _LOCK:
         _STATS["backend_compile_ns"] += int(duration_secs * 1e9)
     # the listener fires on the dispatching thread mid-jit, so the
@@ -383,6 +427,7 @@ def _hook_monitoring() -> None:
     try:
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_on_event_duration)
+        monitoring.register_scalar_listener(_on_compile_phase_start)
         _MONITORING_HOOKED = True
     except Exception:  # noqa: BLE001 — monitoring API is best-effort
         _MONITORING_HOOKED = True  # don't retry every call
@@ -394,19 +439,55 @@ _hook_monitoring()
 # -- persistent compilation cache --------------------------------------------
 
 _PERSISTENT_DIR: Optional[str] = None
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_log = logging.getLogger(__name__)
 
 
-def enable_persistent_cache(cache_dir: str,
-                            min_compile_secs: float = 1.0) -> None:
-    """Point XLA's persistent compilation cache at ``cache_dir`` (conf
-    ``spark.rapids.sql.tpu.compileCacheDir``): executables survive the
-    process, so a re-run pre-warms from disk instead of recompiling."""
+def persistent_cache_stats() -> Dict[str, Any]:
+    """Where the persistent cache is and how much of the compile work can
+    ever land in it: programs compiled by a DONATING dispatch bypass it
+    (:func:`_install_cache_bypass`) and recompile in every process."""
+    with _LOCK:
+        compiles = _STATS["compiles"]
+        bypassed = _STATS["cache_bypass_compiles"]
+    files = len(os.listdir(_PERSISTENT_DIR)) \
+        if _PERSISTENT_DIR and os.path.isdir(_PERSISTENT_DIR) else 0
+    return {"dir": _PERSISTENT_DIR, "files": files, "compiles": compiles,
+            "bypassedDonating": bypassed}
+
+
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache`` — a FIXED path (the directory is part of
+    jax's cache key, so a tempdir, pid or timestamp in it never hits)."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(root, ".jax_cache")
+
+
+def enable_persistent_cache(cache_dir: str = "",
+                            min_compile_secs: float = 1.0) -> str:
+    """The ONE owner of XLA's persistent compilation cache placement;
+    returns the directory in effect.  Where ``JAX_COMPILATION_CACHE_DIR``
+    is set the operator has placed the cache from outside: jax reads the
+    variable itself and nothing here overrides it (a differing
+    ``cache_dir`` — conf ``spark.rapids.sql.tpu.compileCacheDir`` — is
+    ignored with one log line).  Otherwise ``cache_dir`` or, by default,
+    :func:`default_cache_dir`."""
     global _PERSISTENT_DIR
-    if not cache_dir or _PERSISTENT_DIR == cache_dir:
-        return
-    import os
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    env_dir = os.environ.get(_CACHE_ENV)
+    if env_dir:
+        if cache_dir and cache_dir != env_dir and _PERSISTENT_DIR != env_dir:
+            _log.warning("%s=%s is set; ignoring compile cache dir %s",
+                         _CACHE_ENV, env_dir, cache_dir)
+        target = env_dir
+    else:
+        target = cache_dir or default_cache_dir()
+    if _PERSISTENT_DIR == target:
+        return target
+    os.makedirs(target, exist_ok=True)
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", target)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
-    _PERSISTENT_DIR = cache_dir
+    _PERSISTENT_DIR = target
+    return target

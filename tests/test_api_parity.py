@@ -65,7 +65,7 @@ EXEC_PARITY = {
     "UCXShuffleTransport": ("spark_rapids_tpu.parallel.mesh_shuffle",
                             "make_exchange_fn"),
     # fault tolerance: the reference's retry/OOM machinery
-    # (RmmRapidsRetryIterator's withRetry + RetryOOM taxonomy) and the
+    # (RmmRapidsRetryIterator's withRetry + RetryOOM classification) and the
     # task-retry delegation (SURVEY.md section 5) map to the unified
     # fault subsystem
     "RmmRapidsRetryIterator": ("spark_rapids_tpu.fault.retry",

@@ -124,21 +124,29 @@ def test_shared_bucket_policy():
 
 
 def test_pallas_strings_tpu_only(monkeypatch):
-    """Pallas lowering is strictly backend == 'tpu' (plus explicit interp
-    mode); any other accelerator backend takes the XLA formulation."""
+    """Pallas lowering is strictly backend == 'tpu' (plus the explicit
+    interpret conf); any other accelerator backend takes the XLA
+    formulation, and the conf gate turns it off on a TPU too."""
     import jax
 
-    from spark_rapids_tpu.kernels import pallas_strings as PS
-    monkeypatch.delenv("SPARK_RAPIDS_PALLAS_STRINGS", raising=False)
+    from spark_rapids_tpu.config import RapidsConf
+    from spark_rapids_tpu.kernels import pallas_tier as PT
+
+    def engaged(conf=None):
+        PT.configure(RapidsConf(dict(conf or {})))
+        try:
+            return PT.decide("strings").engaged
+        finally:
+            PT.configure(None)
+
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    assert not PS.use_pallas_strings()
+    assert not engaged()
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
-    assert not PS.use_pallas_strings()
+    assert not engaged()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert PS.use_pallas_strings()
-    monkeypatch.setenv("SPARK_RAPIDS_PALLAS_STRINGS", "interp")
+    assert engaged()
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    assert PS.use_pallas_strings()
-    monkeypatch.setenv("SPARK_RAPIDS_PALLAS_STRINGS", "0")
+    assert engaged({"spark.rapids.sql.tpu.pallas.interpret": True})
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert not PS.use_pallas_strings()
+    assert not engaged(
+        {"spark.rapids.sql.tpu.pallas.strings.enabled": False})

@@ -1,4 +1,4 @@
-"""Fault-tolerance subsystem tests: taxonomy, unified retry policy,
+"""Fault-tolerance subsystem tests: error classes, unified retry policy,
 deterministic injection per site x error class, deadline watchdog,
 device-lost recovery with bit-identical replay, and per-partition CPU
 fallback (the reference's "anything the GPU cannot finish must still
@@ -46,7 +46,7 @@ def _clean_rows():
     return sorted(_query(tpu_session()).collect())
 
 
-# -- taxonomy ----------------------------------------------------------------
+# -- error classes ----------------------------------------------------------
 
 
 def test_classify_oom():
@@ -79,6 +79,70 @@ def test_classify_non_retryable():
     # the donated-dispatch tag overrides message classification
     err = mark_non_retryable(_xla_err("RESOURCE_EXHAUSTED: donated"))
     assert classify_error(err) is ErrorClass.NON_RETRYABLE
+
+
+def _mosaic_err(msg):
+    from jax._src.pallas.mosaic.error_handling import MosaicError
+    return MosaicError(msg)
+
+
+def _jax_runtime_err(msg):
+    import jax
+    return jax.errors.JaxRuntimeError(msg)
+
+
+_REFUSAL = "INTERNAL: Mosaic failed to compile TPU kernel: Invalid input layout"
+
+
+@pytest.mark.parametrize("make,msg,phase,want", [
+    (_mosaic_err, _REFUSAL, "bare", ErrorClass.NON_RETRYABLE),
+    (_xla_err, _REFUSAL, "bare", ErrorClass.NON_RETRYABLE),
+    (_mosaic_err, _REFUSAL, "lowering", ErrorClass.NON_RETRYABLE),
+    (_jax_runtime_err, _REFUSAL, "lowering", ErrorClass.NON_RETRYABLE),
+    # pinned by WHERE it was raised, not by its text
+    (_jax_runtime_err, "INTERNAL: opaque", "lowering",
+     ErrorClass.NON_RETRYABLE),
+    (_jax_runtime_err, "INTERNAL: opaque", "executing",
+     ErrorClass.DEVICE_LOST),
+    (_xla_err, "INTERNAL: opaque", "bare", ErrorClass.DEVICE_LOST),
+], ids=["mosaic-bare", "xla-text-bare", "mosaic-lowering", "xla-lowering",
+        "opaque-lowering", "opaque-executing", "opaque-bare"])
+def test_compile_refusal_is_not_a_lost_device(make, msg, phase, want):
+    """A compile refusal reads INTERNAL like a crashed worker, but no
+    replay can fix it: anything raised while a program is lowered or
+    compiled is NON_RETRYABLE; INTERNAL from an EXECUTING program still
+    means the device is gone."""
+    import jax
+    import jax.numpy as jnp
+    from jax.extend.core import Primitive
+    from jax.interpreters import mlir
+
+    from spark_rapids_tpu.utils.compile_registry import instrumented_jit
+    if phase == "bare":
+        assert classify_error(make(msg)) is want
+        return
+    if phase == "lowering":
+        # the raise happens when the ENCLOSING program is lowered, after
+        # tracing succeeded — where a refused Pallas kernel surfaces
+        prim = Primitive(f"refused_kernel_{abs(hash((make, msg)))}")
+        prim.def_abstract_eval(lambda x: x)
+
+        def lower(ctx, x):
+            raise make(msg)
+
+        mlir.register_lowering(prim, lower)
+        program = instrumented_jit(prim.bind, label="refused")
+    else:
+        def boom(x):
+            raise RuntimeError("worker went away")
+
+        program = instrumented_jit(
+            lambda x: jax.pure_callback(
+                boom, jax.ShapeDtypeStruct(x.shape, x.dtype), x),
+            label="lost")
+    with pytest.raises(Exception) as ei:
+        jax.block_until_ready(program(jnp.zeros(4, jnp.float32)))
+    assert classify_error(ei.value) is want, repr(ei.value)
 
 
 def test_retry_policy_deterministic_backoff():

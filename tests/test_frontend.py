@@ -236,7 +236,7 @@ def test_result_cache_cost_weighted_admission():
 def test_admission_sheds_predicted_deadline_miss_before_executing(tmp_path):
     """With >= minRuns history records, a query whose predicted wall
     (median + K*MAD) already misses its deadline is shed at the front
-    door: DeadlineExceeded taxonomy, no execution, per-tenant rollup."""
+    door: DeadlineExceeded classification, no execution, per-tenant rollup."""
     s = _session(**{
         "spark.rapids.sql.tpu.history.dir": str(tmp_path / "h"),
     })

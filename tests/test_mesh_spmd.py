@@ -177,10 +177,7 @@ def test_exchange_batch_collective_unit():
     from spark_rapids_tpu.parallel.mesh_shuffle import (
         exchange_batch_collective, make_mesh,
     )
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(4)
     n = 4
@@ -345,12 +342,14 @@ def test_plan_verify_mesh_fixtures():
 # -- backend honesty ---------------------------------------------------------
 
 
-def test_make_mesh_backend_switch_warns(monkeypatch, caplog):
+def test_make_mesh_never_switches_a_tpu_process_to_cpu(monkeypatch):
     """A default platform too small for the requested mesh silently
     switching to CPU virtual devices is how a bench mislabels CPU scaling
-    as TPU scaling — make_mesh must warn through the explain logger."""
+    as TPU scaling — on a tpu default platform make_mesh raises, even
+    though enough CPU virtual devices exist."""
     import spark_rapids_tpu.parallel.mesh_shuffle as MS
     cpu = jax.devices("cpu")
+    assert len(cpu) >= 4
 
     class FakeDev:
         platform = "tpu"
@@ -361,10 +360,5 @@ def test_make_mesh_backend_switch_warns(monkeypatch, caplog):
         return [FakeDev()]
 
     monkeypatch.setattr(MS.jax, "devices", fake_devices)
-    with caplog.at_level(logging.WARNING,
-                         logger="spark_rapids_tpu.explain"):
-        mesh = MS.make_mesh(4)
-    assert mesh.shape[MS.DATA_AXIS] == 4
-    msgs = [r.getMessage() for r in caplog.records
-            if r.name == "spark_rapids_tpu.explain"]
-    assert any("falling back" in m and "cpu" in m for m in msgs), msgs
+    with pytest.raises(RuntimeError, match="'tpu' has 1"):
+        MS.make_mesh(4)

@@ -14,8 +14,7 @@ def _make_col(strings):
 
 
 @pytest.mark.parametrize("needle", ["ab", "aba", "x", "needle", "zz"])
-def test_pallas_contains_matches_xla(monkeypatch, needle):
-    monkeypatch.setenv("SPARK_RAPIDS_PALLAS_STRINGS", "interp")
+def test_pallas_contains_matches_xla(needle):
     from spark_rapids_tpu.exprs.base import DevVal
     from spark_rapids_tpu.exprs import strings as S
     from spark_rapids_tpu.kernels import pallas_strings as PS
@@ -31,7 +30,8 @@ def test_pallas_contains_matches_xla(monkeypatch, needle):
     v = DevVal(col.dtype, col.data, col.validity, col.offsets)
 
     got = np.asarray(PS.rows_with_match(
-        v.data, v.offsets, v.validity, cap, needle.encode()))
+        v.data, v.offsets, v.validity, cap, needle.encode(),
+        interpret=True))
     want = np.asarray(S._find_matches_reference(v, needle.encode())) \
         if hasattr(S, "_find_matches_reference") else None
     # oracle: python substring check
@@ -43,9 +43,8 @@ def test_pallas_contains_matches_xla(monkeypatch, needle):
         np.testing.assert_array_equal(got, want)
 
 
-def test_pallas_boundary_no_cross(monkeypatch):
+def test_pallas_boundary_no_cross():
     """A needle split across two adjacent rows must NOT match."""
-    monkeypatch.setenv("SPARK_RAPIDS_PALLAS_STRINGS", "interp")
     from spark_rapids_tpu.exprs.base import DevVal
     from spark_rapids_tpu.kernels import pallas_strings as PS
 
@@ -53,6 +52,6 @@ def test_pallas_boundary_no_cross(monkeypatch):
     col, num_rows, cap = _make_col(strs)
     v = DevVal(col.dtype, col.data, col.validity, col.offsets)
     got = np.asarray(PS.rows_with_match(
-        v.data, v.offsets, v.validity, cap, b"ab"))
+        v.data, v.offsets, v.validity, cap, b"ab", interpret=True))
     np.testing.assert_array_equal(
         got[:5], np.array([False, False, True, False, False]))

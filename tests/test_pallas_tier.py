@@ -1,4 +1,4 @@
-"""Pallas kernel tier: interpret-mode parity matrix vs the XLA fallbacks.
+"""Pallas kernel tier: interpret-mode parity matrix vs the XLA formulations.
 
 Every registered kernel must be BIT-identical to the XLA formulation it
 replaces (docs/kernels.md).  On the CPU test backend the kernels engage
@@ -40,8 +40,14 @@ def tier(extra=None):
         PT.configure(None)
 
 
+def on_conf():
+    # three of the four kernels default OFF (the v5e compiler refuses
+    # them — tests/test_chip_compile.py); the parity matrix enables all
+    return {spec.entry.key: True for spec in PT.registered()}
+
+
 def interp_conf():
-    return {INTERPRET_KEY: True}
+    return {INTERPRET_KEY: True, **on_conf()}
 
 
 def off_conf():
@@ -195,15 +201,15 @@ def test_rows_with_match_parity():
     np.testing.assert_array_equal(jax.device_get(got), jax.device_get(want))
 
 
-def test_cpu_without_interpret_silently_falls_back():
-    """Default confs on a non-TPU backend: the XLA formulation runs and
-    each engaged-kernel decision is counted as a fallback."""
+def test_cpu_without_interpret_counts_backend_fallback():
+    """Kernels enabled on a non-TPU backend: the XLA formulation runs
+    (reason "backend") and each such decision is counted."""
     if jax.default_backend() == "tpu":
         pytest.skip("backend fallback only observable off-TPU")
     b = make_batch({"s": (T.STRING, ["fallback", "probe"])})
     c = b.columns[0]
     v = DevVal(c.dtype, c.data, c.validity, c.offsets)
-    with tier({}):  # defaults: kernels on, interpret off
+    with tier(on_conf()):  # kernels on, interpret off
         before = PT.fallback_count()
         got = jax.block_until_ready(S.string_hash2(v))
         assert PT.fallback_count() > before
@@ -220,7 +226,7 @@ def test_decide_reasons():
     with tier(interp_conf()):
         d = PT.decide("stringHash")
         assert d.engaged and d.interpret and d.reason == ""
-    with tier({INTERPRET_KEY: True,
+    with tier({**interp_conf(),
                "spark.rapids.sql.tpu.pallas.vmemBudgetBytes": 1024}):
         d = PT.decide("joinProbe", resident_bytes=4096)
         assert not d.engaged and d.reason == "budget"
@@ -235,19 +241,62 @@ def test_registry_names():
         "gatherScatter", "joinProbe", "stringHash", "strings"]
 
 
-def test_deprecated_strings_env_alias(monkeypatch):
-    # alias applies only while pallas.strings.enabled is not explicitly set
-    monkeypatch.setenv("SPARK_RAPIDS_PALLAS_STRINGS", "0")
-    with tier(interp_conf()):
+def test_gates_are_per_kernel_confs():
+    # each gate is its own conf; the interpret conf alone engages only
+    # what is enabled (defaults: strings on, the refused three off)
+    strings_key = "spark.rapids.sql.tpu.pallas.strings.enabled"
+    with tier({**interp_conf(), strings_key: False}):
         assert not PT.decide("strings").engaged
-        assert PT.decide("stringHash").engaged  # alias is strings-only
-    with tier({**interp_conf(),
-               "spark.rapids.sql.tpu.pallas.strings.enabled": True}):
-        assert PT.decide("strings").engaged  # explicit conf wins
-    monkeypatch.setenv("SPARK_RAPIDS_PALLAS_STRINGS", "interp")
-    with tier({}):
+        assert PT.decide("stringHash").engaged  # gates are independent
+    with tier({INTERPRET_KEY: True}):
         d = PT.decide("strings")
         assert d.engaged and d.interpret
+        assert PT.decide("joinProbe").reason == "off"
+
+
+def test_enabled_kernel_failure_raises_with_its_name(monkeypatch):
+    """No except between an enabled kernel and its caller substitutes
+    the XLA formulation: a trace-time failure raises, naming the kernel,
+    counts no fallback, and classifies NON_RETRYABLE."""
+    from spark_rapids_tpu.fault.errors import ErrorClass, classify_error
+
+    def refuse(*a, **k):
+        raise NotImplementedError("Only 2D gather is supported")
+
+    monkeypatch.setattr(PT, "string_hash_rows", refuse)
+    b = make_batch({"s": (T.STRING, ["refused", "kernel"])})
+    c = b.columns[0]
+    v = DevVal(c.dtype, c.data, c.validity, c.offsets)
+    with tier(interp_conf()):
+        before = PT.fallback_count()
+        with pytest.raises(PT.PallasKernelError, match="stringHash") as ei:
+            S.string_hash2(v)
+        assert PT.fallback_count() == before
+    assert "Only 2D gather" in str(ei.value)
+    assert classify_error(ei.value) is ErrorClass.NON_RETRYABLE
+
+
+def test_compile_refusal_fails_query_without_retry_or_cpu(monkeypatch):
+    """A kernel the backend's compiler refuses fails the QUERY: here the
+    strings kernel is engaged non-interpreted on the CPU backend, which
+    refuses it when the enclosing stage program is lowered (outside any
+    try in the tier).  Default recovery confs (3 attempts, CPU fallback
+    on) must neither replay it as a device loss nor finish it on the
+    host."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the CPU backend's refusal is the injected fault")
+    from spark_rapids_tpu.fault import metrics as FM
+    from spark_rapids_tpu.session import TpuSparkSession
+    monkeypatch.setattr(PT.jax, "default_backend", lambda: "tpu")
+    s = TpuSparkSession(RapidsConf({"spark.rapids.sql.enabled": True}))
+    df = s.create_dataframe({
+        "uniq_refusal_probe_col": ["aa", "abq", None, "b", "xaby"]})
+    before = FM.snapshot()
+    with pytest.raises(ValueError, match="interpret mode") as ei:
+        df.filter(df["uniq_refusal_probe_col"].contains("ab")).collect()
+    assert any("compile refusal" in n for n in ei.value.__notes__)
+    d = FM.delta(before, FM.snapshot())
+    assert not any(d.values()), d
 
 
 def test_session_counts_fallbacks():
