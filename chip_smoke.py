@@ -59,6 +59,13 @@ LIKE_SQL = ("SELECT l_shipmode, count(*) AS n FROM lineitem "
 GROUPBY_SQL = ("SELECT l_orderkey, sum(l_quantity) AS qty, count(*) AS n "
                "FROM lineitem GROUP BY l_orderkey")
 
+#: The script must end within 1200 s (compilation included).  The LIKE
+#: probe takes ~195 s cold on one v5e chip (PR 23, builder's run), so it
+#: runs only if it can start by this many seconds; on a slow host it is
+#: skipped, on a printed line, rather than risking the TPC-H verdict.
+BUDGET_S = 1200.0
+LIKE_START_BY_S = BUDGET_S - 300.0
+
 MUST_BE_ZERO = ("retryCount", "deviceLostCount", "partitionFallbackCount",
                 "pallasFallbackCount")
 
@@ -276,11 +283,18 @@ def check_residency(session, runtime) -> dict:
     return {"batches": n_batches, "arrays": n_arrays, "bytes": nbytes}
 
 
-def one_chip(session, refs, queries, sql) -> str:
+def one_chip(session, refs, queries, sql, t_start: float) -> str:
     """Runs the one-chip phases; returns the device runtime's platform."""
     from spark_rapids_tpu.runtime.device import DeviceRuntime
-    for q in [*queries, "like"]:
+    for q in queries:
         run_query(session, q, sql, refs)
+    elapsed = time.monotonic() - t_start
+    if elapsed <= LIKE_START_BY_S:
+        run_query(session, "like", sql, refs)
+    else:
+        emit("skipped", query="like", elapsed_s=elapsed,
+             reason=f"not started after {LIKE_START_BY_S:g} s of the "
+                    f"{BUDGET_S:g} s this script may run")
     runtime = DeviceRuntime.get(session.conf)
     resident = check_residency(session, runtime)
     stats = runtime.device.memory_stats() or {}
@@ -426,7 +440,7 @@ def main(argv=None) -> int:
         if args.chips == 4:
             ran_on = four_chips(session, refs, sql)
         else:
-            ran_on = one_chip(session, refs, queries, sql)
+            ran_on = one_chip(session, refs, queries, sql, t_start)
 
     emit("cache", **CR.persistent_cache_stats())
     # success only if jax's default device AND the engine's runtime (or
