@@ -355,7 +355,7 @@ out = subprocess.run(
     capture_output=True, text=True, timeout=300)
 assert out.returncode == 0, f"rapidstop failed:\n{out.stdout}{out.stderr}"
 assert "telemetry:" in out.stdout, out.stdout
-assert "dispatch" in out.stdout, out.stdout
+assert "enqueue" in out.stdout, out.stdout
 
 prom = subprocess.run(
     [sys.executable, "tools/rapidstop.py", tpath, "--prom"],
@@ -368,12 +368,12 @@ for line in prom.stdout.strip().splitlines():
         continue
     name, val = line.rsplit(" ", 1)
     float(val)  # every sample parses
-    if name == 'rapids_site_wall_ns_total{site="dispatch"}':
+    if name == 'rapids_site_wall_ns_total{site="enqueue"}':
         wall = float(val)
-assert wall > 0, f"no dispatch wall in Prometheus export:\n{prom.stdout}"
+assert wall > 0, f"no enqueue wall in Prometheus export:\n{prom.stdout}"
 print("telemetry smoke ok:", {
     "intervals": s.last_metrics["telemetryIntervals"],
-    "dispatch_wall_ms": round(wall / 1e6, 2)})
+    "enqueue_wall_ms": round(wall / 1e6, 2)})
 PY
 
 echo "== sentinel smoke: injected dispatch:slow regression must flag"

@@ -339,7 +339,8 @@ class ColumnBatch:
         return f"ColumnBatch({self.schema}, cap={self.capacity})"
 
     def host_num_rows(self) -> int:
-        return int(jax.device_get(self.num_rows))
+        from spark_rapids_tpu.utils.tracing import device_read
+        return int(device_read("num_rows", self.num_rows))
 
 
 jax.tree_util.register_pytree_node(
@@ -443,57 +444,76 @@ def host_column_to_device(col: HostColumn, capacity: int,
 
 def host_to_device(batch: HostBatch, capacity: Optional[int] = None,
                    device=None) -> ColumnBatch:
-    import time
-
     from spark_rapids_tpu.fault import inject
     from spark_rapids_tpu.utils.compile_registry import record_transfer
+    from spark_rapids_tpu.utils.tracing import span
     inject.maybe_fire("h2d")
-    t0 = time.monotonic_ns()
-    cap = capacity if capacity is not None else round_up_capacity(batch.num_rows)
-    cols = [host_column_to_device(c, cap, device) for c in batch.columns]
-    num_rows = jnp.asarray(batch.num_rows, dtype=jnp.int32)
-    if device is not None:
-        num_rows = jax.device_put(num_rows, device)
-    out = ColumnBatch(batch.schema, cols, num_rows, cap)
-    nbytes = sum(getattr(leaf, "nbytes", 0)
-                 for leaf in jax.tree_util.tree_leaves(out))
+    with span("h2d", "transfer") as sp:
+        cap = capacity if capacity is not None \
+            else round_up_capacity(batch.num_rows)
+        cols = [host_column_to_device(c, cap, device) for c in batch.columns]
+        num_rows = jnp.asarray(batch.num_rows, dtype=jnp.int32)
+        if device is not None:
+            num_rows = jax.device_put(num_rows, device)
+        out = ColumnBatch(batch.schema, cols, num_rows, cap)
+        nbytes = sum(getattr(leaf, "nbytes", 0)
+                     for leaf in jax.tree_util.tree_leaves(out))
+        sp.set(bytes=nbytes)
     # enqueue-side wall: device_put is async on real TPUs, so h2dTimeNs
     # is host-pack + transfer-enqueue time (h2d_gb_per_sec reads as an
     # upper bound there; exact on the synchronous CPU backend).  Blocking
     # here for accuracy would serialize staging against device compute —
-    # the overlap this layer exists to create (same lower-bound policy as
-    # dispatch wall vs. metrics.detailEnabled).
-    record_transfer("h2d", nbytes, time.monotonic_ns() - t0)
+    # the overlap this layer exists to create.  The df.cache() staging
+    # path, where nothing overlaps it, ends its own h2d span in a sync
+    # (ops.tpu_exec.TpuCachedScanExec).
+    record_transfer("h2d", nbytes, sp.elapsed_ns)
     return out
 
 
 def device_to_host_many(batches: Sequence[ColumnBatch],
                         keep_dictionary: bool = False) -> List[HostBatch]:
-    # ONE bulk device_get for all batches' buffers AND num_rows scalars:
-    # jax prefetches every leaf with copy_to_host_async before blocking, so
-    # the whole pytree rides a single sync + round trip.  Per-column gets
-    # serialize one round trip each, which dominated query wall time
-    # (see profile_bench.py).
-    import time
-
+    # ONE bulk round trip for all batches' buffers AND num_rows scalars:
+    # every leaf's copy is started with copy_to_host_async (as
+    # jax.device_get does) before anything blocks, so the whole pytree
+    # rides a single sync.  Per-column gets serialize one round trip
+    # each, which dominated query wall time (see profile_bench.py).
+    # The wait is split by cause: ``device_wait`` ends when the programs
+    # that produce the buffers have finished (block_until_ready — it
+    # would block there anyway), ``d2h`` times what is left of the copy.
     from spark_rapids_tpu.fault import inject
     from spark_rapids_tpu.utils.compile_registry import (
         guard_check, record_transfer,
     )
+    from spark_rapids_tpu.utils.tracing import device_wait, span
     inject.maybe_fire("d2h")
     guard_check(list(batches), "device_to_host_many")
-    t0 = time.monotonic_ns()
-    host = jax.device_get([
+    tree = [
         (b.num_rows,
          [(c.data, c.validity, c.offsets, c.codes) if c.codes is not None
           else (c.data, c.validity, c.offsets) if c.offsets is not None
           else (c.data, c.validity) for c in b.columns])
-        for b in batches])
-    nbytes = sum(
-        buf.nbytes
-        for _num_rows, col_bufs in host
-        for bufs in col_bufs for buf in bufs)
-    record_transfer("d2h", nbytes, time.monotonic_ns() - t0)
+        for b in batches]
+    for leaf in jax.tree_util.tree_leaves(tree):
+        start = getattr(leaf, "copy_to_host_async", None)
+        if start is not None:
+            start()
+    device_wait("d2h_ready", tree)
+    with span("d2h", "transfer") as sp:
+        host = jax.device_get(tree)
+        nbytes = sum(
+            buf.nbytes
+            for _num_rows, col_bufs in host
+            for bufs in col_bufs for buf in bufs)
+        sp.set(bytes=nbytes)
+    record_transfer("d2h", nbytes, sp.elapsed_ns)
+    with span("result", "assemble"):
+        return _host_batches(batches, host, keep_dictionary)
+
+
+def _host_batches(batches: Sequence[ColumnBatch], host,
+                  keep_dictionary: bool) -> List[HostBatch]:
+    """HostBatches from the fetched buffers (padding trimmed, strings and
+    arrays decoded into Python objects)."""
     out = []
     for batch, (num_rows, col_bufs) in zip(batches, host):
         n = int(num_rows)
@@ -582,6 +602,7 @@ def host_sizes(batches: Sequence[ColumnBatch]) -> List[Tuple[int, List[int]]]:
     constant past num_rows by construction.
     """
     from spark_rapids_tpu.utils.compile_registry import guard_check
+    from spark_rapids_tpu.utils.tracing import device_read
     guard_check(list(batches), "host_sizes")
 
     def _varlen_total(c):
@@ -598,7 +619,9 @@ def host_sizes(batches: Sequence[ColumnBatch]) -> List[Tuple[int, List[int]]]:
     scalars = [(b.num_rows,
                 [_varlen_total(c) for c in b.columns if c.is_varlen])
                for b in batches]
-    host = jax.device_get(scalars)
+    # the scalars come out of programs still in flight: this read-back is
+    # where the host waits for the chip
+    host = device_read("host_sizes", scalars)
     return [(int(n), [int(t) for t in totals]) for n, totals in host]
 
 

@@ -9,6 +9,7 @@ numpy oracle with Spark CPU semantics.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -152,6 +153,14 @@ def _fp(v) -> str:
     return f"id:{id(v):x}"
 
 
+def _scoped_eval(tpu_eval, scope: str):
+    @functools.wraps(tpu_eval)
+    def run(self, ctx):
+        with jax.named_scope(scope):
+            return tpu_eval(self, ctx)
+    return run
+
+
 class Expression:
     """Declarative expression tree node.
 
@@ -162,6 +171,16 @@ class Expression:
     children: Tuple["Expression", ...] = ()
     dtype: T.DataType = T.NULL
     nullable: bool = True
+
+    def __init_subclass__(cls, **kwargs):
+        # every expression class traces its device evaluation under
+        # ``jax.named_scope("e.<Class>")``: a device trace can then say
+        # which expression an operator's time went to (a per-row
+        # ``e.ToDate`` over a literal was q6's largest cost, PERF.md)
+        super().__init_subclass__(**kwargs)
+        own = cls.__dict__.get("tpu_eval")
+        if own is not None:
+            cls.tpu_eval = _scoped_eval(own, f"e.{cls.__name__}")
 
     # -- construction sugar used by the DataFrame frontend ------------------
 

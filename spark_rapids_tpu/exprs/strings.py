@@ -31,6 +31,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.exprs.base import (
     CpuVal, DevVal, Expression, Literal, UnaryExpression,
 )
+from spark_rapids_tpu.utils.tracing import kernel_scope
 
 # ---------------------------------------------------------------------------
 # Primitives
@@ -77,6 +78,7 @@ def _pow_table(base: int, n: int):
     return out
 
 
+@kernel_scope
 def string_hash2(v: DevVal) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dual 32-bit polynomial row hashes: h = sum byte[i] * base^(end-1-i)
     (mod 2^32).  Equality tests combine both hashes + length (+ the 64-byte
@@ -143,6 +145,7 @@ def hash_literal2(s: str) -> Tuple[int, int]:
     return out[0], out[1]
 
 
+@kernel_scope
 def build_string(dtype, new_lens, src_index_fn, out_byte_cap: int,
                  validity) -> DevVal:
     """Materialize a string column from per-row output lengths.
@@ -164,6 +167,7 @@ def build_string(dtype, new_lens, src_index_fn, out_byte_cap: int,
     return DevVal(dtype, data, validity, offsets)
 
 
+@kernel_scope
 def _gather_substring(v: DevVal, starts, new_lens, out_byte_cap: int,
                       validity) -> DevVal:
     """Common shape: every output row is a contiguous slice of its input row."""
@@ -177,6 +181,7 @@ def _gather_substring(v: DevVal, starts, new_lens, out_byte_cap: int,
     return build_string(T.STRING, new_lens, src, out_byte_cap, validity)
 
 
+@kernel_scope
 def _find_matches(v: DevVal, needle: bytes):
     """bool[nbytes]: needle match beginning at each byte position, fully
     inside the owning row."""

@@ -47,13 +47,10 @@ class RetryPolicy:
         d = self.delay_s(attempt)
         if d <= 0:
             return
-        t0 = time.monotonic_ns()
-        time.sleep(d)
-        t1 = time.monotonic_ns()
-        fault_metrics.record("backoff_wall_ns", t1 - t0)
-        from spark_rapids_tpu.obs import events as obs_events
-        obs_events.emit_span("retry", "backoff", t0=t0, t1=t1,
-                             attempt=attempt)
+        from spark_rapids_tpu.utils.tracing import span
+        with span("retry", "backoff", attempt=attempt) as sp:
+            time.sleep(d)
+        fault_metrics.record("backoff_wall_ns", sp.elapsed_ns)
 
     def __repr__(self):
         return (f"RetryPolicy(max_attempts={self.max_attempts}, "

@@ -103,7 +103,11 @@ class partition_deadline:
         with self._lock:
             self._done = True
         self._cancel.set()
-        self._thread.join(timeout=1.0)
+        # the monitor wakes and exits: a thread hand-off the query waits
+        # for on every partition/collect, so it is on the timeline
+        from spark_rapids_tpu.utils.tracing import span
+        with span("fault", "watchdog_join"):
+            self._thread.join(timeout=1.0)
         if self.fired:
             if exc_type is None:
                 # fired in the gap between the body's last bytecode and

@@ -4,7 +4,6 @@ SURVEY.md section 7)."""
 
 from __future__ import annotations
 
-import time
 from typing import List, Optional
 
 import numpy as np
@@ -12,7 +11,7 @@ import pyarrow as pa
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import HostBatch, HostColumn
-from spark_rapids_tpu.obs import events as obs_events
+from spark_rapids_tpu.utils.tracing import span
 
 _ARROW_TO_TYPE = {
     pa.bool_(): T.BOOLEAN,
@@ -72,7 +71,14 @@ def _dict_host_column(f: T.Field, arr: "pa.DictionaryArray") -> HostColumn:
 
 def arrow_to_host_batch(table_or_batch, schema: Optional[T.Schema] = None,
                         keep_dictionary: bool = False) -> HostBatch:
-    t0 = time.monotonic_ns()
+    with span("io", "arrow_convert") as sp:
+        hb = _arrow_to_host_batch(table_or_batch, schema, keep_dictionary)
+        sp.set(rows=hb.num_rows, columns=len(hb.columns))
+    return hb
+
+
+def _arrow_to_host_batch(table_or_batch, schema: Optional[T.Schema],
+                         keep_dictionary: bool) -> HostBatch:
     tb = table_or_batch
     if isinstance(tb, pa.Table):
         tb = tb.combine_chunks()
@@ -121,11 +127,7 @@ def arrow_to_host_batch(table_or_batch, schema: Optional[T.Schema] = None,
                 values = np.where(validity, np.nan_to_num(values), 0)
             values = values.astype(f.dtype.np_dtype, copy=False)
         cols.append(HostColumn(f.dtype, values, validity))
-    hb = HostBatch(schema, cols)
-    obs_events.emit_span("io", "arrow_convert", t0=t0,
-                         t1=time.monotonic_ns(), rows=tb.num_rows,
-                         columns=len(cols))
-    return hb
+    return HostBatch(schema, cols)
 
 
 def host_batch_to_arrow(hb: HostBatch) -> pa.Table:

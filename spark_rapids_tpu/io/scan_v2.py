@@ -52,9 +52,9 @@ from spark_rapids_tpu.io.decode_pool import (
 )
 from spark_rapids_tpu.io.discovery import csv_options
 from spark_rapids_tpu.io.scan import CpuFileScanExec, _row_group_can_match
-from spark_rapids_tpu.obs import events as obs_events
 from spark_rapids_tpu.obs import timeseries as obs_ts
 from spark_rapids_tpu.plan.physical import ExecContext
+from spark_rapids_tpu.utils.tracing import annotated, record_span, span
 
 #: Decoded-and-ready chunks held beyond the one being consumed — chunk k
 #: on device, k+1 staged on host, k+2..k+1+depth decoding: the classic
@@ -527,8 +527,11 @@ class FileScanV2Exec(CpuFileScanExec):
                 stats["dict"] += res.dict_columns
                 stats["rg_read"] += res.rg_read
                 stats["rg_total"] += res.rg_total
-                obs_events.emit_span(
-                    "scan", "chunk", op_id=self.op_id, t0=res.t0, t1=res.t1,
+                # the worker timed the decode (and opened its profiler
+                # range, see ``annotated`` at the submit below); the
+                # consumer owns the query scope, so the ring entry is here
+                record_span(
+                    "scan", "chunk", self.op_id, res.t0, res.t1,
                     label=res.label, bytes=res.bytes_decoded,
                     skipped=res.skipped)
                 return res
@@ -557,10 +560,10 @@ class FileScanV2Exec(CpuFileScanExec):
 
             def drain_blocking() -> _ChunkResult:
                 entry = pending.popleft()
-                w0 = time.monotonic_ns()
-                for fu in entry[1]:
-                    fu.result()
-                res = finish_entry(entry, time.monotonic_ns() - w0)
+                with span("scan", "wait_decode", self.op_id) as sp:
+                    for fu in entry[1]:
+                        fu.result()
+                res = finish_entry(entry, sp.elapsed_ns)
                 adapt()
                 return res
 
@@ -578,7 +581,9 @@ class FileScanV2Exec(CpuFileScanExec):
                     # numbering AND the active query's scoped registry
                     # (pool workers carry no obs scope)
                     inject.maybe_fire("scan")
-                    pending.append((path, [pool.submit(t) for t in tasks]))
+                    pending.append((path, [
+                        pool.submit(annotated("scan", "chunk", t))
+                        for t in tasks]))
                     harvest()
                     while len(pending) >= stats["depth"]:
                         if ready:

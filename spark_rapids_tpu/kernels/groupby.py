@@ -23,6 +23,7 @@ from spark_rapids_tpu.kernels.layout import (
 )
 from spark_rapids_tpu.kernels.sort import argsort_batch
 from spark_rapids_tpu.kernels.sortkeys import keys_equal_prev
+from spark_rapids_tpu.utils.tracing import kernel_scope
 
 
 @dataclasses.dataclass
@@ -36,6 +37,7 @@ class GroupSegments:
     live: jnp.ndarray        # bool[cap] sorted-row liveness
 
 
+@kernel_scope
 def group_segments(key_vals: List[DevVal], num_rows) -> GroupSegments:
     """Sort rows by key and mark exact group boundaries."""
     cap = int(key_vals[0].validity.shape[0])
@@ -65,6 +67,7 @@ def group_segments(key_vals: List[DevVal], num_rows) -> GroupSegments:
     return GroupSegments(perm, seg_ids, seg_start, num_groups, live)
 
 
+@kernel_scope
 def groupby_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
                       agg_inputs: List[DevVal], agg_fns: Sequence,
                       merge: bool,

@@ -51,6 +51,7 @@ from spark_rapids_tpu.batch import (
 )
 from spark_rapids_tpu.exprs.base import DevVal
 from spark_rapids_tpu.kernels.layout import compaction_indices
+from spark_rapids_tpu.utils.tracing import kernel_scope
 
 TABLE_SLOTS = 8192          # key-range capacity of the slot table
 _CHUNK = 16384              # rows per exact-f32 accumulation chunk
@@ -104,6 +105,7 @@ def _float_limb_rows(x, use, nc: int, c: int):
     return rows, scale
 
 
+@kernel_scope
 def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
                          agg_inputs: List[DevVal], agg_fns: Sequence,
                          key_schema: T.Schema,

@@ -30,6 +30,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import ColumnBatch, round_up_capacity
 from spark_rapids_tpu.utils.compile_registry import instrumented_jit
+from spark_rapids_tpu.utils.tracing import device_read, kernel_scope
 from spark_rapids_tpu.exprs.base import DevVal
 from spark_rapids_tpu.kernels.layout import (
     compaction_indices, ensure_row_layout, gather_rows,
@@ -298,6 +299,7 @@ class JoinSizing:
     build_cap: int
 
 
+@kernel_scope
 def _phase1(probe_h1, probe_ok, probe_live, build_sorted_h1, build_live_n):
     # candidate ranges on h1 only (h2 + exact keys verified in phase 2)
     lo = jnp.searchsorted(build_sorted_h1, probe_h1, side="left")
@@ -309,6 +311,7 @@ def _phase1(probe_h1, probe_ok, probe_live, build_sorted_h1, build_live_n):
 _phase1_jit = instrumented_jit(_phase1, label="join:phase1")
 
 
+@kernel_scope
 def _build_sort(h1, h2):
     cap = int(h1.shape[0])
     iota = jnp.arange(cap, dtype=jnp.int32)
@@ -319,6 +322,7 @@ def _build_sort(h1, h2):
 _build_sort_jit = instrumented_jit(_build_sort, label="join:build_sort")
 
 
+@kernel_scope
 def join_pairs(left_keys: List[DevVal], left_num_rows,
                right_keys: List[DevVal], right_num_rows,
                pair_cap_hint: Optional[int] = None):
@@ -356,7 +360,7 @@ def join_pairs(left_keys: List[DevVal], left_num_rows,
     lo, counts, total = _phase1_jit(l_h1, l_ok, l_live, r_sorted,
                                     right_num_rows)
 
-    total_pairs = int(jax.device_get(total))
+    total_pairs = int(device_read("join_pairs", total))
     pair_cap = round_up_capacity(max(total_pairs, 1))
     if pair_cap_hint is not None:
         pair_cap = max(pair_cap, pair_cap_hint)
@@ -398,6 +402,7 @@ def join_pairs(left_keys: List[DevVal], left_num_rows,
                   total)
 
 
+@kernel_scope
 def join_pairs_static(left_keys: List[DevVal], left_num_rows,
                       right_keys: List[DevVal], right_num_rows,
                       pair_cap: int):
@@ -524,6 +529,7 @@ def _caps_overflow(needs: List[jnp.ndarray], caps: List[int]):
     return ovf
 
 
+@kernel_scope
 def stitch_join_output_static(left: ColumnBatch, right: ColumnBatch,
                               l_idx, r_idx, n_pairs, l_counts, r_matched,
                               join_type: str, out_schema: T.Schema,
@@ -661,7 +667,7 @@ def _string_byte_caps(batch: ColumnBatch, indices, live) -> List[int]:
                 lens = (c.offsets[1:] - c.offsets[:-1]).astype(jnp.int64)
             total = jnp.sum(jnp.where(live, lens[jnp.clip(
                 indices, 0, batch.capacity - 1)], 0))
-            caps.append(round_up_capacity(int(jax.device_get(total)),
+            caps.append(round_up_capacity(int(device_read("join_bytes", total)),
                                           minimum=16))
     return caps
 
@@ -726,6 +732,7 @@ def hash_join(left: ColumnBatch, left_keys: List[DevVal],
                               r_matched, join_type, out_schema)
 
 
+@kernel_scope
 def stitch_join_output(left: ColumnBatch, right: ColumnBatch, l_idx, r_idx,
                        n_pairs, l_counts, r_matched, join_type: str,
                        out_schema: T.Schema) -> ColumnBatch:
@@ -767,7 +774,7 @@ def stitch_join_output(left: ColumnBatch, right: ColumnBatch, l_idx, r_idx,
         n_un_l = jnp.sum(un_l_mask).astype(jnp.int32)
         n_un_r = jnp.sum(un_r_mask).astype(jnp.int32)
         total = n_pairs + n_un_l + n_un_r
-        total_h = int(jax.device_get(total))
+        total_h = int(device_read("join_rows", total))
         out_cap = round_up_capacity(max(total_h, 1))
 
         un_l_idx, _ = compaction_indices(un_l_mask, left.num_rows)
@@ -825,8 +832,8 @@ def _cross_pairs(left: ColumnBatch, right: ColumnBatch, condition):
     (l_idx, r_idx, n_pairs, l_counts, r_matched).  Pair capacity is
     n_l * n_r — callers bound it by chunking the left side."""
     l_cap, r_cap = left.capacity, right.capacity
-    n_l = int(jax.device_get(left.num_rows))
-    n_r = int(jax.device_get(right.num_rows))
+    n_l, n_r = (int(n) for n in device_read(
+        "join_sides", (left.num_rows, right.num_rows)))
     total = n_l * n_r
     pair_cap = round_up_capacity(max(total, 1))
     i = jnp.arange(pair_cap, dtype=jnp.int32)

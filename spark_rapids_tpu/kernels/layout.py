@@ -19,6 +19,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import (
     ColumnBatch, DeviceColumn, round_up_capacity,
 )
+from spark_rapids_tpu.utils.tracing import device_read, kernel_scope
 
 
 def _string_lengths(col: DeviceColumn):
@@ -57,6 +58,7 @@ def _gather_string_column(col: DeviceColumn, indices, live, out_cap: int,
     return DeviceColumn(col.dtype, data, validity, new_offsets)
 
 
+@kernel_scope
 def gather_rows(batch: ColumnBatch, indices, num_rows,
                 out_capacity: Optional[int] = None,
                 out_byte_caps: Optional[Sequence[int]] = None,
@@ -103,6 +105,7 @@ def gather_rows(batch: ColumnBatch, indices, num_rows,
                        out_cap)
 
 
+@kernel_scope
 def dict_decode_column(col: DeviceColumn) -> DeviceColumn:
     """Materialize a dictionary-encoded string column to plain row layout.
 
@@ -152,7 +155,8 @@ def row_slices(batch: ColumnBatch, total_rows: int, rows_per: int):
     offsets; slices past ``total_rows`` are not produced."""
     bounds = list(range(0, total_rows, max(rows_per, 1))) + [total_rows]
     varlen = [c for c in batch.columns if c.is_varlen]
-    marks = jax.device_get(
+    marks = device_read(
+        "slice_marks",
         [c.offsets[jnp.asarray(bounds, jnp.int32)] for c in varlen]) \
         if varlen else []
     for i in range(len(bounds) - 1):
@@ -165,6 +169,7 @@ def row_slices(batch: ColumnBatch, total_rows: int, rows_per: int):
                           out_capacity=pcap, out_byte_caps=bcaps or None)
 
 
+@kernel_scope
 def compaction_indices(mask, num_rows):
     """(indices, count): stable order of rows where mask is True and live.
 
@@ -191,6 +196,7 @@ def compaction_indices(mask, num_rows):
     return idx, count.astype(jnp.int32)
 
 
+@kernel_scope
 def compact(batch: ColumnBatch, mask) -> ColumnBatch:
     """Filter: keep rows where mask (bool[cap]) is True.  Single-phase —
     output capacity = input capacity (a filter can only shrink)."""
@@ -244,6 +250,7 @@ def _pack_kway(vals_list, los, his, out_cap: int):
         xla, resident_bytes=resident)
 
 
+@kernel_scope
 def concat_kway(batches: Sequence[ColumnBatch], out_capacity: int,
                 out_byte_caps: Optional[Sequence[int]] = None) -> ColumnBatch:
     """Concatenate k batches (same schema) into ONE output allocation.
@@ -333,6 +340,7 @@ def concat_kway_run(batches: Sequence[ColumnBatch], out_capacity: int,
 _CONCAT_KWAY_JIT = None
 
 
+@kernel_scope
 def gather_segments_kway(batches: Sequence[ColumnBatch], starts, counts,
                          out_capacity: int,
                          out_byte_caps: Optional[Sequence[int]] = None,

@@ -49,7 +49,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
-import time
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -163,18 +162,16 @@ def run(name: str, pallas_fn: Callable, fallback_fn: Callable,
         if d.reason != "off":
             _note_fallback()
         return fallback_fn()
-    from spark_rapids_tpu.obs.events import emit_span
-    t0 = time.monotonic_ns()
-    try:
-        out = pallas_fn(d.interpret)
-    except Exception as e:
-        raise PallasKernelError(
-            f"Pallas kernel '{name}' is enabled "
-            f"({_KERNELS[name].entry.key}) but "
-            f"failed to trace: {type(e).__name__}: {e}") from e
-    emit_span("pallas", name, t0=t0, t1=time.monotonic_ns(),
-              interpret=d.interpret, resident_bytes=resident_bytes)
-    return out
+    from spark_rapids_tpu.utils.tracing import span
+    with span("pallas", name, interpret=d.interpret,
+              resident_bytes=resident_bytes):
+        try:
+            return pallas_fn(d.interpret)
+        except Exception as e:
+            raise PallasKernelError(
+                f"Pallas kernel '{name}' is enabled "
+                f"({_KERNELS[name].entry.key}) but "
+                f"failed to trace: {type(e).__name__}: {e}") from e
 
 
 # ---------------------------------------------------------------------------

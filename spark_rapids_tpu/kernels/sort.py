@@ -15,8 +15,10 @@ from spark_rapids_tpu.kernels.sortkeys import (
     argsort_by_words,
     encode_sort_keys,
 )
+from spark_rapids_tpu.utils.tracing import kernel_scope
 
 
+@kernel_scope
 def argsort_batch(key_vals: List[DevVal], ascendings: List[bool],
                   nulls_firsts: List[bool], num_rows,
                   string_prefix_bytes: int = DEFAULT_STRING_PREFIX_BYTES,
@@ -32,6 +34,7 @@ def argsort_batch(key_vals: List[DevVal], ascendings: List[bool],
     return argsort_by_words(words, cap)
 
 
+@kernel_scope
 def sort_batch(batch: ColumnBatch, key_vals: List[DevVal],
                ascendings: List[bool], nulls_firsts: List[bool],
                string_prefix_bytes: int = DEFAULT_STRING_PREFIX_BYTES

@@ -43,6 +43,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import DeviceColumn
 from spark_rapids_tpu.exprs.base import DevVal
+from spark_rapids_tpu.utils.tracing import kernel_scope
 
 DEFAULT_STRING_PREFIX_BYTES = 64
 
@@ -170,6 +171,7 @@ def string_prefix_words(col_or_val, prefix_bytes: int) -> List[jnp.ndarray]:
     return words
 
 
+@kernel_scope
 def encode_sort_keys(vals: List[DevVal], ascendings: List[bool],
                      nulls_firsts: List[bool], num_rows,
                      string_prefix_bytes: int = DEFAULT_STRING_PREFIX_BYTES,
@@ -240,6 +242,7 @@ def encode_sort_keys(vals: List[DevVal], ascendings: List[bool],
 _DIRECT_SORT_MAX_WORDS_TPU = 1
 
 
+@kernel_scope
 def argsort_by_words(words: List[jnp.ndarray], cap: int) -> jnp.ndarray:
     """Stable permutation (int32[cap]) ordering rows by the word tuple."""
     iota = jnp.arange(cap, dtype=jnp.int32)

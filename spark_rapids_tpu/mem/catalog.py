@@ -62,6 +62,7 @@ from spark_rapids_tpu.config import (
     conf_bytes,
 )
 from spark_rapids_tpu.obs import events as obs_events
+from spark_rapids_tpu.utils.tracing import span
 
 DEVICE_SPILL_BUDGET = conf_bytes(
     "spark.rapids.memory.tpu.spillBudgetBytes", 8 << 30,
@@ -275,26 +276,35 @@ class SpillableBatch:
         transitions and counters update under it."""
         from spark_rapids_tpu.fault import inject
         cat = self._catalog
-        un_t0 = time.monotonic_ns()
-        inject.maybe_fire("unspill")
-        host = self._read_disk() if tier == self.TIER_DISK else self._host
-        with cat._lock:
-            raced = self.tier != tier
+        with span("unspill",
+                  "disk" if tier == self.TIER_DISK else "host") as un_span:
+            inject.maybe_fire("unspill")
+            host = self._read_disk() if tier == self.TIER_DISK \
+                else self._host
+            with cat._lock:
+                raced = self.tier != tier
+                if not raced:
+                    # Mark device-resident BEFORE reserving so the budget
+                    # loop cannot pick this handle as its own spill victim
+                    # mid-rehydration; keep the host copy until the upload
+                    # lands so a failure can revert.
+                    if tier == self.TIER_HOST:
+                        cat._host_bytes -= self._host_nbytes
+                    self.tier = self.TIER_DEVICE
+                    cat._device_bytes += self.device_bytes
+                    cat.metrics["unspilled"] += 1
             if not raced:
-                # Mark device-resident BEFORE reserving so the budget loop
-                # cannot pick this handle as its own spill victim
-                # mid-rehydration; keep the host copy until the upload
-                # lands so a failure can revert.
-                if tier == self.TIER_HOST:
-                    cat._host_bytes -= self._host_nbytes
-                self.tier = self.TIER_DEVICE
-                cat._device_bytes += self.device_bytes
-                cat.metrics["unspilled"] += 1
-        if raced:
-            # lost to a concurrent get()/spill that moved the handle:
-            # retry the state machine from the top, OUTSIDE the lock (the
-            # retry may join a writer task that needs it)
-            return self.get()
+                return self._rehydrate(tier, host, un_span)
+            un_span.set(raced=True)
+        # lost to a concurrent get()/spill that moved the handle: retry
+        # the state machine from the top, OUTSIDE the lock (the retry may
+        # join a writer task that needs it)
+        return self.get()
+
+    def _rehydrate(self, tier: int, host, un_span) -> ColumnBatch:
+        """The upload half of :meth:`_unspill`, with the tier already
+        marked device-resident; a failure reverts that."""
+        cat = self._catalog
         try:
             cat.reserve(self.device_bytes, exclude=self.batch_id)
             dev = host_to_device(host, capacity=self._capacity)
@@ -315,9 +325,7 @@ class SpillableBatch:
             if os.path.exists(self._disk_path):
                 os.unlink(self._disk_path)
             self._disk_path = None
-        obs_events.emit_span(
-            "unspill", "disk" if tier == self.TIER_DISK else "host",
-            t0=un_t0, t1=time.monotonic_ns(), bytes=self.device_bytes)
+        un_span.set(bytes=self.device_bytes)
         return dev
 
     def close(self):
@@ -573,34 +581,33 @@ class BufferCatalog:
         the error on the handle for the consumer's next ``get()``.
         """
         h = task.handle
-        t0 = time.monotonic_ns()
         with self._lock:
             if task.state != _SpillTask.QUEUED:
                 return  # cancelled while queued
             task.state = _SpillTask.RUNNING
             dev = h._device
+        from spark_rapids_tpu.fault import inject
+        sp = span("spill", "to_host")
         try:
-            from spark_rapids_tpu.fault import inject
-            inject.maybe_fire("spill")
-            host = device_to_host(dev, keep_dictionary=True)
-            nbytes = host_batch_bytes(host)
-            with self._lock:
-                live = h._spill_task is task and \
-                    h.tier == SpillableBatch.TIER_SPILLING and not h.closed
-                if live:
-                    h._host = host
-                    h._host_nbytes = nbytes
-                    h._device = None
-                    h.tier = SpillableBatch.TIER_HOST
-                    self._host_bytes += nbytes
-                    self.metrics["spill_to_host_bytes"] += nbytes
-                    # the copy is safe on host now: an earlier attempt's
-                    # stashed failure is moot, don't fail a later get()
-                    h._pending_error = None
-                # else: aborted (invalidate/close) mid-copy — drop the copy
-            obs_events.emit_span("spill", "to_host", t0=t0,
-                                 t1=time.monotonic_ns(),
-                                 bytes=nbytes if live else 0)
+            with sp:
+                inject.maybe_fire("spill")
+                host = device_to_host(dev, keep_dictionary=True)
+                nbytes = host_batch_bytes(host)
+                with self._lock:
+                    live = h._spill_task is task and \
+                        h.tier == SpillableBatch.TIER_SPILLING and not h.closed
+                    if live:
+                        h._host = host
+                        h._host_nbytes = nbytes
+                        h._device = None
+                        h.tier = SpillableBatch.TIER_HOST
+                        self._host_bytes += nbytes
+                        self.metrics["spill_to_host_bytes"] += nbytes
+                        # the copy is safe on host now: an earlier attempt's
+                        # stashed failure is moot, don't fail a later get()
+                        h._pending_error = None
+                    # else: aborted (invalidate/close) mid-copy — drop it
+                sp.set(bytes=nbytes if live else 0)
         except BaseException as e:
             with self._lock:
                 if h._spill_task is task and \
@@ -623,7 +630,7 @@ class BufferCatalog:
                 if h._spill_task is task:
                     h._spill_task = None
                 task.state = _SpillTask.DONE
-                self.metrics["spill_wall_ns"] += time.monotonic_ns() - t0
+                self.metrics["spill_wall_ns"] += time.monotonic_ns() - sp.t0
             task.mark_done()
         self._enforce_host_budget(raise_errors=raise_errors)
 
@@ -670,23 +677,25 @@ class BufferCatalog:
                 victim.tier = SpillableBatch.TIER_SPILLING
                 self._host_bytes -= victim._host_nbytes
                 host = victim._host
-            t0 = time.monotonic_ns()
+            sp = span("spill", "to_disk")
             try:
-                enc = victim._write_disk(host, self._dir())
-                with self._lock:
-                    if victim.closed:
-                        path = victim._disk_path
-                        victim._disk_path = None
-                    else:
-                        path = None
-                        victim._host = None
-                        victim._host_nbytes = 0
-                        victim.tier = SpillableBatch.TIER_DISK
-                        victim._pending_error = None
-                        self.metrics["spilled_to_disk"] += 1
-                        self.metrics["spill_to_disk_bytes"] += enc
-                if path and os.path.exists(path):
-                    os.unlink(path)
+                with sp:
+                    enc = victim._write_disk(host, self._dir())
+                    with self._lock:
+                        if victim.closed:
+                            path = victim._disk_path
+                            victim._disk_path = None
+                        else:
+                            path = None
+                            victim._host = None
+                            victim._host_nbytes = 0
+                            victim.tier = SpillableBatch.TIER_DISK
+                            victim._pending_error = None
+                            self.metrics["spilled_to_disk"] += 1
+                            self.metrics["spill_to_disk_bytes"] += enc
+                    if path and os.path.exists(path):
+                        os.unlink(path)
+                    sp.set(bytes=enc)
             except BaseException as e:
                 with self._lock:
                     if victim._spill_task is task and \
@@ -707,10 +716,9 @@ class BufferCatalog:
                 task.state = _SpillTask.DONE
                 if victim._spill_task is task:
                     victim._spill_task = None
-                self.metrics["spill_wall_ns"] += time.monotonic_ns() - t0
+                self.metrics["spill_wall_ns"] += \
+                    time.monotonic_ns() - sp.t0
             task.mark_done()
-            obs_events.emit_span("spill", "to_disk", t0=t0,
-                                 t1=time.monotonic_ns(), bytes=enc)
 
     def drain_spills(self) -> None:
         """Join every in-flight async spill (tests, bench, shutdown

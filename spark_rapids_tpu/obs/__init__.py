@@ -4,7 +4,9 @@ Chrome-trace/JSONL export, and the ``tools/rapidsprof.py`` analysis CLI.
 The package is deliberately engine-free (stdlib only, relative imports)
 so ``rapidsprof`` can load it standalone the way ``rapidslint`` loads
 ``spark_rapids_tpu.analysis`` — without executing the engine's root
-``__init__`` (which imports jax).  See ``docs/observability.md``.
+``__init__`` (which imports jax); ``xplane`` alone loads more, tsl's
+generated ``xplane_pb2``, when a trace is read.  See
+``docs/observability.md``.
 """
 
 from __future__ import annotations

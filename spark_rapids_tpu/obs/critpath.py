@@ -9,16 +9,20 @@ runner blocks on is exactly the critical path) attributes every
 nanosecond of the query window ``[t0, t1)`` to the highest-priority
 site covering it, and the uncovered remainder to ``wait`` (host compute
 / runner wait).  By construction the segments sum to the window EXACTLY
-— the same parity discipline PR 10 pinned with
-``attributed_device_ns == deviceTimeNs`` — and the pinned test asserts
-it on a query that shuffles, spills and retries, serial and under
+— ``sum(last_metrics["critpath"].values()) == queryWallNs`` — and
+the pinned test asserts it on a query that shuffles, spills and retries, serial and under
 3-thread serve concurrency.
 
-Priority encodes the blocking chain (runner wait -> decode -> H2D ->
-dispatch -> shuffle sync -> spill stall -> D2H): ``device`` first, so
-an exchange's credit is its span wall MINUS the device time nested
-inside it — i.e. the host-side shuffle sync cost, not a recount of the
-dispatches it drove.
+Priority encodes the blocking chain: ``device_wait`` — the host blocked
+on the chip (a size read-back, the wait before a D2H copy, an
+exchange's sync) — first, so an exchange's credit is its span wall
+MINUS the waits nested inside it, i.e. the host-side shuffle cost.
+``enqueue`` (the host wall of an asynchronous jitted call; never device
+time) ranks below every site that names real work, and the outer host
+parts of ``session.execute_with_metrics`` (``stage``, ``stage_inputs``,
+``result``, ``plan``, ``bookkeeping``) last, innermost first: each is
+credited only what nothing inside it claims.  The window is the whole
+of ``execute_with_metrics``, so the segments are ``queryWallNs``.
 
 Engine-free (stdlib only, duck-typed events) so ``rapidsprof
 --critpath`` reconstructs the same decomposition offline from a JSONL
@@ -34,8 +38,9 @@ from .events import SPAN, field
 #: Site attribution priority, highest first.  ``wait`` (uncovered wall)
 #: is not a site — it is the remainder.
 SITE_PRIORITY: Tuple[str, ...] = (
-    "device", "h2d", "d2h", "spill", "unspill", "exchange", "mesh",
-    "scan", "io", "dispatch", "pallas", "retry", "fault",
+    "device_wait", "h2d", "d2h", "spill", "unspill", "exchange", "mesh",
+    "scan", "io", "pallas", "retry", "fault", "enqueue",
+    "stage", "stage_inputs", "result", "plan", "bookkeeping",
 )
 
 WAIT = "wait"

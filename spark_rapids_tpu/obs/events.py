@@ -1,9 +1,10 @@
 """Query-scoped observability event bus and per-query attribution scopes.
 
-The engine's instrumentation chokepoints (``utils.tracing``,
-``utils.compile_registry``, ``mem.catalog``, ``parallel.exchange``,
-``fault.*``, ``plan.adaptive``) emit typed span/instant events into a
-bounded ring buffer while a query runs; ``session.execute`` opens a
+The engine's instrumentation chokepoints emit typed span/instant events
+into a bounded ring buffer while a query runs — spans through ONE
+emitter, ``utils.tracing.span``, which also puts each on the profiler's
+timeline (only it calls :func:`emit_span`); instants directly
+(``fault.*``, ``plan.adaptive``, ``history``, ``serve``); ``session.execute`` opens a
 :class:`QueryScope` before its metric snapshots and drains it after, so
 the event window matches the metric deltas exactly.  The reference
 analogue is the Spark event log + the SQL UI's per-exec metrics feed,
@@ -67,8 +68,9 @@ def ring_drops_total() -> int:
 class Event:
     """One timeline entry.  ``kind`` is ``span`` (t0..t1) or ``instant``
     (t0 == t1); times are ``time.monotonic_ns`` stamps; ``site`` names the
-    emitting chokepoint (device/dispatch/h2d/d2h/spill/unspill/exchange/
-    retry/fault/adaptive/io); ``op_id`` ties the event to a physical-plan
+    emitting chokepoint (device_wait/enqueue/stage/h2d/d2h/spill/unspill/
+    exchange/retry/fault/adaptive/io/plan/…, the list is in
+    ``utils/tracing.py``); ``op_id`` ties the event to a physical-plan
     node when the site knows one."""
 
     __slots__ = ("kind", "site", "name", "op_id", "t0", "t1", "thread",

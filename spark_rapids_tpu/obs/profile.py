@@ -3,9 +3,11 @@
 ``session.execute`` builds one profile per query from the drained events
 and keeps a bounded history (``session.query_history()``, conf
 ``spark.rapids.sql.tpu.obs.history.maxQueries``) — the SQL-UI role of
-the reference's per-exec ``GpuMetric`` tables, answering "which operator
-ate the device time" and "when did the spill storm start" from data the
-chokepoints already produced.
+the reference's per-exec ``GpuMetric`` tables, answering "which operator's
+programs did the host enqueue, and where did it wait" and "when did the
+spill storm start" from data the chokepoints already produced.  (Which
+operator ate the DEVICE's time is the device trace's to say:
+``tools/rapidsprof.py --xplane``.)
 
 Engine-free (stdlib only): ``tools/rapidsprof.py`` builds the same
 profiles from a JSONL event log, so events are accessed duck-typed via
@@ -22,7 +24,7 @@ from .events import SPAN, field
 
 def _new_rollup(name: str) -> Dict[str, Any]:
     return {
-        "name": name, "dispatches": 0, "device_ns": 0, "errors": 0,
+        "name": name, "dispatches": 0, "enqueue_ns": 0, "errors": 0,
         "rows": 0, "batches": 0, "shuffle_bytes": 0, "shuffle_rows": 0,
         "shuffle_pieces": 0, "adaptive": {},
     }
@@ -32,9 +34,13 @@ class QueryProfile:
     """Per-operator rollups + per-site totals + wall-clock bounds for one
     query's event window.
 
-    ``op_rollups`` is keyed by physical-plan ``op_id`` (device spans carry
-    the stage root's op_id; exchange spans carry the exchange's); each
-    rollup keeps the operator's display ``name``.  ``site_totals`` maps
+    ``op_rollups`` is keyed by physical-plan ``op_id`` (enqueue spans
+    carry the op_id of the stage root they were dispatched for, or the
+    program's label where no stage was; exchange spans carry the
+    exchange's); each rollup keeps the operator's display ``name``.
+    A rollup's ``enqueue_ns`` is the host wall of its enqueues: what the
+    host spent handing the operator's programs to the chip, not what the
+    chip spent running them (the device trace answers that).  ``site_totals`` maps
     site -> {count, wall_ns, bytes}.  ``metrics`` / ``op_metrics`` are the
     query's ``last_metrics`` scalars and per-op metric dicts, stashed so a
     history entry is self-contained.
@@ -89,10 +95,14 @@ class QueryProfile:
             if t0:
                 self.t_min = t0 if not self.t_min else min(self.t_min, t0)
                 self.t_max = max(self.t_max, t1)
-            if site == "device":
-                r = self._rollup(op_id, name)
+            if site == "enqueue":
+                # under an operator: its class (op_id is <Class>#<k>);
+                # else the program's own label
+                r = self._rollup(
+                    op_id or f"jit:{name}",
+                    op_id.split("#")[0].split("@")[0] or name)
                 r["dispatches"] += 1
-                r["device_ns"] += max(0, t1 - t0)
+                r["enqueue_ns"] += max(0, t1 - t0)
                 r["rows"] += int(pay.get("rows", 0) or 0)
                 r["batches"] += int(pay.get("batches", 0) or 0)
                 if pay.get("error"):
@@ -113,16 +123,16 @@ class QueryProfile:
         return len(self.events)
 
     @property
-    def attributed_device_ns(self) -> int:
-        """Device ns the profile ties to concrete operators — compare
-        against ``last_metrics['deviceTimeNs']`` for coverage."""
-        return sum(r["device_ns"] for r in self.op_rollups.values())
+    def attributed_enqueue_ns(self) -> int:
+        """Enqueue wall the profile ties to operators and programs:
+        equal to the ``enqueue`` site's total by construction."""
+        return sum(r["enqueue_ns"] for r in self.op_rollups.values())
 
     def top_operators(self, n: int = 10) -> List[Dict[str, Any]]:
-        """Rollups sorted by device time (then shuffle bytes), op_id
+        """Rollups sorted by enqueue wall (then shuffle bytes), op_id
         attached under ``op_id``."""
         rows = [dict(r, op_id=op) for op, r in self.op_rollups.items()]
-        rows.sort(key=lambda r: (r["device_ns"], r["shuffle_bytes"]),
+        rows.sort(key=lambda r: (r["enqueue_ns"], r["shuffle_bytes"]),
                   reverse=True)
         return rows[:n]
 
@@ -144,14 +154,12 @@ class QueryProfile:
 
     def summary(self) -> str:
         """Top-of-profile text block (rapidsprof's per-query header)."""
-        dev = self.metrics.get("deviceTimeNs", 0) or 0
-        attr = self.attributed_device_ns
-        pct = 100.0 * attr / dev if dev else 100.0
         lines = [
             f"query {self.query_id}: wall {self.wall_ns / 1e6:.2f} ms, "
             f"{self.event_count} events ({self.dropped} dropped), "
-            f"device {attr / 1e6:.2f} ms attributed ({pct:.0f}% of "
-            f"deviceTimeNs)"
+            f"enqueue {self.attributed_enqueue_ns / 1e6:.2f} ms, "
+            f"waiting on the device "
+            f"{self.site('device_wait')['wall_ns'] / 1e6:.2f} ms"
         ]
         if self.dropped:
             sites = ", ".join(
@@ -165,7 +173,7 @@ class QueryProfile:
         for r in self.top_operators(5):
             lines.append(
                 f"  {r['name'] or r['op_id'] or '?'}: "
-                f"{r['device_ns'] / 1e6:.2f} ms device, "
+                f"{r['enqueue_ns'] / 1e6:.2f} ms enqueue, "
                 f"{r['dispatches']} dispatches"
                 + (f", {r['errors']} errored" if r["errors"] else ""))
         return "\n".join(lines)
@@ -176,8 +184,8 @@ def _fmt_rollup(r: Dict[str, Any], ms: Dict[str, Any]) -> str:
     if r:
         if r["dispatches"]:
             parts.append(f"dispatches={r['dispatches']}")
-        if r["device_ns"]:
-            parts.append(f"device={r['device_ns'] / 1e6:.2f}ms")
+        if r["enqueue_ns"]:
+            parts.append(f"enqueue={r['enqueue_ns'] / 1e6:.2f}ms")
         if r["errors"]:
             parts.append(f"errors={r['errors']}")
         if r["shuffle_bytes"]:

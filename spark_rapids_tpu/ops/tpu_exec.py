@@ -33,6 +33,7 @@ from spark_rapids_tpu.kernels.layout import (
 from spark_rapids_tpu.kernels.sort import sort_batch
 from spark_rapids_tpu.plan.physical import ExecContext, PhysicalOp, TpuExec
 from spark_rapids_tpu.utils.compile_registry import instrumented_jit
+from spark_rapids_tpu.utils.tracing import device_read
 
 
 def shrink_to_fit(batch: ColumnBatch,
@@ -852,7 +853,8 @@ class TpuHashAggregateExec(TpuExec):
         if not self._hash_active(ctx):
             return [self._run(db) for db in batches]
         pairs = [self._run_hash(db) for db in batches]
-        flags = jax.device_get([f for _, f in pairs]) if pairs else []
+        flags = device_read("hashagg_flags", [f for _, f in pairs]) \
+            if pairs else []
         if not any(bool(f) for f in flags):
             ctx.metric(self.op_id, "mxuAggBatches").add(len(pairs))
             return [p for p, _ in pairs]
@@ -1410,12 +1412,20 @@ class TpuCachedScanExec(TpuExec):
 
     def _materialize(self, ctx):
         from spark_rapids_tpu.runtime.device import DeviceRuntime
+        from spark_rapids_tpu.utils.tracing import span
         catalog = DeviceRuntime.get(ctx.conf).catalog
         parts = []
         for p in self.children[0].partitions(ctx):
             handles = []
             for db in p:
-                handles.append(catalog.register(shrink_to_fit(db)))
+                db = shrink_to_fit(db)
+                # nothing consumes a batch while the table is staged, so
+                # H2D can end in a sync here: host_to_device's own
+                # h2d/transfer span times the enqueue, this one what is
+                # left of the copies (and of the re-bucketing gather)
+                with span("h2d", "cache_ready", self.op_id):
+                    jax.block_until_ready(db)
+                handles.append(catalog.register(db))
             parts.append(handles)
         self.holder.partitions = parts
 
@@ -1627,7 +1637,8 @@ class TpuGenerateExec(TpuExec):
                 lens = (c.offsets[1:] - c.offsets[:-1]).astype(jnp.int64)
                 tot = jnp.sum(jnp.where(live, lens[parent], 0))
                 bcaps.append(round_up_capacity(
-                    max(int(jax.device_get(tot)), 16), minimum=16))
+                    max(int(device_read("generate_bytes", tot)), 16),
+                    minimum=16))
         g = gather_rows(kept_batch, parent, total, out_capacity=elem_cap,
                         out_byte_caps=bcaps or None)
         cols = list(g.columns)

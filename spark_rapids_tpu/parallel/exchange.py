@@ -28,6 +28,7 @@ from spark_rapids_tpu.plan.physical import (
 )
 from spark_rapids_tpu.obs import events as obs_events
 from spark_rapids_tpu.utils.compile_registry import instrumented_jit
+from spark_rapids_tpu.utils.tracing import device_read, device_wait, span
 
 def _range_sample_limit(ctx) -> int:
     from spark_rapids_tpu.config import CPU_RANGE_PARTITIONING_SAMPLE
@@ -389,22 +390,22 @@ class TpuShuffleExchangeExec(TpuExec):
             pid = part.device_partition_ids(merged, d)
             local_batches.append(merged)
             pids_list.append(jnp.asarray(pid, jnp.int32))
-        import time as _time
-
         from spark_rapids_tpu.utils.tracing import metrics_detail
         stats: dict = {}
-        t0 = _time.monotonic_ns()
-        out = mesh_exchange_batches(mesh, local_batches, pids_list,
-                                    self.output_schema, stats=stats)
-        # No unconditional host sync here: blocking on the all_to_all kills
-        # its async overlap with downstream dispatch (the whole point of
-        # the collective path).  Default shuffleWallNs is therefore a
-        # dispatch-wall LOWER BOUND; the accurate-sync path rides the
-        # metrics-detail conf for measurement runs.
-        if out and metrics_detail(ctx.conf):
-            jax.block_until_ready(out)
-            ctx.metric(self.op_id, "shuffleWallSyncs").add(1)
-        wall_ns = _time.monotonic_ns() - t0
+        with span("exchange", "mesh", self.op_id) as sp:
+            out = mesh_exchange_batches(mesh, local_batches, pids_list,
+                                        self.output_schema, stats=stats)
+            # No unconditional host sync here: blocking on the all_to_all
+            # kills its async overlap with downstream dispatch (the whole
+            # point of the collective path).  Default shuffleWallNs is
+            # therefore a dispatch-wall LOWER BOUND; the accurate-sync path
+            # rides the metrics-detail conf for measurement runs.
+            if out and metrics_detail(ctx.conf):
+                device_wait("mesh_exchange", out, self.op_id)
+                ctx.metric(self.op_id, "shuffleWallSyncs").add(1)
+            sp.set(bytes=stats.get("payload_bytes", 0), devices=n,
+                   bytes_per_device=stats.get("bytes_per_device"))
+        wall_ns = sp.elapsed_ns
         ctx.metric(self.op_id, "meshExchanges").add(1)
         ctx.metric(self.op_id, "meshDevices").add(n)
         # shuffle throughput accounting (RapidsCachingReader.scala:125-133
@@ -414,10 +415,6 @@ class TpuShuffleExchangeExec(TpuExec):
         ctx.metric(self.op_id, "shuffleWireBytes").add(
             stats.get("wire_bytes", 0))
         ctx.metric(self.op_id, "shuffleWallNs").add(wall_ns)
-        obs_events.emit_span(
-            "exchange", "mesh", self.op_id, t0, t0 + wall_ns,
-            bytes=stats.get("payload_bytes", 0), devices=n,
-            bytes_per_device=stats.get("bytes_per_device"))
         if stats.get("encoded_materialized"):
             # the encoded-corridor gap at mesh boundaries, measured:
             # dict-encoded columns give up their codes here (the
@@ -491,33 +488,34 @@ class TpuShuffleExchangeExec(TpuExec):
         frb = fixed_row_bytes(self.output_schema)
         vscales = varlen_byte_scales(self.output_schema)
         out: List[List] = [[] for _ in range(n)]
-        import time as _time
-        t0 = _time.monotonic_ns()
-        if SHUFFLE_SPLIT_V2.get(ctx.conf):
-            self._split_v2(ctx, all_batches, n, catalog, frb, vscales, out)
-        else:
-            self._split_v1(ctx, all_batches, n, catalog, frb, vscales, out)
-        ctx.metric(self.op_id, "shufflePieces").add(
-            sum(len(p) for p in out))
-        # downstream AQE coalescing reads these instead of unspilling
-        # batches just to count rows (GpuCustomShuffleReaderExec's use of
-        # map-status sizes)
-        self._last_part_rows = [sum(h.piece_rows for h in p) for p in out]
-        self._last_part_bytes = [sum(h.piece_bytes for h in p) for p in out]
-        # write-side shuffle metrics (single-host split path).  Wall time
-        # covers pid-sort + the count sync(s); the final piece gathers may
-        # still be in flight (async dispatch), so this is a lower bound on
-        # split cost, not an upper
-        ctx.metric(self.op_id, "shuffleBytes").add(
-            sum(self._last_part_bytes))
-        ctx.metric(self.op_id, "shuffleRows").add(sum(self._last_part_rows))
-        split_t1 = _time.monotonic_ns()
-        ctx.metric(self.op_id, "shuffleWallNs").add(split_t1 - t0)
-        obs_events.emit_span(
-            "exchange", "split", self.op_id, t0, split_t1,
-            bytes=sum(self._last_part_bytes),
-            rows=sum(self._last_part_rows),
-            pieces=sum(len(p) for p in out), partitions=n)
+        with span("exchange", "split", self.op_id) as sp:
+            if SHUFFLE_SPLIT_V2.get(ctx.conf):
+                self._split_v2(ctx, all_batches, n, catalog, frb, vscales,
+                               out)
+            else:
+                self._split_v1(ctx, all_batches, n, catalog, frb, vscales,
+                               out)
+            ctx.metric(self.op_id, "shufflePieces").add(
+                sum(len(p) for p in out))
+            # downstream AQE coalescing reads these instead of unspilling
+            # batches just to count rows (GpuCustomShuffleReaderExec's use
+            # of map-status sizes)
+            self._last_part_rows = [sum(h.piece_rows for h in p)
+                                    for p in out]
+            self._last_part_bytes = [sum(h.piece_bytes for h in p)
+                                     for p in out]
+            # write-side shuffle metrics (single-host split path).  Wall
+            # time covers pid-sort + the count sync(s); the final piece
+            # gathers may still be in flight (async dispatch), so this is a
+            # lower bound on split cost, not an upper
+            ctx.metric(self.op_id, "shuffleBytes").add(
+                sum(self._last_part_bytes))
+            ctx.metric(self.op_id, "shuffleRows").add(
+                sum(self._last_part_rows))
+            sp.set(bytes=sum(self._last_part_bytes),
+                   rows=sum(self._last_part_rows),
+                   pieces=sum(len(p) for p in out), partitions=n)
+        ctx.metric(self.op_id, "shuffleWallNs").add(sp.elapsed_ns)
         # planner-error accounting: the static size estimate the planner
         # used for this exchange's input (stashed by overrides) vs. the
         # actual materialized bytes just recorded — pure host arithmetic
@@ -569,7 +567,8 @@ class TpuShuffleExchangeExec(TpuExec):
                 ctx.metric(self.op_id, "shuffleSplitDispatches").add(1)
         if not sorted_all:
             return
-        host = jax.device_get([(c, bt) for _, c, bt in sorted_all])
+        host = device_read("split_counts",
+                           [(c, bt) for _, c, bt in sorted_all], self.op_id)
         ctx.metric(self.op_id, "shuffleSyncs").add(1)
         counts_h = [np.asarray(c, dtype=np.int64) for c, _ in host]
         bytes_h = [[np.asarray(b, dtype=np.int64) for b in bt]
@@ -665,9 +664,10 @@ class TpuShuffleExchangeExec(TpuExec):
                                       RangePartitioning) \
                     else self._sort_by_pid_impl(db, pi, n)
                 ctx.metric(self.op_id, "shuffleSplitDispatches").add(1)
-                counts_h = np.asarray(jax.device_get(counts))
-                bytes_h = [np.asarray(jax.device_get(b))
-                           for b in byte_totals]
+                counts_h, bytes_h = device_read(
+                    "split_counts", (counts, list(byte_totals)), self.op_id)
+                counts_h = np.asarray(counts_h)
+                bytes_h = [np.asarray(b) for b in bytes_h]
                 ctx.metric(self.op_id, "shuffleSyncs").add(1)
                 offset = 0
                 for p in range(n):
@@ -744,7 +744,7 @@ def _sample_device_keys(all_batches: List[List[ColumnBatch]],
             for batches in all_batches for db in batches]
     if not subs:
         return rows
-    meta = jax.device_get([
+    meta = device_read("range_sample", [
         (b.num_rows, [c.offsets for c in b.columns if c.is_varlen])
         for b in subs])
     gathered = []

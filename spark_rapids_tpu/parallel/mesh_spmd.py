@@ -56,7 +56,6 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import time
 from collections import OrderedDict
 from typing import List, Optional, Tuple
 
@@ -71,7 +70,9 @@ from spark_rapids_tpu.parallel.mesh_shuffle import (
     DATA_AXIS, _fit_1d, _unshard,
 )
 from spark_rapids_tpu.utils.compile_registry import instrumented_jit
-from spark_rapids_tpu.utils.tracing import device_dispatch
+from spark_rapids_tpu.utils.tracing import (
+    device_dispatch, device_read, span,
+)
 
 
 def _is_varlen(f) -> bool:
@@ -378,62 +379,62 @@ def run_mesh_stage(root, ctx, variant: str,
             label=f"meshStage:{root.name}")
         cache[key] = program
 
-    t0 = time.monotonic_ns()
-    ctx.metric("pipeline", "programs").add(1)
-    ctx.metric("pipeline", "meshProgramDispatches").add(1)
-    for ex in exchanges:
-        ctx.metric(ex.op_id, "meshBoundariesFused").add(1)
-    for j in joins:
-        ctx.metric(j.op_id, "meshJoinsFused").add(1)
-    out_schema = root.output_schema
-    overflowed = False
-    results: List[ColumnBatch] = []
-    with device_dispatch(ctx, "pipeline", root.name,
-                         obs_op=root.op_id) as holder:
-        out_lists, ovf_g = PL._run_oom_guarded(
-            ctx, lambda: program(tuple(flat_globals)), args=(),
-            retryable=True)
-        # the ONLY host read of a fused stage, paid only when a join
-        # fused: did any shard's bucketed join output overflow its
-        # static capacity?  (a [n]-bool fetch after the one dispatch,
-        # not a per-boundary shuffleSync)
-        if joins:
-            overflowed = bool(jax.device_get(ovf_g).any())
-        if overflowed:
-            holder["outputs"] = []
-            out_lists = []
-        # one catalog handle per stacked output global, closed right
-        # after unsharding: per-shard HBM accounting without exposing a
-        # long-lived spill victim that would gather every shard
-        cat = DeviceRuntime.get(ctx.conf).catalog
-        out_schemas = scache.get(key) or [out_schema] * len(out_lists)
-        handles = [
-            cat.register_sharded(
-                _global_batch(sch, pl, _out_capacity(sch, pl)))
-            for sch, pl in zip(out_schemas, out_lists)]
-        bytes_per_device = [0] * n
-        for h in handles:
-            for d, v in enumerate(h.shard_bytes):
-                bytes_per_device[d] += v
-        dev_pos = {d: i for i, d in enumerate(devices)}
-        for sch, pl in zip(out_schemas, out_lists):
-            cap = _out_capacity(sch, pl)
-            per_dev: List[list] = [[] for _ in range(n)]
-            for g in pl:
-                for shard in g.addressable_shards:
-                    per_dev[dev_pos[shard.device]].append(shard.data)
-            for d in range(n):
-                arrs = _unshard(per_dev[d])
-                results.append(_batch_from_payloads(
-                    sch, arrs, cap, squeeze=False))
-        for h in handles:
-            h.close()
-        if not overflowed:
-            holder["outputs"] = results
-    obs_events.emit_span(
-        "mesh", "program", root.op_id, t0, time.monotonic_ns(),
-        devices=n, fused_boundaries=len(exchanges),
-        fused_joins=len(joins), bytes_per_device=bytes_per_device)
+    with span("mesh", "program", root.op_id) as mesh_span:
+        ctx.metric("pipeline", "programs").add(1)
+        ctx.metric("pipeline", "meshProgramDispatches").add(1)
+        for ex in exchanges:
+            ctx.metric(ex.op_id, "meshBoundariesFused").add(1)
+        for j in joins:
+            ctx.metric(j.op_id, "meshJoinsFused").add(1)
+        out_schema = root.output_schema
+        overflowed = False
+        results: List[ColumnBatch] = []
+        with device_dispatch(ctx, "pipeline", root.name,
+                             obs_op=root.op_id) as holder:
+            out_lists, ovf_g = PL._run_oom_guarded(
+                ctx, lambda: program(tuple(flat_globals)), args=(),
+                retryable=True)
+            # the ONLY host read of a fused stage, paid only when a join
+            # fused: did any shard's bucketed join output overflow its
+            # static capacity?  (a [n]-bool fetch after the one dispatch,
+            # not a per-boundary shuffleSync)
+            if joins:
+                overflowed = bool(device_read(
+                    "join_overflow", ovf_g, root.op_id).any())
+            if overflowed:
+                holder["outputs"] = []
+                out_lists = []
+            # one catalog handle per stacked output global, closed right
+            # after unsharding: per-shard HBM accounting without exposing a
+            # long-lived spill victim that would gather every shard
+            cat = DeviceRuntime.get(ctx.conf).catalog
+            out_schemas = scache.get(key) or [out_schema] * len(out_lists)
+            handles = [
+                cat.register_sharded(
+                    _global_batch(sch, pl, _out_capacity(sch, pl)))
+                for sch, pl in zip(out_schemas, out_lists)]
+            bytes_per_device = [0] * n
+            for h in handles:
+                for d, v in enumerate(h.shard_bytes):
+                    bytes_per_device[d] += v
+            dev_pos = {d: i for i, d in enumerate(devices)}
+            for sch, pl in zip(out_schemas, out_lists):
+                cap = _out_capacity(sch, pl)
+                per_dev: List[list] = [[] for _ in range(n)]
+                for g in pl:
+                    for shard in g.addressable_shards:
+                        per_dev[dev_pos[shard.device]].append(shard.data)
+                for d in range(n):
+                    arrs = _unshard(per_dev[d])
+                    results.append(_batch_from_payloads(
+                        sch, arrs, cap, squeeze=False))
+            for h in handles:
+                h.close()
+            if not overflowed:
+                holder["outputs"] = results
+        mesh_span.set(devices=n, fused_boundaries=len(exchanges),
+                      fused_joins=len(joins),
+                      bytes_per_device=bytes_per_device)
     if overflowed:
         # a shard's true join output exceeded its static bucket: the
         # fused results are invalid — rerun the whole stage host-driven

@@ -231,7 +231,10 @@ class TpuOverrides:
         from spark_rapids_tpu.config import FUSION_ENABLED
         if FUSION_ENABLED.get(self.conf):
             phys = _fuse_map_chains(phys)
-        return phys
+        # last: every planner (session, ml, the recovery's CPU re-lowering)
+        # hands out a tree whose op ids are its pre-order positions
+        from spark_rapids_tpu.plan.physical import assign_op_ids
+        return assign_op_ids(phys)
 
     def _shuffle_parts(self) -> int:
         return self.conf.shuffle_partitions

@@ -13,6 +13,7 @@ CPU<->TPU boundary (GpuTransitionOverrides analogue).
 from __future__ import annotations
 
 import collections
+import itertools
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional
@@ -124,6 +125,9 @@ def prefetch_spillables(handles, depth: int = 1):
     return handles[0]._catalog.prefetch(handles, depth=depth)
 
 
+_OP_SEQ = itertools.count(1)
+
+
 class PhysicalOp:
     """Base physical operator."""
 
@@ -132,7 +136,10 @@ class PhysicalOp:
     def __init__(self, children: List["PhysicalOp"], output_schema: T.Schema):
         self.children = children
         self.output_schema = output_schema
-        self.op_id = f"{type(self).__name__}@{id(self):x}"
+        # unique in the process and no memory address; a planned tree is
+        # renumbered by :func:`assign_op_ids`, so that what is keyed on
+        # an op_id reads the same in every process that plans the query
+        self.op_id = f"{type(self).__name__}@{next(_OP_SEQ)}"
 
     @property
     def name(self) -> str:
@@ -437,7 +444,7 @@ def _drive_partitions(root: PhysicalOp, ctx: ExecContext,
     for i, part in enumerate(parts):
         got: List = []
         try:
-            with trace_range(f"partition:{i}"), \
+            with trace_range("partition", str(i)), \
                     partition_deadline(ctx.conf, f"partition:{i}"):
                 for b in part:
                     got.append(b)
@@ -521,7 +528,29 @@ def _history_cached_collect(op: PhysicalOp, ctx: ExecContext
     if not hbs:
         return HostBatch(op.output_schema, [
             _empty_host_col(f) for f in op.output_schema.fields])
-    return HostBatch.concat(hbs)
+    return concat_result(hbs)
+
+
+def assign_op_ids(root: PhysicalOp) -> PhysicalOp:
+    """``<Class>#<k>``, ``k`` the operator's pre-order position in the
+    planned tree: the same plan gets the same ids in every process (an
+    operator made later, at run time, keeps its ``<Class>@<n>``)."""
+    seen: set = set()
+    stack = [root]
+    while stack:
+        op = stack.pop()
+        if id(op) in seen:
+            continue   # a subtree shared by two parents keeps its first id
+        op.op_id = f"{type(op).__name__}#{len(seen)}"
+        seen.add(id(op))
+        stack.extend(reversed(op.children))
+    return root
+
+
+def concat_result(batches: List[HostBatch]) -> HostBatch:
+    from spark_rapids_tpu.utils.tracing import span
+    with span("result", "concat"):
+        return HostBatch.concat(batches)
 
 
 def collect_host(op: PhysicalOp, ctx: ExecContext) -> HostBatch:
@@ -535,7 +564,7 @@ def collect_host(op: PhysicalOp, ctx: ExecContext) -> HostBatch:
             from spark_rapids_tpu.fault.recovery import (
                 run_pipeline_with_recovery,
             )
-            with trace_range("pipeline_collect",
+            with trace_range("collect", "pipeline",
                              ctx.metric("collect", "wallTimeNs")):
                 hb = run_pipeline_with_recovery(op, ctx)
             if hb is not None:
@@ -549,7 +578,7 @@ def collect_host(op: PhysicalOp, ctx: ExecContext) -> HostBatch:
                     return HostBatch(op.output_schema, [
                         _empty_host_col(f) for f in op.output_schema.fields
                     ])
-                return HostBatch.concat(batches)
+                return concat_result(batches)
         root = op if not op.is_tpu else DeviceToHostExec(op)
         t0 = time.monotonic()
         batches: List[HostBatch] = _drive_partitions(
@@ -560,7 +589,7 @@ def collect_host(op: PhysicalOp, ctx: ExecContext) -> HostBatch:
             return HostBatch(op.output_schema, [
                 _empty_host_col(f) for f in op.output_schema.fields
             ])
-        return HostBatch.concat(batches)
+        return concat_result(batches)
     finally:
         ctx.close_deferred()
         # Give back any staging acquires whose batches never reached a

@@ -54,7 +54,7 @@ from spark_rapids_tpu.batch import (
 )
 from spark_rapids_tpu.plan.physical import ExecContext, PhysicalOp, TpuExec
 from spark_rapids_tpu.utils.compile_registry import instrumented_jit
-from spark_rapids_tpu.utils.tracing import device_dispatch
+from spark_rapids_tpu.utils.tracing import device_dispatch, span
 
 
 def concat_static(batches: List[ColumnBatch], schema: T.Schema
@@ -97,18 +97,38 @@ def build_pipeline(op: PhysicalOp, ctx: ExecContext,
     """
     if id(op) in memo:
         return memo[id(op)]
+    # the operator's pre-order position in its stage: with its class it
+    # names the operator on the device timeline the same way in every
+    # process (``op_id`` holds a memory address)
+    k = memo[_ORDER] = memo.get(_ORDER, -1) + 1
     f = None
     if isinstance(op, TpuExec) and not (
             op is not root and getattr(op, "pipeline_stage_break", False)):
         f = op.pipeline_inline(
             ctx,
             lambda child: build_pipeline(child, ctx, sources, memo, root))
+        if f is not None:
+            f = _scoped(f, f"{type(op).__name__}.{k}")
     if f is None:
         idx = len(sources)
         sources.append(op)
         f = lambda args, _i=idx: list(args[_i])  # noqa: E731
     memo[id(op)] = f
     return f
+
+
+#: ``memo`` key of the pre-order counter (never collides with an ``id()``)
+_ORDER = "order"
+
+
+def _scoped(f: Callable, scope: str) -> Callable:
+    """``f`` traced under ``jax.named_scope(scope)``: every HLO operation
+    the operator's inlined function emits carries ``…/<Class>.<k>/…`` in
+    its ``op_name``, so a trace can charge device time to the operator."""
+    def run(args):
+        with jax.named_scope(scope):
+            return f(args)
+    return run
 
 
 class MeshBuildScope:
@@ -583,7 +603,8 @@ def _run_stage_host(root: PhysicalOp, ctx: ExecContext, variant: str,
                     unfused: bool = False) -> List[ColumnBatch]:
     variant_fn = getattr(root, "stage_variant", None)
     fuse = _fuse_tail_enabled(ctx)
-    mats = _materialize_sources(sources, ctx, fuse)
+    with span("stage_inputs", root.name):
+        mats = _materialize_sources(sources, ctx, fuse)
     args = tuple(tuple(bs) for bs, _, _ in mats)
     spec = tuple(sp for _, sp, _ in mats) if fuse else None
     from spark_rapids_tpu.batch import colocate_batches
@@ -676,4 +697,5 @@ def pipeline_collect(root: PhysicalOp, ctx: ExecContext
         from spark_rapids_tpu.plan.physical import _empty_host_col
         return HostBatch(root.output_schema, [
             _empty_host_col(f) for f in root.output_schema.fields])
-    return HostBatch.concat(hbs)
+    from spark_rapids_tpu.plan.physical import concat_result
+    return concat_result(hbs)

@@ -218,31 +218,29 @@ class FrontDoorServer:
     def handle_request(self, req: Dict[str, Any]) -> Dict[str, Any]:
         """One wire request -> one wire response (never raises; every
         failure becomes an ``ok: false`` response)."""
-        from spark_rapids_tpu.obs import events as obs_events
+        from spark_rapids_tpu.utils.tracing import span
         with self._lock:
             self._requests += 1
         op = req.get("op")
-        t0 = time.monotonic_ns()
-        try:
-            if op == "submit":
-                resp = self._handle_submit(req)
-            elif op == "stats":
-                resp = {"ok": True, "scheduler": self.scheduler.stats(),
-                        "frontend": self.stats()}
-            elif op == "drain":
-                resp = self._handle_drain(req)
-            elif op == "ping":
-                resp = {"ok": True}
-            else:
-                resp = {"ok": False, "error": f"unknown op: {op!r}",
-                        "error_class": "ProtocolError"}
-        except Exception as e:
-            # a failed request must not take down the connection loop
-            resp = {"ok": False, "error": f"{type(e).__name__}: {e}",
-                    "error_class": _error_class(e)}
-        t1 = time.monotonic_ns()
-        obs_events.emit_span("serve.frontend", f"op_{op}", "serve",
-                             t0=t0, t1=t1, ok=bool(resp.get("ok")))
+        with span("serve.frontend", f"op_{op}", "serve") as sp:
+            try:
+                if op == "submit":
+                    resp = self._handle_submit(req)
+                elif op == "stats":
+                    resp = {"ok": True, "scheduler": self.scheduler.stats(),
+                            "frontend": self.stats()}
+                elif op == "drain":
+                    resp = self._handle_drain(req)
+                elif op == "ping":
+                    resp = {"ok": True}
+                else:
+                    resp = {"ok": False, "error": f"unknown op: {op!r}",
+                            "error_class": "ProtocolError"}
+            except Exception as e:
+                # a failed request must not take down the connection loop
+                resp = {"ok": False, "error": f"{type(e).__name__}: {e}",
+                        "error_class": _error_class(e)}
+            sp.set(ok=bool(resp.get("ok")))
         return resp
 
     def _handle_drain(self, req: Dict[str, Any]) -> Dict[str, Any]:

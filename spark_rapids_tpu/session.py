@@ -141,7 +141,9 @@ class TpuSparkSession:
 
     def sql(self, query: str):
         from spark_rapids_tpu.sql.parser import parse_sql
-        return parse_sql(query, self)
+        from spark_rapids_tpu.utils.tracing import span
+        with span("plan", "parse"):
+            return parse_sql(query, self)
 
     # -- execution ----------------------------------------------------------
 
@@ -178,6 +180,54 @@ class TpuSparkSession:
         self.last_explain = explain
         return phys
 
+    def _plan_and_context(self, plan):
+        """Everything between entry and the first dispatch: conf-shaped
+        process state, the physical plan (memo probe or lowering) and
+        this query's :class:`ExecContext`."""
+        from spark_rapids_tpu.config import (
+            OBS_TELEMETRY_ENABLED, OBS_TELEMETRY_INTERVAL_MS,
+            OBS_TELEMETRY_MAX_INTERVALS,
+        )
+        from spark_rapids_tpu import history as qhistory
+        from spark_rapids_tpu.kernels import pallas_tier
+        from spark_rapids_tpu.obs import timeseries as obs_ts
+        from spark_rapids_tpu.plan.physical import ExecContext
+        # (re)shape the process telemetry ring from this session's conf
+        # and (re)register the engine gauges — a repeat execute with the
+        # same shape keeps the live ring and its accumulated intervals
+        obs_ts.configure(OBS_TELEMETRY_ENABLED.get(self.conf),
+                         OBS_TELEMETRY_INTERVAL_MS.get(self.conf),
+                         OBS_TELEMETRY_MAX_INTERVALS.get(self.conf))
+        self._register_telemetry_gauges()
+        # the Pallas kernel tier consults this session's conf for its
+        # per-kernel gates at trace time (kernels.pallas_tier)
+        pallas_tier.configure(self.conf)
+        phys = self.plan_physical(plan)
+        if self.conf.test_enforce_tpu:
+            _assert_on_tpu(phys)
+        if self.runtime is not None:
+            # re-resolve: a device-lost recovery mid-query rebuilds the
+            # process runtime (new semaphore/device, same catalog) — the
+            # next query must ride the live instance, not the dead one
+            from spark_rapids_tpu.runtime.device import DeviceRuntime
+            self.runtime = DeviceRuntime.get(self.conf)
+        ctx = ExecContext(
+            self.conf,
+            semaphore=self.runtime.semaphore if self.runtime else None,
+            device=self.runtime.device if self.runtime else None,
+            mesh=self._shuffle_mesh())
+        # the fault-recovery CPU fallback re-lowers THIS logical plan
+        # with sql.enabled=false to replay a failed partition on the CPU
+        # operator path (fault.recovery)
+        ctx.logical_plan = plan
+        self.last_physical_plan = phys
+        self.last_exec_ctx = ctx
+        # query-intelligence hooks (history/): seed the plan from the
+        # statistics store and arm the fragment-cache key on the context
+        # — a single conf read when no history dir is configured
+        qhistory.begin_query(self, plan, phys, ctx)
+        return phys, ctx
+
     def _shuffle_mesh(self):
         """The >1-device mesh for the ICI collective shuffle, or None.
 
@@ -209,58 +259,30 @@ class TpuSparkSession:
         scheduler uses it for per-tenant rollups."""
         from spark_rapids_tpu.config import (
             FAULTS_SPEC, OBS_ENABLED, OBS_RING_MAX_EVENTS,
-            OBS_TELEMETRY_ENABLED, OBS_TELEMETRY_INTERVAL_MS,
-            OBS_TELEMETRY_MAX_INTERVALS,
         )
         from spark_rapids_tpu.fault import inject as fault_inject
         from spark_rapids_tpu.fault import metrics as FM
         from spark_rapids_tpu.obs import events as obs_events
-        from spark_rapids_tpu.obs import timeseries as obs_ts
-        from spark_rapids_tpu.plan.physical import ExecContext, collect_host
-        from spark_rapids_tpu.utils import compile_registry as CR
-        # (re)shape the process telemetry ring from this session's conf
-        # and (re)register the engine gauges — a repeat execute with the
-        # same shape keeps the live ring and its accumulated intervals
-        obs_ts.configure(OBS_TELEMETRY_ENABLED.get(self.conf),
-                         OBS_TELEMETRY_INTERVAL_MS.get(self.conf),
-                         OBS_TELEMETRY_MAX_INTERVALS.get(self.conf))
-        self._register_telemetry_gauges()
-        # the Pallas kernel tier consults this session's conf for its
-        # per-kernel gates at trace time (kernels.pallas_tier)
         from spark_rapids_tpu.kernels import pallas_tier
-        pallas_tier.configure(self.conf)
-        phys = self.plan_physical(plan)
-        if self.conf.test_enforce_tpu:
-            _assert_on_tpu(phys)
-        if self.runtime is not None:
-            # re-resolve: a device-lost recovery mid-query rebuilds the
-            # process runtime (new semaphore/device, same catalog) — the
-            # next query must ride the live instance, not the dead one
-            from spark_rapids_tpu.runtime.device import DeviceRuntime
-            self.runtime = DeviceRuntime.get(self.conf)
-        ctx = ExecContext(
-            self.conf,
-            semaphore=self.runtime.semaphore if self.runtime else None,
-            device=self.runtime.device if self.runtime else None,
-            mesh=self._shuffle_mesh())
-        # the fault-recovery CPU fallback re-lowers THIS logical plan
-        # with sql.enabled=false to replay a failed partition on the CPU
-        # operator path (fault.recovery)
-        ctx.logical_plan = plan
-        self.last_physical_plan = phys
-        self.last_exec_ctx = ctx
-        # open the query scope exactly around the metric snapshots so
-        # the event window and the CR/FM deltas describe the same
-        # interval; the scope also carries this query's counters and
-        # fault registry under concurrent serving
+        from spark_rapids_tpu.plan.physical import collect_host
+        from spark_rapids_tpu.utils import compile_registry as CR
+        from spark_rapids_tpu.utils.tracing import span
+        # the query wall is partitioned from HERE to the stamp taken just
+        # before critpath.compute: the scope (event ring, this query's
+        # counters and fault registry under concurrent serving) opens
+        # first, so planning's span and everything after it land inside
+        t_query0 = time.monotonic_ns()
         obs_token = obs_events.begin_query(
             enabled=OBS_ENABLED.get(self.conf),
             max_events=OBS_RING_MAX_EVENTS.get(self.conf))
-        # query-intelligence hooks (history/): seed the plan from the
-        # statistics store and arm the fragment-cache key on the context
-        # — a single conf read when no history dir is configured
-        from spark_rapids_tpu import history as qhistory
-        qhistory.begin_query(self, plan, phys, ctx)
+        try:
+            with span("plan", "physical"):
+                phys, ctx = self._plan_and_context(plan)
+        except BaseException:
+            # a plan the test mode refuses must not leak its scope into
+            # the next query's window
+            obs_events.end_query(obs_token)
+            raise
         # (re)install the deterministic fault registry per query (on the
         # scope just opened, so concurrent queries keep separate specs):
         # call counters reset so "the Nth dispatch" is query-relative;
@@ -270,7 +292,6 @@ class TpuSparkSession:
         # them (e.g. ml.to_device_batches staging outside execute)
         spec = FAULTS_SPEC.get(self.conf)
         fault_inject.install(spec)
-        t_query0 = time.monotonic_ns()
         before = CR.snapshot()
         fm_before = FM.snapshot()
         pt_before = pallas_tier.fallback_count()
@@ -286,10 +307,54 @@ class TpuSparkSession:
         finally:
             if spec:
                 fault_inject.uninstall()
-        # ONE query-end stamp: the wall metric, the history record and
-        # the critical-path window must agree to the nanosecond or the
-        # decomposition's exactness contract breaks
+        # the answer is on the host: what follows is the engine's own
+        # bookkeeping (snapshot deltas, ~80 last_metrics keys, the
+        # history record and its regression sentinel)
+        with span("bookkeeping", "metrics") as bookkeeping:
+            frame = self._query_metrics(
+                plan, phys, ctx, out, obs_token,
+                (before, fm_before, pt_before, cat_before),
+                bookkeeping.t0 - t_query0)
+        # drain the obs epoch and fold it into a bounded-history profile
+        # (obs.profile); the event counts become metrics so tests and
+        # bench can assert the bus's own economics
+        obs_events_list, obs_dropped, obs_dropped_by_site = \
+            obs_events.end_query(obs_token)
+        frame.last_metrics["obsEventCount"] = len(obs_events_list)
+        frame.last_metrics["obsEventsDropped"] = obs_dropped
+        # exact wall decomposition (obs.critpath): the segments partition
+        # [t_query0, t_query1) — entry to this stamp — so they sum to
+        # queryWallNs EXACTLY
+        from spark_rapids_tpu.obs import critpath as obs_critpath
         t_query1 = time.monotonic_ns()
+        cp = obs_critpath.compute(obs_events_list, t_query0, t_query1)
+        frame.last_metrics["critpathAttributedNs"] = cp.attributed_ns
+        frame.last_metrics["critpath"] = dict(cp.segments)
+        frame.last_metrics["queryWallNs"] = t_query1 - t_query0
+        # publish by one reference assignment: a concurrent reader of
+        # self.last_metrics sees the previous complete dict or this one,
+        # never a half-filled frame
+        self.last_metrics = frame.last_metrics
+        if obs_token is not None and obs_token.bus is not None:
+            self._record_profile(obs_token.query_id, obs_events_list,
+                                 obs_dropped, t_query1 - t_query0,
+                                 frame.last_metrics,
+                                 dropped_by_site=obs_dropped_by_site,
+                                 qt0_ns=t_query0, qt1_ns=t_query1)
+        return out, frame.last_metrics
+
+    def _query_metrics(self, plan, phys, ctx, out, obs_token, befores,
+                       answer_wall_ns: int) -> "_MetricsFrame":
+        """The ``last_metrics`` frame of one query: this query's counter
+        deltas against the ``befores`` snapshots taken before it ran, the
+        per-operator metrics summed, and the history record with its
+        regression sentinel (the ``bookkeeping`` span's body)."""
+        from spark_rapids_tpu.fault import metrics as FM
+        from spark_rapids_tpu.obs import timeseries as obs_ts
+        from spark_rapids_tpu import history as qhistory
+        from spark_rapids_tpu.kernels import pallas_tier
+        from spark_rapids_tpu.utils import compile_registry as CR
+        before, fm_before, pt_before, cat_before = befores
         if obs_token is not None:
             # per-scope counters: exactly this query's activity, even
             # with N queries in flight (the global snapshot delta would
@@ -315,7 +380,15 @@ class TpuSparkSession:
         frame.last_metrics["compileCount"] = d["compiles"]
         frame.last_metrics["compileWallNs"] = d["compile_wall_ns"]
         frame.last_metrics["dispatchCount"] = d["dispatches"]
+        # the compile wall by phase (jax.monitoring, routed by event
+        # name): tracing, MLIR lowering, XLA compiling, persistent-cache
+        # loads — disjoint, and together no more than compileWallNs
+        frame.last_metrics["jaxTraceNs"] = d["trace_ns"]
+        frame.last_metrics["lowerNs"] = d["lower_ns"]
         frame.last_metrics["backendCompileNs"] = d["backend_compile_ns"]
+        frame.last_metrics["compileCacheLoadNs"] = d["cache_load_ns"]
+        frame.last_metrics["compileCacheHits"] = d["cache_hits"]
+        frame.last_metrics["compileCacheMisses"] = d["cache_misses"]
         frame.last_metrics["compiledShapes"] = CR.compiled_shapes()
         # data-plane economics: input bytes donated to dispatches (HBM
         # reused for outputs) and the host<->device staging volume/time
@@ -487,31 +560,9 @@ class TpuSparkSession:
         # instant lands inside this query's event window
         alerts = qhistory.end_query(self, plan, phys, ctx,
                                     frame.last_metrics,
-                                    t_query1 - t_query0, out)
+                                    answer_wall_ns, out)
         frame.last_metrics["regressionAlerts"] = len(alerts)
-        # drain the obs epoch and fold it into a bounded-history profile
-        # (obs.profile); the event counts become metrics so tests and
-        # bench can assert the bus's own economics
-        obs_events_list, obs_dropped, obs_dropped_by_site = \
-            obs_events.end_query(obs_token)
-        frame.last_metrics["obsEventCount"] = len(obs_events_list)
-        frame.last_metrics["obsEventsDropped"] = obs_dropped
-        # exact wall decomposition (obs.critpath): the segments partition
-        # [t_query0, t_query1) so attributed + wait == wall EXACTLY
-        from spark_rapids_tpu.obs import critpath as obs_critpath
-        cp = obs_critpath.compute(obs_events_list, t_query0, t_query1)
-        frame.last_metrics["critpathAttributedNs"] = cp.attributed_ns
-        # publish by one reference assignment: a concurrent reader of
-        # self.last_metrics sees the previous complete dict or this one,
-        # never a half-filled frame
-        self.last_metrics = frame.last_metrics
-        if obs_token is not None and obs_token.bus is not None:
-            self._record_profile(obs_token.query_id, obs_events_list,
-                                 obs_dropped, t_query1 - t_query0,
-                                 frame.last_metrics,
-                                 dropped_by_site=obs_dropped_by_site,
-                                 qt0_ns=t_query0, qt1_ns=t_query1)
-        return out, frame.last_metrics
+        return frame
 
     def _register_telemetry_gauges(self) -> None:
         """(Re)register the engine gauges on the telemetry ring.  Gauges
@@ -558,7 +609,8 @@ class TpuSparkSession:
         scalars = {k: v for k, v in metrics.items()
                    if not isinstance(v, dict)}
         op_metrics = {k: v for k, v in metrics.items()
-                      if isinstance(v, dict) and k != "memory"}
+                      if isinstance(v, dict)
+                      and k not in ("memory", "critpath")}
         prof = QueryProfile(query_id, events, dropped, wall_ns=wall_ns,
                             metrics=scalars, op_metrics=op_metrics,
                             dropped_by_site=dropped_by_site,

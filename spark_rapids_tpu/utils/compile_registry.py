@@ -35,9 +35,25 @@ Data-plane accounting rides the same snapshot/delta machinery:
   batch staging layer, feeding bench.py's ``h2d_gb_per_sec`` /
   ``d2h_gb_per_sec``.
 
-When available, ``jax.monitoring`` backend-compile duration events are
-also accumulated (``backend_compile_ns``) — pure XLA compile seconds,
-excluding the first-run execution that the wall number includes.
+The compile wall is also split by phase from ``jax.monitoring``'s
+duration events, routed by exact event name (jax 0.9.0), per query scope
+and per program (:func:`per_label_compiles`):
+
+* ``trace_ns`` — ``/jax/core/compile/jaxpr_trace_duration``: Python
+  tracing of the program to a jaxpr;
+* ``lower_ns`` — ``…/jaxpr_to_mlir_module_duration``: jaxpr to MLIR;
+* ``backend_compile_ns`` — ``…/backend_compile_duration`` LESS the
+  persistent-cache retrieval jax times inside it: XLA compiling, nothing
+  else;
+* ``cache_load_ns`` — ``/jax/compilation_cache/cache_retrieval_time_sec``
+  (reading and deserialising an executable on a hit);
+* ``cache_hits`` / ``cache_misses`` — the cache's own events (a miss is
+  counted when the entry is written).
+
+Only a program's outermost phases count (a ``jnp`` helper traced inside
+a stage program fires a nested trace event), so the four wall counters
+are disjoint and sum to no more than ``compile_wall_ns``, which also
+holds the first execution.
 """
 
 from __future__ import annotations
@@ -47,13 +63,13 @@ import logging
 import os
 import sys
 import threading
-import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
 
 from spark_rapids_tpu.fault import inject as _fault_inject
 from spark_rapids_tpu.obs import events as _obs_events
+from spark_rapids_tpu.utils import tracing as _tracing
 
 _LOCK = threading.Lock()
 _STATS: Dict[str, int] = {
@@ -62,14 +78,22 @@ _STATS: Dict[str, int] = {
     "cache_bypass_compiles": 0,  # ... of which donating (never persisted)
     "compile_wall_ns": 0,   # wall ns of calls that triggered a compile
     "dispatches": 0,        # jitted program invocations
-    "backend_compile_ns": 0,  # jax.monitoring backend compile durations
+    # the compile wall by phase (jax.monitoring, outermost phases only)
+    "trace_ns": 0,          # Python tracing to a jaxpr
+    "lower_ns": 0,          # jaxpr -> MLIR module
+    "backend_compile_ns": 0,  # XLA compiling (cache retrieval taken out)
+    "cache_load_ns": 0,     # persistent-cache read + deserialise (hits)
+    "cache_hits": 0,        # persistent-cache hits
+    "cache_misses": 0,      # persistent-cache entries written
     "donated_bytes": 0,     # input buffer bytes donated to dispatches
     "h2d_bytes": 0,         # host->device staging bytes
     "h2d_ns": 0,            # host->device staging wall ns
     "d2h_bytes": 0,         # device->host bulk-copy bytes
     "d2h_ns": 0,            # device->host bulk-copy wall ns
 }
-_LABEL_COMPILES: Dict[str, int] = {}
+#: program name (the sanitised label, what ``XLA Modules`` shows after
+#: ``jit_``) -> {"compiles": n, "<phase>_ns": …, "cache_hits": …}
+_LABEL_COMPILES: Dict[str, Dict[str, int]] = {}
 
 
 def snapshot() -> Dict[str, int]:
@@ -93,9 +117,25 @@ def compiled_shapes() -> int:
         return _STATS["compiles"]
 
 
-def per_label_compiles() -> Dict[str, int]:
+def per_label_compiles() -> Dict[str, Dict[str, int]]:
+    """Which program cost what: program name -> ``compiles`` (executable
+    cache misses at its call sites) and the compile phases' ns and cache
+    counts credited to it."""
     with _LOCK:
-        return dict(_LABEL_COMPILES)
+        return {k: dict(v) for k, v in _LABEL_COMPILES.items()}
+
+
+def _credit_label_locked(program: str, key: str, n: int) -> None:
+    d = _LABEL_COMPILES.setdefault(program, {})
+    d[key] = d.get(key, 0) + n
+
+
+def program_name(label: str) -> str:
+    """``stage:TpuHashAggregateExec`` -> ``stage_TpuHashAggregateExec``:
+    the label as a Python identifier, the name the jitted function (and
+    so the ``jit_<name>`` XLA module on the device timeline) is given."""
+    out = "".join(c if c.isalnum() or c == "_" else "_" for c in label)
+    return out if out and not out[0].isdigit() else "p_" + out
 
 
 def _record(label: str, compiled: bool, wall_ns: int,
@@ -106,7 +146,7 @@ def _record(label: str, compiled: bool, wall_ns: int,
         if compiled:
             _STATS["compiles"] += 1
             _STATS["compile_wall_ns"] += wall_ns
-            _LABEL_COMPILES[label] = _LABEL_COMPILES.get(label, 0) + 1
+            _credit_label_locked(label, "compiles", 1)
             if bypassed_cache:
                 _STATS["cache_bypass_compiles"] += 1
     # credit the executing query's scope as well: under concurrent
@@ -123,7 +163,8 @@ def _record(label: str, compiled: bool, wall_ns: int,
 
 
 def record_transfer(kind: str, nbytes: int, wall_ns: int) -> None:
-    """Accumulate one host<->device staging pass (kind: "h2d" | "d2h")."""
+    """Accumulate one host<->device staging pass (kind: "h2d" | "d2h");
+    the caller's ``tracing.span`` around the pass is its timeline entry."""
     with _LOCK:
         _STATS[kind + "_bytes"] += int(nbytes)
         _STATS[kind + "_ns"] += int(wall_ns)
@@ -131,10 +172,6 @@ def record_transfer(kind: str, nbytes: int, wall_ns: int) -> None:
     if sc is not None:
         sc.add(kind + "_bytes", int(nbytes))
         sc.add(kind + "_ns", int(wall_ns))
-    if _obs_events.active():
-        now = time.monotonic_ns()
-        _obs_events.emit_span(kind, "transfer", t0=now - int(wall_ns),
-                              t1=now, bytes=int(nbytes))
 
 
 # -- use-after-donate guard (tests) ------------------------------------------
@@ -268,13 +305,18 @@ def donation_supported() -> bool:
     return not _DONATION_FORCED_OFF
 
 
+try:  # jax 0.9 keeps it under _src only (jax.core lost the name)
+    from jax._src.core import trace_state_clean as _jax_trace_state_clean
+except ImportError:  # pragma: no cover — moved again
+    _jax_trace_state_clean = getattr(jax.core, "trace_state_clean", None)
+
+
 def _trace_state_clean() -> bool:
     """False while jax is tracing (a nested-jit call inlines, it doesn't
     dispatch)."""
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # noqa: BLE001
+    if _jax_trace_state_clean is None:
         return True
+    return _jax_trace_state_clean()
 
 
 _DONATION_WARNING_FILTERED = False
@@ -308,6 +350,7 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
     if fn is None:
         return functools.partial(instrumented_jit, label=label, **jit_kwargs)
     name = label or getattr(fn, "__name__", "jit")
+    program = program_name(name)
     donate = tuple(jit_kwargs.get("donate_argnums") or ())
     if donate and not donation_supported():
         jit_kwargs = {k: v for k, v in jit_kwargs.items()
@@ -315,7 +358,15 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
         donate = ()
     if donate:
         _filter_donation_warning()
-    jitted = jax.jit(fn, **jit_kwargs)
+
+    # the jitted function carries the program name, so the device
+    # timeline's ``XLA Modules`` line reads ``jit_<program>(<hash>)`` and
+    # jax.monitoring's ``fun_name`` is the key of per_label_compiles()
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    named.__name__ = named.__qualname__ = program
+    jitted = jax.jit(named, **jit_kwargs)
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
@@ -339,31 +390,37 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
             donated_bytes = sum(
                 getattr(leaf, "nbytes", 0) for leaf in donated_leaves)
         before = _cache_size(jitted)
-        t0 = time.monotonic_ns()
-        if donate:
-            # a compile triggered by a donating dispatch must neither read
-            # nor write the persistent cache (deserialized executables
-            # mishandle the donation aliasing — see _install_cache_bypass)
-            with _no_persist_scope():
-                out = jitted(*args, **kwargs)
-        else:
-            out = jitted(*args, **kwargs)
-        t1 = time.monotonic_ns()
-        after = _cache_size(jitted)
-        compiled = after >= 0 and after != before
-        _record(name, compiled, t1 - t0, donated_bytes,
+        # ONE span a jitted call: the host wall of the (asynchronous)
+        # enqueue, compile-inclusive on a first call — never device time
+        prev_program = getattr(_COMPILE_PHASES, "program", None)
+        _COMPILE_PHASES.program = program
+        try:
+            with _tracing.span("enqueue", name,
+                               _tracing.current_op()) as sp:
+                if donate:
+                    # a compile triggered by a donating dispatch must
+                    # neither read nor write the persistent cache
+                    # (deserialized executables mishandle the donation
+                    # aliasing — see _install_cache_bypass)
+                    with _no_persist_scope():
+                        out = jitted(*args, **kwargs)
+                else:
+                    out = jitted(*args, **kwargs)
+                after = _cache_size(jitted)
+                compiled = after >= 0 and after != before
+                if compiled:
+                    sp.set(compiled=True)
+        finally:
+            _COMPILE_PHASES.program = prev_program
+        _record(program, compiled, sp.elapsed_ns, donated_bytes,
                 bypassed_cache=bool(donate))
-        if compiled:
-            _obs_events.emit_span("dispatch", name, t0=t0, t1=t1,
-                                  compiled=True)
-        else:
-            _obs_events.emit_span("dispatch", name, t0=t0, t1=t1)
         if donated_leaves:
             _guard_mark(name, donated_leaves)
         return out
 
     wrapper.jitted = jitted
     wrapper.label = name
+    wrapper.program = program
     return wrapper
 
 
@@ -372,29 +429,42 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
 _MONITORING_HOOKED = False
 
 
-#: Per-thread stack of the exception that was being handled when each
-#: open compile phase started (normally None).  jax brackets tracing,
-#: MLIR lowering and the backend compile with a start scalar and an end
-#: duration that fires even while the phase unwinds by exception.
+#: Per-thread state of the open compile phases.  ``stack`` holds, per
+#: open phase, the exception that was being handled when it started
+#: (normally None): jax brackets tracing, MLIR lowering and the backend
+#: compile with a start scalar and an end duration that fires even while
+#: the phase unwinds by exception.  ``program`` is the instrumented
+#: program being called on this thread; ``retrieved`` the cache-retrieval
+#: seconds jax timed inside the backend phase that is still open.
 _COMPILE_PHASES = threading.local()
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+_PHASE_COUNTER = {_TRACE_EVENT: "trace_ns", _LOWER_EVENT: "lower_ns",
+                  _BACKEND_EVENT: "backend_compile_ns"}
+_CACHE_COUNTER = {_CACHE_HIT_EVENT: "cache_hits",
+                  _CACHE_MISS_EVENT: "cache_misses"}
 
 
 def _on_compile_phase_start(event: str, value: float, **kw) -> None:
-    if "/compile/" not in event:
+    if event not in _PHASE_COUNTER:
         return
-    stack = getattr(_COMPILE_PHASES, "ambient", None)
+    stack = getattr(_COMPILE_PHASES, "stack", None)
     if stack is None:
-        stack = _COMPILE_PHASES.ambient = []
+        stack = _COMPILE_PHASES.stack = []
     stack.append(sys.exception())
 
 
-def _pin_compile_failure(fun_name: str) -> None:
+def _pin_compile_failure(ambient, fun_name: str) -> None:
     """Called as a compile phase ends: an exception in flight that was
     not already being handled when the phase began was raised BY the
     trace/lower/compile — a refusal that no replay can fix, whatever
     its status text says (Mosaic refusals read ``INTERNAL``)."""
-    stack = getattr(_COMPILE_PHASES, "ambient", None)
-    ambient = stack.pop() if stack else None
     err = sys.exception()
     if err is None or err is ambient or \
             getattr(err, "rapids_error_class", None) is not None:
@@ -406,18 +476,54 @@ def _pin_compile_failure(fun_name: str) -> None:
                  f"(never retried, never completed on the CPU)")
 
 
-def _on_event_duration(event: str, duration_secs: float, **kw) -> None:
-    if "compil" not in event:
+def _credit(counter: str, n: int, fun_name: str = "") -> None:
+    """``n`` into the process tally, the compiling query's scope (the
+    listeners fire on the dispatching thread mid-jit) and the program.
+    A phase outside any instrumented call (an eager ``jnp`` op compiling
+    its one-op program) is no part of ``compile_wall_ns``: it is kept
+    under its own name in per_label_compiles() alone."""
+    program = getattr(_COMPILE_PHASES, "program", None)
+    if program is None:
+        if fun_name:
+            with _LOCK:
+                _credit_label_locked(fun_name.removeprefix("jit_"),
+                                     counter, n)
         return
-    if "/compile/" in event:
-        _pin_compile_failure(kw.get("fun_name", "?"))
     with _LOCK:
-        _STATS["backend_compile_ns"] += int(duration_secs * 1e9)
-    # the listener fires on the dispatching thread mid-jit, so the
-    # current scope is the compiling query's
+        _STATS[counter] += n
+        _credit_label_locked(program, counter, n)
     sc = _obs_events.current_scope()
     if sc is not None:
-        sc.add("backend_compile_ns", int(duration_secs * 1e9))
+        sc.add(counter, n)
+
+
+def _on_event_duration(event: str, duration_secs: float, **kw) -> None:
+    ns = int(duration_secs * 1e9)
+    counter = _PHASE_COUNTER.get(event)
+    if counter is not None:
+        stack = getattr(_COMPILE_PHASES, "stack", None)
+        ambient = stack.pop() if stack else None
+        fun_name = kw.get("fun_name", "?")
+        _pin_compile_failure(ambient, fun_name)
+        if stack:
+            return  # nested (a jnp helper traced inside a program)
+        if event == _BACKEND_EVENT:
+            # jax times the persistent-cache read inside this phase
+            ns = max(0, ns - getattr(_COMPILE_PHASES, "retrieved", 0))
+            _COMPILE_PHASES.retrieved = 0
+        _credit(counter, ns, fun_name)
+    elif event == _CACHE_LOAD_EVENT:
+        _COMPILE_PHASES.retrieved = \
+            getattr(_COMPILE_PHASES, "retrieved", 0) + ns
+        _credit("cache_load_ns", ns)
+    # …/compilation_cache/compile_time_saved_sec is time NOT spent: it
+    # (and any other duration) moves no counter
+
+
+def _on_event(event: str, **kw) -> None:
+    counter = _CACHE_COUNTER.get(event)
+    if counter is not None:
+        _credit(counter, 1)
 
 
 def _hook_monitoring() -> None:
@@ -428,6 +534,7 @@ def _hook_monitoring() -> None:
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_on_event_duration)
         monitoring.register_scalar_listener(_on_compile_phase_start)
+        monitoring.register_event_listener(_on_event)
         _MONITORING_HOOKED = True
     except Exception:  # noqa: BLE001 — monitoring API is best-effort
         _MONITORING_HOOKED = True  # don't retry every call

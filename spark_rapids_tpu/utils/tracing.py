@@ -1,30 +1,52 @@
-"""Profiler ranges fused with metrics.
+"""The one span API: every program span lands on the profiler's clock AND
+in the obs ring.
 
 Reference analogue: NvtxWithMetrics (NvtxWithMetrics.scala:27-36) — one
-``with`` block feeds both the profiler timeline and a SQL metric.  On TPU the
-profiler side is XProf via ``jax.profiler.TraceAnnotation`` (the XLA runtime
-exports these through the PJRT profiler C API, SURVEY.md section 2.9 NVTX
-row); the metric side is the ExecContext Metric objects.
+``with`` block feeds both the profiler timeline and a SQL metric.  On TPU
+the profiler side is XProf via ``jax.profiler.TraceAnnotation`` (the XLA
+runtime exports these through the PJRT profiler C API, SURVEY.md section
+2.9 NVTX row), which shares the device's clock; the obs side is the
+per-query event ring (``obs.events``) that ``critpath``, ``QueryProfile``
+and ``rapidsprof`` read.
 
-Device-time accounting: jax dispatch is asynchronous, so the wall time of a
-dispatch call is only a *lower bound* on device execution.  The accurate
-number needs a ``block_until_ready`` on the outputs — a host sync that
-costs a device round trip and kills async overlap, so it is gated behind
-``spark.rapids.sql.tpu.metrics.detailEnabled`` (off by default).
-:func:`device_dispatch` implements both modes for the dispatch sites in
-``plan/pipeline.py`` / ``plan/physical.py``.
+:class:`span` is the only way the program opens a span.  It enters
+``TraceAnnotation("srt/<site>/<name>")`` and, on exit, appends the same
+interval to the ring, so a kept ``.xplane.pb`` holds every program span
+under one prefix next to the device's operations, and the ring holds the
+same intervals on the host clock.  With no profiler session the
+annotation is one flag test; the ring append is what it was.
+
+Sites (``obs.critpath.SITE_PRIORITY`` ranks them): ``device_wait`` — the
+host blocked on the chip (a size read-back, the wait before a D2H copy,
+an exchange's sync, the ``metrics.detailEnabled`` sync); ``h2d`` /
+``d2h`` — staging copies; ``enqueue`` — ONE span per jitted call
+(``compile_registry.instrumented_jit``), the host wall of an
+asynchronous enqueue (compile-inclusive on a first call), never device
+time; ``stage`` — a stage program's whole dispatch (enqueue + any size
+read-back inside it; its wall is the ``deviceTimeNs`` metric);
+``stage_inputs``, ``plan``, ``result``, ``bookkeeping`` — the host parts
+of ``session.execute_with_metrics``; ``scan``, ``io``, ``exchange``,
+``mesh``, ``spill``, ``unspill``, ``pallas``, ``retry``,
+``serve.frontend`` as before.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import threading
 import time
-from typing import Optional
+from typing import Callable, Optional
 
 import jax.profiler
 
 from spark_rapids_tpu.config import METRICS_DETAIL
 from spark_rapids_tpu.obs import events as obs_events
+
+#: every program span's profiler name starts with this
+PREFIX = "srt"
+
+_CURRENT = threading.local()
 
 
 def metrics_detail(conf) -> bool:
@@ -33,65 +55,173 @@ def metrics_detail(conf) -> bool:
     return METRICS_DETAIL.get(conf)
 
 
+class span:
+    """``with span(site, name, op, **payload) as sp:`` — one interval on
+    both timelines.  ``sp.set(k=v)`` adds payload known only inside the
+    body (bytes moved, ``compiled``); a body that raises is recorded with
+    ``error=True``; ``sp.t0`` is the opening stamp and ``sp.elapsed_ns``
+    the closed interval's width, so a metric fed from them agrees with
+    the span to the nanosecond.  Only a ``with`` opens one: the profiler
+    range and the thread's current operator are restored however the body
+    leaves.  ``ring=False`` keeps a span off the ring: a pool worker's
+    side of an interval the consumer records with :func:`record_span`."""
+
+    __slots__ = ("site", "name", "op", "payload", "t0", "elapsed_ns",
+                 "_ann", "_ring", "_outer_op")
+
+    def __init__(self, site: str, name: str, op: str = "",
+                 ring: bool = True, **payload):
+        self.site = site
+        self.name = name
+        self.op = op
+        self.payload = payload
+        self.t0 = 0
+        self.elapsed_ns = 0
+        self._ann = None
+        self._ring = ring
+        self._outer_op = None
+
+    def __enter__(self) -> "span":
+        self._ann = jax.profiler.TraceAnnotation(
+            f"{PREFIX}/{self.site}/{self.name}")
+        self._ann.__enter__()
+        if self.op:
+            # spans opened inside inherit the operator (an exchange's
+            # split programs, a stage's enqueue)
+            self._outer_op = getattr(_CURRENT, "op", "")
+            _CURRENT.op = self.op
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def set(self, **payload) -> None:
+        self.payload.update(payload)
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.monotonic_ns()
+        self._ann.__exit__(None, None, None)
+        if self._outer_op is not None:
+            _CURRENT.op = self._outer_op
+        self.elapsed_ns = t1 - self.t0
+        if exc_type is not None:
+            self.payload["error"] = True
+        if self._ring:
+            obs_events.emit_span(self.site, self.name, self.op,
+                                 self.t0, t1, **self.payload)
+        return False
+
+
+def record_span(site: str, name: str, op: str, t0: int, t1: int,
+                **payload) -> None:
+    """Ring entry for an interval that was timed on another thread (a
+    decode-pool worker's chunk, harvested by the consumer).  The worker
+    opens the profiler range itself with :func:`annotated`."""
+    obs_events.emit_span(site, name, op, t0, t1, **payload)
+
+
+def annotated(site: str, name: str, fn: Callable) -> Callable:
+    """``fn`` run under the profiler range only (``ring=False``): for a
+    pool task whose interval the consumer hands to :func:`record_span`."""
+    def run(*args, **kwargs):
+        with span(site, name, ring=False):
+            return fn(*args, **kwargs)
+    return run
+
+
+def kernel_scope(fn: Callable) -> Callable:
+    """Decorator: trace ``fn`` under ``jax.named_scope("k.<module>.<fn>")``
+    — the kernel entry points a device trace blames (row gathers, the
+    compaction, group-by, sort keys, the join probe, string byte
+    gathers), so their HLO operations say which kernel they came from
+    inside whichever operator scope inlined them."""
+    scope = f"k.{fn.__module__.rsplit('.', 1)[-1]}.{fn.__name__}"
+
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+    return run
+
+
+def current_op() -> str:
+    """The operator of the innermost open span that names one, on this
+    thread: what an ``enqueue`` or ``device_wait`` span opened now is
+    working for."""
+    return getattr(_CURRENT, "op", "")
+
+
+def device_wait(name: str, tree, op: str = ""):
+    """Block until ``tree``'s buffers are ready, under a ``device_wait``
+    span: the host's wall on the chip, by name."""
+    with span("device_wait", name, op or current_op()):
+        return jax.block_until_ready(tree)
+
+
+def device_read(name: str, tree, op: str = ""):
+    """``jax.device_get(tree)`` under a ``device_wait`` span: a size or
+    flag read-back out of programs still in flight is where the host
+    waits for the chip (the bytes are a few scalars)."""
+    with span("device_wait", name, op or current_op()):
+        return jax.device_get(tree)
+
+
 @contextlib.contextmanager
-def trace_range(name: str, metric=None):
-    """Profiler range + optional elapsed-nanos metric accumulation."""
-    t0 = time.monotonic_ns()
-    with jax.profiler.TraceAnnotation(name):
-        yield
-    if metric is not None:
-        metric.add(time.monotonic_ns() - t0)
+def trace_range(site: str, name: str, metric=None):
+    """A container range (a partition's drive loop, the whole collect)
+    plus optional elapsed-nanos metric accumulation.  Profiler side
+    only: on the ring it would cover, and so rename, every host gap
+    ``critpath`` reports as ``wait``."""
+    sp = span(site, name, ring=False)
+    try:
+        with sp:
+            yield
+    finally:
+        if metric is not None:
+            metric.add(sp.elapsed_ns)
 
 
 @contextlib.contextmanager
 def device_dispatch(ctx, op_id: str, name: str,
                     obs_op: Optional[str] = None):
-    """Time one device program dispatch into ``ctx.metric(op_id,
-    'deviceTimeNs')`` under a profiler range.
+    """One stage program's dispatch: span ``srt/stage/<name>`` whose wall
+    goes into ``ctx.metric(op_id, 'deviceTimeNs')``.
 
-    The body sets ``holder['outputs']`` to the dispatched result.  With
-    the metrics-detail conf on, the outputs are blocked on before the
-    clock stops — on pre-staged (already device-resident) inputs that
-    delta IS device execution time; ``deviceTimeSyncs`` counts how many
-    accurate samples the total contains.  Detail off: the dispatch wall
-    alone is recorded (a lower bound, async dispatch).
+    That wall is the HOST's: the asynchronous enqueue(s) plus any size
+    read-back the body takes — not device time (docs/metrics.md).  The
+    jitted calls inside open their own ``enqueue`` spans and inherit
+    ``obs_op`` as their operator.  The body sets ``holder['outputs']``
+    to the dispatched result; with the metrics-detail conf on they are
+    blocked on — a ``device_wait`` span — before the clock stops, and
+    ``deviceTimeSyncs`` counts those samples.
 
     The elapsed time is recorded in a ``finally`` so a dispatch that
     raises (an injected fault, an OOM about to be retried) still shows
     in the metric and the profile instead of vanishing; the failed
-    attempt's obs span is tagged ``error``.  ``obs_op`` names the
+    attempt's span is tagged ``error``.  ``obs_op`` names the
     physical-plan node the span is attributed to when the metric op_id
     is a shared bucket (the pipeline dispatcher passes the stage root's
     op_id here while keeping the metric under ``"pipeline"``).
     """
     holder: dict = {}
-    err = False
-    t0 = time.monotonic_ns()
+    op = obs_op or op_id
+    sp = span("stage", name, op)
     try:
-        with jax.profiler.TraceAnnotation(f"{op_id}:{name}"):
+        with sp:
             yield holder
             if metrics_detail(ctx.conf) and \
                     holder.get("outputs") is not None:
-                jax.block_until_ready(holder["outputs"])
+                device_wait("metrics_detail", holder["outputs"], op)
                 ctx.metric(op_id, "deviceTimeSyncs").add(1)
-    except BaseException:
-        err = True
-        raise
     finally:
-        elapsed = time.monotonic_ns() - t0
-        ctx.metric(op_id, "deviceTimeNs").add(elapsed)
-        if err:
+        ctx.metric(op_id, "deviceTimeNs").add(sp.elapsed_ns)
+        if sp.payload.get("error"):
             ctx.metric(op_id, "deviceTimeErrors").add(1)
-            obs_events.emit_span("device", name, obs_op or op_id,
-                                 t0, t0 + elapsed, error=True)
-        else:
-            obs_events.emit_span("device", name, obs_op or op_id,
-                                 t0, t0 + elapsed)
 
 
 def start_profile(logdir: str):
     """Begin an XProf capture (nsys-capture analogue,
-    docs/dev/nvtx_profiling.md)."""
+    docs/dev/nvtx_profiling.md).  The capture keeps the HLO proto, so
+    ``tools/rapidsprof.py --xplane`` can tie every device operation to
+    its operator and kernel scope."""
     jax.profiler.start_trace(logdir)
 
 

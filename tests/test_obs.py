@@ -104,13 +104,16 @@ def test_rollup_matches_last_metrics_on_shuffle_spill_query():
 
         m = s.last_metrics
         p = s.query_history()[-1]
-        # dispatch: one device span per compiled-program dispatch
-        assert p.site("dispatch")["count"] == m["dispatchCount"]
-        # device time: every nanosecond the metric pipeline charged is
-        # attributed to a named operator (>=90% is the acceptance floor;
-        # the spans add the exact same elapsed values, so it is exact)
+        # enqueue: ONE span per compiled-program dispatch (no second
+        # `device`/`dispatch` span over the same call)
+        assert p.site("enqueue")["count"] == m["dispatchCount"]
+        assert p.site("dispatch")["count"] == p.site("device")["count"] == 0
+        # deviceTimeNs is the host wall of the stage dispatches: the
+        # `stage` spans add the exact same elapsed values, so it is exact
         assert m["deviceTimeNs"] > 0
-        assert p.attributed_device_ns == m["deviceTimeNs"]
+        assert p.site("stage")["wall_ns"] == m["deviceTimeNs"]
+        # every enqueue nanosecond is tied to an operator or a program
+        assert p.attributed_enqueue_ns == p.site("enqueue")["wall_ns"]
         # shuffle: exchange split/mesh spans carry the same bytes the
         # per-op shuffleBytes metric accumulated
         assert m["shuffleBytes"] > 0
@@ -171,7 +174,7 @@ def test_jsonl_roundtrip_through_rapidsprof(tmp_path):
          logs[0], "--chrome", trace],
         capture_output=True, text=True, cwd=REPO_ROOT, timeout=120)
     assert proc.returncode == 0, proc.stderr
-    assert "top operators by device time" in proc.stdout
+    assert "top operators by enqueue wall" in proc.stdout
     assert "Exec" in proc.stdout  # names at least one real operator
     with open(trace) as f:
         tdoc = json.load(f)
@@ -207,4 +210,4 @@ def test_explain_last_metrics_annotates_operators():
     _simple_query(s).collect()
     text = s.explain_last(metrics=True)
     assert "dispatches=" in text
-    assert "device=" in text
+    assert "enqueue=" in text

@@ -165,12 +165,12 @@ def test_render_intervals_empty_and_window():
 
 
 def test_critpath_exact_partition_with_overlap_and_priority():
-    # window [1000, 1100): exchange covers [1005,1040) with a device
-    # span nested inside [1010,1030) — device outranks exchange, so the
-    # exchange is credited only its host-side remainder.
+    # window [1000, 1100): exchange covers [1005,1040) with a wait on
+    # the device nested inside [1010,1030) — device_wait outranks
+    # exchange, so the exchange is credited only its host-side remainder.
     evs = [
         {"kind": "span", "site": "exchange", "t0": 1005, "t1": 1040},
-        {"kind": "span", "site": "device", "t0": 1010, "t1": 1030},
+        {"kind": "span", "site": "device_wait", "t0": 1010, "t1": 1030},
         {"kind": "span", "site": "io", "t0": 1050, "t1": 1060},
         {"kind": "instant", "site": "fault", "t0": 1055, "t1": 1055},
         {"kind": "span", "site": "h2d", "t0": 1090, "t1": 1500},  # clipped
@@ -179,12 +179,12 @@ def test_critpath_exact_partition_with_overlap_and_priority():
     cp = obs_critpath.compute(evs, 1000, 1100)
     assert cp.total_ns == 100
     assert sum(cp.segments.values()) == cp.total_ns  # exact by construction
-    assert cp.segments == {"exchange": 15, "device": 20, "io": 10,
+    assert cp.segments == {"exchange": 15, "device_wait": 20, "io": 10,
                            "h2d": 10, "wait": 45}
     assert cp.attributed_ns == 55
     # the chain is a merged, ordered partition of the window
     assert cp.chain[0] == ("wait", 1000, 1005)
-    assert [c[0] for c in cp.chain] == ["wait", "exchange", "device",
+    assert [c[0] for c in cp.chain] == ["wait", "exchange", "device_wait",
                                         "exchange", "wait", "io", "wait",
                                         "h2d"]
     assert all(a[2] == b[1] for a, b in zip(cp.chain, cp.chain[1:]))
@@ -196,8 +196,8 @@ def test_critpath_empty_window_and_unknown_site():
     assert obs_critpath.compute([], 50, 50).segments == {}
     cp = obs_critpath.compute(
         [{"kind": "span", "site": "weird", "t0": 10, "t1": 20},
-         {"kind": "span", "site": "device", "t0": 12, "t1": 14}], 10, 20)
-    assert cp.segments == {"weird": 8, "device": 2}  # unknown = lowest rank
+         {"kind": "span", "site": "enqueue", "t0": 12, "t1": 14}], 10, 20)
+    assert cp.segments == {"weird": 8, "enqueue": 2}  # unknown = lowest rank
 
 
 # -- critical path: end to end ------------------------------------------------
@@ -457,7 +457,7 @@ def test_rapidstop_renders_flushed_telemetry_without_jax(tmp_path):
     assert os.path.exists(tpath)
     intervals = obs_ts.read_telemetry_log(tpath)
     assert intervals
-    assert any("dispatch" in (iv.get("sites") or {}) for iv in intervals)
+    assert any("enqueue" in (iv.get("sites") or {}) for iv in intervals)
 
     # the CLI renders the table and the Prometheus view in a fresh
     # process that must never import jax (runtime-free discipline)
@@ -476,13 +476,13 @@ def test_rapidstop_renders_flushed_telemetry_without_jax(tmp_path):
                           timeout=120)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "telemetry:" in proc.stdout
-    assert "dispatch" in proc.stdout
+    assert "enqueue" in proc.stdout
     prom = subprocess.run([sys.executable, tool, tpath, "--prom"],
                           capture_output=True, text=True, cwd=REPO_ROOT,
                           timeout=120)
     assert prom.returncode == 0, prom.stderr
     assert "rapids_telemetry_intervals_total" in prom.stdout
-    assert 'rapids_site_events_total{site="dispatch"}' in prom.stdout
+    assert 'rapids_site_events_total{site="enqueue"}' in prom.stdout
     missing = subprocess.run(
         [sys.executable, tool, str(tmp_path / "nope.jsonl"), "--once"],
         capture_output=True, text=True, cwd=REPO_ROOT, timeout=120)

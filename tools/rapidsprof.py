@@ -4,19 +4,28 @@
 Usage:
     python tools/rapidsprof.py <events.jsonl> [more.jsonl ...]
         [--top N] [--query ID] [--chrome out.json] [--critpath]
+    python tools/rapidsprof.py --xplane <file.xplane.pb> [--top N]
 
 Reads the JSONL event log(s) a session wrote under
 ``spark.rapids.sql.tpu.obs.eventLogDir`` and prints, per query and in
-aggregate: top operators by device time, transfer/spill pressure, the
+aggregate: top operators by enqueue wall, transfer/spill pressure, the
 retry/fault summary, and a per-query comparison table.  ``--chrome``
 additionally exports a Chrome ``trace_event`` JSON (load it in Perfetto
 or chrome://tracing).
+
+``--xplane`` reads a ``jax.profiler`` trace instead (made with
+``benchmark/run.py --trace 1 --keep-trace PATH`` or
+``utils.tracing.start_profile``) and prints which operator ate the
+DEVICE's time: self-time by ``XLA Modules`` name (the stage program) ->
+operator scope -> kernel scope, and the idle gaps by the innermost
+``srt/<site>/<name>`` program span the host was in (``obs/xplane.py``).
 
 Runtime-free by construction (the RAPIDS profiling-tool role, and the
 same loading discipline as ``rapidslint``): the ``obs`` package is
 loaded standalone without executing the engine's root ``__init__``, so
 no jax import and no device runtime — a log from a TPU host analyzes on
-any laptop.
+any laptop.  Only ``--xplane`` imports more than the stdlib: tsl's
+generated ``xplane_pb2`` (``google.protobuf``), on first use.
 """
 
 from __future__ import annotations
@@ -46,6 +55,7 @@ def _load_obs():
 _obs = _load_obs()
 from rapidsprof_obs import critpath as obs_critpath  # noqa: E402
 from rapidsprof_obs import export as obs_export  # noqa: E402
+from rapidsprof_obs import xplane as obs_xplane  # noqa: E402
 from rapidsprof_obs.profile import QueryProfile  # noqa: E402
 
 
@@ -93,21 +103,21 @@ def report(profiles, top_n: int = 10, critpath: bool = False) -> str:
                                   "recorded)")
             lines.append("")
 
-    # aggregate top operators by device time
+    # aggregate top operators by enqueue wall
     merged = {}
     for p in profiles:
         for r in p.top_operators(10 ** 9):
             m = merged.setdefault(
                 r["op_id"] or r["name"],
-                {"name": r["name"], "device_ns": 0, "dispatches": 0,
+                {"name": r["name"], "enqueue_ns": 0, "dispatches": 0,
                  "errors": 0, "shuffle_bytes": 0})
             m["name"] = m["name"] or r["name"]
-            m["device_ns"] += r["device_ns"]
+            m["enqueue_ns"] += r["enqueue_ns"]
             m["dispatches"] += r["dispatches"]
             m["errors"] += r["errors"]
             m["shuffle_bytes"] += r["shuffle_bytes"]
-    lines.append("== top operators by device time ==")
-    ops = sorted(merged.values(), key=lambda m: m["device_ns"],
+    lines.append("== top operators by enqueue wall ==")
+    ops = sorted(merged.values(), key=lambda m: m["enqueue_ns"],
                  reverse=True)[:top_n]
     if not ops:
         lines.append("  (no operator events)")
@@ -115,7 +125,7 @@ def report(profiles, top_n: int = 10, critpath: bool = False) -> str:
         extra = f", {m['errors']} errored" if m["errors"] else ""
         sh = f", shuffle {_mb(m['shuffle_bytes'])}" \
             if m["shuffle_bytes"] else ""
-        lines.append(f"  {m['name'] or '?'}: {m['device_ns'] / 1e6:.2f} ms "
+        lines.append(f"  {m['name'] or '?'}: {m['enqueue_ns'] / 1e6:.2f} ms "
                      f"across {m['dispatches']} dispatches{extra}{sh}")
 
     # transfer/spill pressure
@@ -170,16 +180,16 @@ def report(profiles, top_n: int = 10, critpath: bool = False) -> str:
     if len(profiles) > 1:
         lines.append("")
         lines.append("== per-query comparison ==")
-        lines.append("  query | sess | wall ms | device ms | events | "
+        lines.append("  query | sess | wall ms | enqueue ms | events | "
                      "dropped | dispatches | shuffle MB")
         for p in profiles:
             sh = sum(r["shuffle_bytes"] for r in p.op_rollups.values())
             lines.append(
                 f"  {p.query_id:>5} | {p.session_id:>4} | "
                 f"{p.wall_ns / 1e6:>7.1f} | "
-                f"{p.attributed_device_ns / 1e6:>9.2f} | "
+                f"{p.attributed_enqueue_ns / 1e6:>9.2f} | "
                 f"{p.event_count:>6} | {p.dropped:>7} | "
-                f"{p.site('dispatch')['count']:>10} | "
+                f"{p.site('enqueue')['count']:>10} | "
                 f"{sh / (1 << 20):>10.2f}")
     return "\n".join(lines)
 
@@ -187,7 +197,11 @@ def report(profiles, top_n: int = 10, critpath: bool = False) -> str:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="analyze spark_rapids_tpu obs event logs")
-    ap.add_argument("logs", nargs="+", help="JSONL event log path(s)")
+    ap.add_argument("logs", nargs="*", help="JSONL event log path(s)")
+    ap.add_argument("--xplane", default=None, metavar="FILE",
+                    help="read a jax.profiler .xplane.pb instead: device "
+                         "time by stage program/operator/kernel scope, "
+                         "idle gaps by srt/ span")
     ap.add_argument("--top", type=int, default=10,
                     help="operators to list (default 10)")
     ap.add_argument("--query", type=int, default=None,
@@ -199,6 +213,15 @@ def main(argv=None) -> int:
                          "decomposition")
     args = ap.parse_args(argv)
 
+    if args.xplane:
+        reduced = obs_xplane.reduce_xplane(args.xplane)
+        if reduced is None:
+            print("no TPU device plane in", args.xplane)
+            return 2
+        print(obs_xplane.format_report(reduced, max(args.top, 25)))
+        return 0
+    if not args.logs:
+        ap.error("give event log path(s) or --xplane FILE")
     profiles = load_profiles(args.logs)
     if args.query is not None:
         profiles = [p for p in profiles if p.query_id == args.query]
