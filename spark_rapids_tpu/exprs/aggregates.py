@@ -57,6 +57,8 @@ class AggBufferSpec:
 class AggregateFunction(Expression):
     """Base: declares buffers + segment kernels.  Not columnar-evaluable."""
 
+    context_free = False  # a reduction over rows, whatever its argument
+
     def __init__(self, child: Expression):
         self.children = (child,)
         self._resolve_type()

@@ -171,12 +171,17 @@ class Expression:
     children: Tuple["Expression", ...] = ()
     dtype: T.DataType = T.NULL
     nullable: bool = True
+    #: False on a class whose value is not a function of its children's
+    #: values alone (row position, partition, a seed, opaque python, a
+    #: reduction over rows): the planner never folds it to a constant
+    context_free: bool = True
 
     def __init_subclass__(cls, **kwargs):
         # every expression class traces its device evaluation under
         # ``jax.named_scope("e.<Class>")``: a device trace can then say
-        # which expression an operator's time went to (a per-row
-        # ``e.ToDate`` over a literal was q6's largest cost, PERF.md)
+        # which expression an operator's time went to (it showed q6
+        # parsing ``to_date('<literal>')`` per row, 35 % of its device
+        # time, until the planner folded constants: PERF.md, PR 27)
         super().__init_subclass__(**kwargs)
         own = cls.__dict__.get("tpu_eval")
         if own is not None:
@@ -229,6 +234,18 @@ class Expression:
     @property
     def references(self) -> List[str]:
         return [e.column for e in self.collect(lambda e: isinstance(e, ColumnRef))]
+
+    @property
+    def foldable(self) -> bool:
+        """True when the planner may replace this subtree by the
+        :class:`Literal` its ``cpu_eval`` gives on any one row
+        (Catalyst's ``Expression.foldable``): a context-free class over
+        foldable children.  A leaf other than a ``Literal`` takes its
+        value from outside the tree (a column, the row's position), and
+        a ``Literal`` cannot carry an array."""
+        return (self.context_free and bool(self.children)
+                and not self.dtype.is_array
+                and all(c.foldable for c in self.children))
 
     # -- evaluation ---------------------------------------------------------
 
@@ -315,6 +332,8 @@ def eval_maybe_encoded(expr: "Expression", ctx: TpuEvalCtx) -> DevVal:
 
 
 class Literal(Expression):
+    foldable = True
+
     def __init__(self, value: Any, dtype: Optional[T.DataType] = None):
         if dtype is None:
             dtype = infer_literal_type(value)
@@ -382,6 +401,9 @@ def infer_literal_type(value: Any) -> T.DataType:
 
 
 class Alias(Expression):
+    # the planner folds beneath an alias: the name stays in the tree
+    foldable = False
+
     def __init__(self, child: Expression, alias_name: str):
         self.children = (child,)
         self.alias_name = alias_name

@@ -17,6 +17,8 @@ from spark_rapids_tpu.exprs.base import (
 class MonotonicallyIncreasingID(Expression):
     """(partition_id << 33) + row offset within partition."""
 
+    context_free = False
+
     def __init__(self):
         self.children = ()
         self.dtype = T.LONG
@@ -37,6 +39,8 @@ class MonotonicallyIncreasingID(Expression):
 
 
 class SparkPartitionID(Expression):
+    context_free = False
+
     def __init__(self):
         self.children = ()
         self.dtype = T.INT
@@ -59,6 +63,8 @@ class Rand(Expression):
     (seed, partition, base row id) — results differ from Spark CPU's XORShift
     but are deterministic per plan execution (the reference flags GpuRand as
     'retries are not idempotent')."""
+
+    context_free = False
 
     def __init__(self, seed: int = 0):
         self.children = ()

@@ -24,6 +24,9 @@ from spark_rapids_tpu.exprs.base import CpuVal, Expression
 
 
 class PythonUDF(Expression):
+    # opaque python: it may read a clock, a file, its own call count
+    context_free = False
+
     def __init__(self, fn: Callable, return_type: T.DataType,
                  *children: Expression, name: Optional[str] = None):
         self.fn = fn

@@ -49,6 +49,8 @@ class WindowFrame:
 class WindowFunction(Expression):
     """Marker base for ranking/offset window functions."""
 
+    context_free = False  # the row's place in its partition
+
     needs_order = True
 
 
@@ -101,6 +103,8 @@ class Lead(Lag):
 
 class WindowExpression(Expression):
     """function OVER (PARTITION BY ... ORDER BY ... frame)."""
+
+    context_free = False
 
     def __init__(self, function: Expression,
                  partition_by: List[Expression],

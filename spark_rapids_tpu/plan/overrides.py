@@ -21,9 +21,12 @@ from typing import List, Optional, Tuple
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.config import RapidsConf
 from spark_rapids_tpu.exprs.base import (
-    ColumnRef, Expression, SortOrder, resolve,
+    ColumnRef, CpuEvalCtx, Expression, Literal, SortOrder, resolve,
 )
-from spark_rapids_tpu.exprs.aggregates import AggregateFunction
+from spark_rapids_tpu.exprs.aggregates import (
+    AggregateExpression, AggregateFunction, Average, Count, Max, Min, Sum,
+)
+from spark_rapids_tpu.exprs.conditional import If
 from spark_rapids_tpu.plan import logical as L
 from spark_rapids_tpu.ops import cpu_exec as C
 from spark_rapids_tpu.ops import tpu_exec as X
@@ -37,6 +40,7 @@ from spark_rapids_tpu.parallel.partitioning import (
 from spark_rapids_tpu.plan.physical import (
     DeviceToHostExec, HostToDeviceExec, PhysicalOp,
 )
+from spark_rapids_tpu.utils.tracing import span
 
 
 class ExprMeta:
@@ -217,9 +221,21 @@ class TpuOverrides:
         if self.conf.get("spark.rapids.sql.scan.pushdown.enabled", True) \
                 not in (False, "false"):
             plan = _pushdown_scan_filters(plan)
+        # before tagging: a constant subtree with no device implementation
+        # (cast('…' as date)) must not send its operator to the CPU
+        with span("plan", "fold") as sp:
+            plan, folded = _fold_constants(plan)
+            sp.set(folded=len(folded))
+        plan, absorbed = _filters_into_keyless_aggregates(plan)
         meta = PlanMeta(plan, self.conf)
         self.tag(meta)
-        self.last_explain = "\n".join(meta.explain_lines())
+        lines = meta.explain_lines()
+        if folded:
+            lines.append(f"folded {len(folded)}: " + ", ".join(folded))
+        if absorbed:
+            lines.append("filter applied inside the keyless aggregate "
+                         "above it: " + ", ".join(absorbed))
+        self.last_explain = "\n".join(lines)
         if self.conf.explain_enabled:
             # routed through the obs sink (a logger by default) instead of
             # print(): library embedders and pytest capture aren't spammed,
@@ -234,7 +250,11 @@ class TpuOverrides:
         # last: every planner (session, ml, the recovery's CPU re-lowering)
         # hands out a tree whose op ids are its pre-order positions
         from spark_rapids_tpu.plan.physical import assign_op_ids
-        return assign_op_ids(phys)
+        phys = assign_op_ids(phys)
+        # kept with the plan: every query that runs it (hit or miss of
+        # the plan cache) publishes it as last_metrics["foldedExprs"]
+        phys.folded_exprs = len(folded)
+        return phys
 
     def _shuffle_parts(self) -> int:
         return self.conf.shuffle_partitions
@@ -619,6 +639,12 @@ class TpuOverrides:
         return C.CpuLocalLimitExec(node.n, single)
 
 
+#: aggregate functions that skip NULL arguments and do not depend on the
+#: order of their rows (``first``/``last`` do): for these a dropped row
+#: and a NULL argument are the same thing
+_NULL_SKIPPING_AGGS = (Sum, Count, Min, Max, Average)
+
+
 class _FakeNode:
     """Minimal logical-node stand-in for recursive planner helpers."""
 
@@ -669,6 +695,140 @@ def _pushdown_scan_filters(plan: L.LogicalPlan) -> L.LogicalPlan:
     clone = copy.copy(plan)
     clone.children = tuple(new_children)
     return clone
+
+
+def _constant_literal(e: Expression) -> Optional[Literal]:
+    """The :class:`Literal` a foldable ``e`` evaluates to on the CPU
+    oracle — by definition the value the unfolded plan computes on every
+    row — typed by the expression's resolved dtype (DATE stays DATE, held
+    as days since the epoch); None when ``cpu_eval`` raises or answers
+    in another type, and the subtree stays as written."""
+    import numpy as np
+    from spark_rapids_tpu.batch import HostBatch
+    one_row = HostBatch.from_pydict({"__fold": (T.INT, [0])})
+    try:
+        v = e.cpu_eval(CpuEvalCtx(one_row))
+    except Exception:
+        return None
+    if v.dtype != e.dtype:
+        return None
+    if not v.validity[0]:
+        return Literal(None, e.dtype)
+    value = v.values[0]
+    return Literal(value.item() if isinstance(value, np.generic) else value,
+                   e.dtype)
+
+
+def _fold_constants(plan: L.LogicalPlan
+                    ) -> Tuple[L.LogicalPlan, List[str]]:
+    """Constant folding (Catalyst's ``ConstantFolding``; Spark never ships
+    ``to_date('1994-01-01')`` to an executor, this engine plans for itself
+    and has to do it): in every expression a logical node carries, replace
+    each MAXIMAL foldable subtree that is not already a literal by the
+    Literal it evaluates to.  Returns the plan and one
+    ``<expr> -> lit(<value>:<type>)`` per fold.
+
+    Non-mutating, like :func:`_pushdown_scan_filters`: untouched
+    expressions, lists and subtrees come back as the ORIGINAL objects, so
+    a plan with nothing to fold is returned itself.  Output names live on
+    the nodes (``Project.names``, ``AggregateExpression.output_name``) or
+    in an ``Alias``, which is never folded away, so no column is renamed."""
+    import copy
+    folded: List[str] = []
+
+    def fold_expr(e: Expression) -> Expression:
+        if isinstance(e, Literal):
+            return e
+        if e.foldable:
+            lit = _constant_literal(e)
+            if lit is not None:
+                folded.append(f"{e!r} -> lit({lit.value!r}:{lit.dtype})")
+                return lit
+            return e
+        kids = [fold_expr(c) for c in e.children]
+        if all(n is o for n, o in zip(kids, e.children)):
+            return e
+        return e.with_children(kids)
+
+    def fold_value(v):
+        if isinstance(v, Expression):
+            return fold_expr(v)
+        if isinstance(v, L.LogicalPlan):
+            return fold_node(v)
+        if isinstance(v, SortOrder):
+            child = fold_expr(v.child)
+            return v if child is v.child else \
+                SortOrder(child, v.ascending, v.nulls_first)
+        if isinstance(v, AggregateExpression):
+            fn = fold_expr(v.fn)
+            return v if fn is v.fn else AggregateExpression(fn, v.output_name)
+        if isinstance(v, (list, tuple)):
+            new = [fold_value(x) for x in v]
+            return v if all(n is o for n, o in zip(new, v)) else type(v)(new)
+        return v
+
+    def fold_node(node: L.LogicalPlan) -> L.LogicalPlan:
+        new = {k: fold_value(v) for k, v in vars(node).items()}
+        if all(new[k] is v for k, v in vars(node).items()):
+            return node
+        clone = copy.copy(node)
+        vars(clone).update(new)
+        return clone
+
+    return fold_node(plan), folded
+
+
+def _filters_into_keyless_aggregates(plan: L.LogicalPlan
+                                     ) -> Tuple[L.LogicalPlan, List[str]]:
+    """``SELECT sum(x) … WHERE p`` plans as ``sum(if(p, x, NULL))`` over
+    the filter's input: a Filter directly under a keyless Aggregate whose
+    functions all skip NULL arguments becomes a condition on each
+    argument, and the Filter node goes.  The reduction emits its one row
+    either way, a row the filter drops contributes a NULL, which those
+    functions skip — and the device no longer compacts the surviving rows
+    of every column the aggregate reads (one million-row gather a column
+    and batch: 89 % of TPC-H Q6's device time, PERF.md, PR 27) only to sum
+    them.  A keyed aggregate keeps its Filter: a group whose rows are all
+    dropped must not appear.  Returns the plan and each absorbed condition.
+
+    Only where the rewrite cannot show: every function is one of
+    :data:`_NULL_SKIPPING_AGGS` over a non-string argument (``if`` has no
+    device form for strings), and neither the condition nor an argument
+    holds an expression that is not ``context_free`` (a ``rand()`` would
+    be drawn once per function, and for other rows).  Non-mutating, like
+    :func:`_pushdown_scan_filters`; it runs after the pushdown, so a file
+    scan keeps the conjuncts it skips row groups by."""
+    import copy
+    absorbed: List[str] = []
+
+    def context_free(e: Expression) -> bool:
+        return not e.collect(lambda x: not x.context_free)
+
+    def absorbable(agg: L.Aggregate, cond: Expression) -> bool:
+        return bool(agg.aggs) and context_free(cond) and all(
+            type(a.fn) in _NULL_SKIPPING_AGGS
+            and not a.fn.child.dtype.is_string
+            and context_free(a.fn.child) for a in agg.aggs)
+
+    def rewrite(node: L.LogicalPlan) -> L.LogicalPlan:
+        children = tuple(rewrite(c) for c in node.children)
+        if any(n is not o for n, o in zip(children, node.children)):
+            node = copy.copy(node)
+            node.children = children
+        while isinstance(node, L.Aggregate) and not node.keys and \
+                isinstance(node.children[0], L.Filter) and \
+                absorbable(node, node.children[0].condition):
+            below = node.children[0]
+            cond = below.condition
+            absorbed.append(repr(cond))
+            node = L.Aggregate([], [], [
+                AggregateExpression(a.fn.with_children([If(
+                    cond, a.fn.child, Literal(None, a.fn.child.dtype))]),
+                    a.output_name)
+                for a in node.aggs], below.children[0])
+        return node
+
+    return rewrite(plan), absorbed
 
 
 def _compile_plan_udfs(plan: L.LogicalPlan) -> L.LogicalPlan:

@@ -132,6 +132,8 @@ class PhysicalOp:
     """Base physical operator."""
 
     is_tpu = False
+    #: on a planned tree's root: constant subtrees TpuOverrides.apply folded
+    folded_exprs = 0
 
     def __init__(self, children: List["PhysicalOp"], output_schema: T.Schema):
         self.children = children

@@ -377,6 +377,9 @@ class TpuSparkSession:
         # cached executables trace nothing and count nothing)
         frame.last_metrics["pallasFallbackCount"] = \
             pallas_tier.fallback_count() - pt_before
+        # constant subtrees the planner folded to literals when it built
+        # this plan (kept on its root, so a plan-cache hit reads it too)
+        frame.last_metrics["foldedExprs"] = phys.folded_exprs
         frame.last_metrics["compileCount"] = d["compiles"]
         frame.last_metrics["compileWallNs"] = d["compile_wall_ns"]
         frame.last_metrics["dispatchCount"] = d["dispatches"]

@@ -2,10 +2,12 @@
 analogue (reference tests/: every test body runs under a CPU session and a
 TPU session and the collected results must match)."""
 
+import jax
 import pytest
 
 from spark_rapids_tpu.config import RapidsConf
 from spark_rapids_tpu.session import TpuSparkSession
+from spark_rapids_tpu.utils import compile_registry as CR
 
 from conftest import FLOAT_ABS, FLOAT_REL, TEST_PLATFORM
 
@@ -106,3 +108,37 @@ def _row_approx_eq(ra, rb, i):
                                        abs=max(FLOAT_ABS, 1e-6)), f"row {i}"
         else:
             assert va == vb, f"row {i}: {va!r} vs {vb!r}"
+
+
+class _LoweringJax:
+    """Stands in for ``jax`` inside compile_registry: ``jit`` keeps the
+    lowered text (with debug info) of every program's first call."""
+
+    def __init__(self, texts):
+        self._texts = texts
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    def jit(self, fn, **kw):
+        real, texts = jax.jit(fn, **kw), self._texts
+
+        class Jitted:
+            def __call__(self, *a, **k):
+                if fn.__name__ not in texts:
+                    texts[fn.__name__] = real.lower(*a, **k).as_text(
+                        debug_info=True)
+                return real(*a, **k)
+
+            def _cache_size(self):
+                return real._cache_size()
+
+        return Jitted()
+
+
+def lowered_stage_texts(monkeypatch, build_df, **confs):
+    texts = {}
+    monkeypatch.setattr(CR, "jax", _LoweringJax(texts))
+    s = tpu_session(**confs)
+    build_df(s).collect()
+    return s, texts

@@ -13,10 +13,9 @@ import subprocess
 import sys
 import threading
 
-import jax
 import pytest
 
-from compare import tpu_session
+from compare import lowered_stage_texts, tpu_session
 from spark_rapids_tpu import functions as F
 from spark_rapids_tpu.obs import xplane as obs_xplane
 from spark_rapids_tpu.utils import compile_registry as CR
@@ -44,56 +43,23 @@ def _q6_shaped(s, n=512, tag="q6"):
                  .alias("revenue")))
 
 
-class _LoweringJax:
-    """Stands in for ``jax`` inside compile_registry: ``jit`` keeps the
-    lowered text (with debug info) of every program's first call."""
-
-    def __init__(self, texts):
-        self._texts = texts
-
-    def __getattr__(self, name):
-        return getattr(jax, name)
-
-    def jit(self, fn, **kw):
-        real, texts = jax.jit(fn, **kw), self._texts
-
-        class Jitted:
-            def __call__(self, *a, **k):
-                if fn.__name__ not in texts:
-                    texts[fn.__name__] = real.lower(*a, **k).as_text(
-                        debug_info=True)
-                return real(*a, **k)
-
-            def _cache_size(self):
-                return real._cache_size()
-
-        return Jitted()
-
-
-def _lowered_stage_texts(monkeypatch, build_df, **confs):
-    texts = {}
-    monkeypatch.setattr(CR, "jax", _LoweringJax(texts))
-    s = tpu_session(**confs)
-    build_df(s).collect()
-    return s, texts
-
-
 # -- names on the device timeline ---------------------------------------------
 
 
 def test_stage_program_module_name_and_scopes(monkeypatch):
     """A q6-shaped stage lowers to a module named after its label, and
     its operations carry the operator's and the kernels' scopes."""
-    s, texts = _lowered_stage_texts(
+    s, texts = lowered_stage_texts(
         monkeypatch, lambda s: _q6_shaped(s, tag="names"), **FLOAT_AGG)
     stage = [n for n in texts if n.startswith("stage_")]
     assert stage, texts.keys()
     for name in stage:
         assert re.search(rf"module @jit_{name}\b", texts[name])
     assert not any(n == "run" for n in texts)   # no more jit_run(<hash>)
-    update = next(t for t in texts.values() if "k.layout.gather_rows" in t)
-    # the planner folds Q6's filter into the update aggregate: its scope is
-    # the aggregate's, <Class>.<pre-order position>
+    update = next(t for t in texts.values()
+                  if "k.hashagg.hash_group_aggregate" in t)
+    # the planner applies Q6's filter inside the update aggregate: its
+    # scope is the aggregate's, <Class>.<pre-order position>
     assert re.search(r"TpuHashAggregateExec\.\d+/", update)
     assert "e.Multiply" in update or "e.And" in update   # expression scopes
     assert not re.search(r"@[0-9a-f]{6,}|0x[0-9a-f]{6,}", " ".join(
@@ -108,7 +74,7 @@ def test_filter_operator_has_its_own_scope(monkeypatch):
                                  "fb": [float(i) for i in range(300)]})
         return df.filter(F.col("fa") > 10).order_by("fb")
 
-    _s, texts = _lowered_stage_texts(monkeypatch, build)
+    _s, texts = lowered_stage_texts(monkeypatch, build)
     joined = "\n".join(texts.values())
     assert re.search(r"TpuFilterExec\.\d+", joined), texts.keys()
     assert re.search(r"k\.layout\.(compact|gather_rows|compaction_indices)",
@@ -135,7 +101,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path[:0] = [sys.argv[1], os.path.join(sys.argv[1], "tests")]
 import jax
 from test_tracing import _q6_shaped
-from compare import tpu_session
+from compare import lowered_stage_texts, tpu_session
 from spark_rapids_tpu.utils import compile_registry as CR
 junk = [object() for _ in range(int(sys.argv[2]))]   # move the heap
 s = tpu_session(**{"spark.rapids.sql.variableFloatAgg.enabled": True})
