@@ -239,7 +239,8 @@ class DonationHygieneRule(Rule):
             name = dotted_name(node.func) or ""
             donating = [k for k in node.keywords
                         if k.arg in ("donate_argnums", "donate_argnames")]
-            if donating and not name.endswith("instrumented_jit"):
+            if donating and not name.endswith(("instrumented_jit",
+                                               "plan_jit")):
                 yield self.finding(
                     sf, node,
                     f"`{name}(..., {donating[0].arg}=...)` donates outside "

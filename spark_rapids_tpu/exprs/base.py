@@ -18,6 +18,7 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import ColumnBatch, DeviceColumn, HostBatch, HostColumn
+from spark_rapids_tpu.utils import params as _params
 
 
 @dataclasses.dataclass
@@ -332,24 +333,41 @@ def eval_maybe_encoded(expr: "Expression", ctx: TpuEvalCtx) -> DevVal:
 
 
 class Literal(Expression):
-    foldable = True
+    """A constant.  ``slot`` is None for a literal baked into the programs
+    that evaluate it; a *lifted* literal (``plan/logical.plan_shape``)
+    names the position of its value in the executing query's bound
+    parameters (``utils/params``) and ``value`` is only what its shape was
+    first planned with: evaluation and ``repr`` read the slot."""
 
-    def __init__(self, value: Any, dtype: Optional[T.DataType] = None):
+    foldable = True
+    slot: Optional[int] = None
+
+    def __init__(self, value: Any, dtype: Optional[T.DataType] = None,
+                 slot: Optional[int] = None):
         if dtype is None:
             dtype = infer_literal_type(value)
         self.value = value
         self.dtype = dtype
         self.nullable = value is None
         self.children = ()
+        if slot is not None:
+            self.slot = slot
 
     def with_children(self, children):
         return self
 
     def __repr__(self):
+        if self.slot is not None:
+            return f"lit({_params.shown(self.slot, self.value)!r})"
         return f"lit({self.value!r})"
 
     def tpu_eval(self, ctx: TpuEvalCtx) -> DevVal:
         cap = ctx.capacity
+        if self.slot is not None:
+            val = _params.traced(self.slot)
+            return DevVal(self.dtype,
+                          jnp.full(cap, val, dtype=self.dtype.jnp_dtype),
+                          jnp.ones(cap, dtype=jnp.bool_))
         if self.value is None:
             validity = jnp.zeros(cap, dtype=jnp.bool_)
             if self.dtype.is_string:
@@ -373,6 +391,11 @@ class Literal(Expression):
 
     def cpu_eval(self, ctx: CpuEvalCtx) -> CpuVal:
         n = ctx.num_rows
+        if self.slot is not None:
+            return CpuVal(self.dtype,
+                          np.full(n, _params.host(self.slot),
+                                  dtype=self.dtype.np_dtype),
+                          np.ones(n, dtype=np.bool_))
         if self.value is None:
             validity = np.zeros(n, dtype=np.bool_)
             if self.dtype.is_string:

@@ -55,7 +55,8 @@ def write_dataframe(df, fmt: str, path: str, mode: str = "error",
         return {"num_files": 0, "num_rows": 0, "num_bytes": 0,
                 "partitions": 0}
     session = df.session
-    phys = session.plan_physical(df.plan)
+    from spark_rapids_tpu.utils import params
+    phys, bound, _facts = session.plan_bound(df.plan)
     if phys.is_tpu:
         phys = DeviceToHostExec(phys)
     ctx = ExecContext(
@@ -65,21 +66,22 @@ def write_dataframe(df, fmt: str, path: str, mode: str = "error",
     stats = {"num_files": 0, "num_rows": 0, "num_bytes": 0, "partitions": 0}
     part_dirs = set()
     try:
-        for pi, part in enumerate(phys.partitions(ctx)):
-            batches: List[HostBatch] = [hb for hb in part if hb.num_rows]
-            if not batches:
-                continue
-            hb = HostBatch.concat(batches)
-            if partition_by:
-                _write_partitioned(hb, fmt, path, pi, partition_by, stats,
-                                   part_dirs)
-                continue
-            table = host_batch_to_arrow(hb)
-            fname = os.path.join(path, f"part-{pi:05d}.{_ext(fmt)}")
-            _write_table(table, fname=fname, fmt=fmt)
-            stats["num_files"] += 1
-            stats["num_rows"] += hb.num_rows
-            stats["num_bytes"] += os.path.getsize(fname)
+        with params.executing(bound):
+            for pi, part in enumerate(phys.partitions(ctx)):
+                batches: List[HostBatch] = [hb for hb in part if hb.num_rows]
+                if not batches:
+                    continue
+                hb = HostBatch.concat(batches)
+                if partition_by:
+                    _write_partitioned(hb, fmt, path, pi, partition_by, stats,
+                                       part_dirs)
+                    continue
+                table = host_batch_to_arrow(hb)
+                fname = os.path.join(path, f"part-{pi:05d}.{_ext(fmt)}")
+                _write_table(table, fname=fname, fmt=fmt)
+                stats["num_files"] += 1
+                stats["num_rows"] += hb.num_rows
+                stats["num_bytes"] += os.path.getsize(fname)
     finally:
         ctx.close_deferred()
     stats["partitions"] = len(part_dirs)

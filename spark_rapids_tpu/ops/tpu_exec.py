@@ -32,7 +32,7 @@ from spark_rapids_tpu.kernels.layout import (
 )
 from spark_rapids_tpu.kernels.sort import sort_batch
 from spark_rapids_tpu.plan.physical import ExecContext, PhysicalOp, TpuExec
-from spark_rapids_tpu.utils.compile_registry import instrumented_jit
+from spark_rapids_tpu.utils.compile_registry import plan_jit
 from spark_rapids_tpu.utils.tracing import device_read
 
 
@@ -177,7 +177,7 @@ class TpuProjectExec(TpuExec):
             return ColumnBatch(schema, cols, batch.num_rows, batch.capacity)
 
         self.batch_fn = run
-        self._run = instrumented_jit(run, label="TpuProject")
+        self._run = plan_jit(run, label="TpuProject")
 
     def describe(self):
         return f"TpuProject({', '.join(f.name for f in self.output_schema)})"
@@ -203,7 +203,7 @@ class TpuFilterExec(TpuExec):
             return compact(batch, keep)
 
         self.batch_fn = run
-        self._run = instrumented_jit(run, label="TpuFilter")
+        self._run = plan_jit(run, label="TpuFilter")
 
     def describe(self):
         return f"TpuFilter({self.condition!r})"
@@ -377,7 +377,7 @@ class TpuFusedMapExec(TpuExec):
             return batch
 
         self.batch_fn = composed
-        self._run = instrumented_jit(composed, label="TpuFusedMap")
+        self._run = plan_jit(composed, label="TpuFusedMap")
 
     def describe(self):
         return f"TpuFusedMap({' -> '.join(self.labels)})"
@@ -452,7 +452,7 @@ class TpuSortExec(TpuExec):
                               [o.nulls_first for o in self.orders],
                               string_prefix_bytes=self.string_prefix_bytes)
 
-        self._run = instrumented_jit(run, label="TpuSort")
+        self._run = plan_jit(run, label="TpuSort")
 
     def absorb_input(self, fns):
         # project/filter commute with concat (row-wise / stable), so fused
@@ -531,11 +531,11 @@ class TpuHashAggregateExec(TpuExec):
         from spark_rapids_tpu.kernels.hashagg import TABLE_SLOTS
         self._mxu_table = TABLE_SLOTS  # refreshed from conf in _hash_active
 
-        @instrumented_jit(label="TpuHashAggregate")
+        @plan_jit(label="TpuHashAggregate")
         def run(batch: ColumnBatch) -> ColumnBatch:
             return self._aggregate_batch(batch)
 
-        @instrumented_jit(label="TpuHashAggregate:hash")
+        @plan_jit(label="TpuHashAggregate:hash")
         def run_hash(batch: ColumnBatch):
             return self._aggregate_batch_hash(batch)
 
@@ -544,9 +544,9 @@ class TpuHashAggregateExec(TpuExec):
         # the merge input is always a fresh >1-way concat this exec built
         # (never a cached/spill-held batch) and is consumed here: donate
         # its buffers so concat + merge don't hold two full copies
-        self._merge_run = instrumented_jit(self._merge_partials,
+        self._merge_run = plan_jit(self._merge_partials,
                                            label="TpuHashAggregate:merge")
-        self._merge_run_donate = instrumented_jit(
+        self._merge_run_donate = plan_jit(
             self._merge_partials, label="TpuHashAggregate:merge",
             donate_argnums=(0,))
         self._input_fns = []
@@ -568,8 +568,8 @@ class TpuHashAggregateExec(TpuExec):
                 batch = f(batch)
             return self._aggregate_batch_hash(batch)
 
-        self._run = instrumented_jit(run, label="TpuHashAggregate")
-        self._run_hash = instrumented_jit(run_hash,
+        self._run = plan_jit(run, label="TpuHashAggregate")
+        self._run_hash = plan_jit(run_hash,
                                           label="TpuHashAggregate:hash")
 
     def _hash_active(self, ctx) -> bool:
@@ -1345,7 +1345,7 @@ class TpuExpandExec(TpuExec):
         self._runs = []
         for proj in projections:
             def make(proj=proj):
-                @instrumented_jit(label="TpuExpand")
+                @plan_jit(label="TpuExpand")
                 def run(batch):
                     ctx = TpuEvalCtx(batch)
                     cols = [e.tpu_eval(ctx).to_column() for e in proj]

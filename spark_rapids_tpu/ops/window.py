@@ -298,10 +298,10 @@ class TpuWindowExec(TpuExec):
                 "one Window exec handles one (partition, order) spec"
 
         from spark_rapids_tpu.utils.compile_registry import (
-            instrumented_jit,
+            plan_jit,
         )
 
-        @instrumented_jit(label="TpuWindow")
+        @plan_jit(label="TpuWindow")
         def run(batch: ColumnBatch) -> ColumnBatch:
             return self._compute(batch)
 

@@ -27,7 +27,7 @@ from spark_rapids_tpu.plan.physical import (
     CpuExec, ExecContext, PhysicalOp, TpuExec,
 )
 from spark_rapids_tpu.obs import events as obs_events
-from spark_rapids_tpu.utils.compile_registry import instrumented_jit
+from spark_rapids_tpu.utils.compile_registry import plan_jit
 from spark_rapids_tpu.utils.tracing import device_read, device_wait, span
 
 def _range_sample_limit(ctx) -> int:
@@ -126,7 +126,7 @@ class TpuShuffleExchangeExec(TpuExec):
         self.partitioning = partitioning
         self._input_fns = []
         self._fused_map = None
-        self._sort_by_pid = instrumented_jit(
+        self._sort_by_pid = plan_jit(
             self._sort_by_pid_impl, label="TpuShuffleExchange:split",
             static_argnames=("n", "keep_encoded"))
 
@@ -167,7 +167,7 @@ class TpuShuffleExchangeExec(TpuExec):
                     b = f(b)
                 return b
 
-            self._fused_map = instrumented_jit(
+            self._fused_map = plan_jit(
                 composed, label="TpuShuffleExchange:map")
 
     def has_materialized_split(self, ctx) -> bool:
@@ -366,7 +366,7 @@ class TpuShuffleExchangeExec(TpuExec):
                         b = f(b)
                     return b
 
-                self._fused_map = instrumented_jit(
+                self._fused_map = plan_jit(
                     composed, label="TpuShuffleExchange:map")
             batches = [self._fused_map(b) for b in batches]
         if not batches:

@@ -9,6 +9,7 @@ exec inventory of SURVEY.md section 2.5.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from spark_rapids_tpu import types as T
@@ -451,20 +452,31 @@ class AggregateInPandas(LogicalPlan):
         return T.Schema(fields)
 
 
-def plan_fingerprint(plan: LogicalPlan) -> str:
+def plan_fingerprint(plan: LogicalPlan, pinned: Optional[List] = None,
+                     baked: Optional[List] = None) -> str:
     """Canonical identity of a logical plan for physical-plan reuse.
 
     Built from node types + their scalar/expression attributes; objects
     without stable reprs (user fns, batch lists) key by python identity —
     collisions are impossible (identity reprs are unique), only *misses*
-    for structurally equal but distinct-object inputs, which is safe.
+    for structurally equal but distinct-object inputs, which is safe
+    while the object lives: a holder of the fingerprint keeps alive what
+    ``pinned`` collects (every object keyed by identity).
+
+    A literal is encoded by its value, a *lifted* one (:func:`plan_shape`)
+    by slot and type alone; ``baked`` collects the former.
     """
-    from spark_rapids_tpu.exprs.base import Expression, SortOrder
+    from spark_rapids_tpu.exprs.base import Expression, Literal, SortOrder
 
     def enc(v):
         if isinstance(v, AggregateExpression):
             return f"AE({v.output_name},{enc(v.fn)})"
         if isinstance(v, Expression):
+            if isinstance(v, Literal):
+                if v.slot is not None:
+                    return f"Literal?{v.slot}:{v.dtype}"
+                if baked is not None:
+                    baked.append(v)
             # NOT repr(): Expression.__repr__ prints only class + children,
             # omitting scalar attributes (ConcatWs.sep, Lag.offset,
             # window frames...) — encode every non-child attribute too so
@@ -490,6 +502,8 @@ def plan_fingerprint(plan: LogicalPlan) -> str:
             return "{" + ",".join(
                 f"{enc(k)}:{enc(x)}" for k, x in sorted(
                     v.items(), key=lambda kv: str(kv[0]))) + "}"
+        if pinned is not None:
+            pinned.append(v)
         return f"id:{id(v):x}"  # fns, batch lists, cache holders...
 
     attrs = []
@@ -497,8 +511,146 @@ def plan_fingerprint(plan: LogicalPlan) -> str:
         if k in ("children", "_schema"):
             continue
         attrs.append(f"{k}={enc(v)}")
-    kids = ",".join(plan_fingerprint(c) for c in plan.children)
+    kids = ",".join(plan_fingerprint(c, pinned, baked) for c in plan.children)
     return f"{plan.name}({';'.join(attrs)})[{kids}]"
+
+
+class PlanShape:
+    """What :func:`plan_shape` splits a logical plan into: the ``plan``
+    with every liftable literal replaced by a slotted one, its
+    ``fingerprint`` (the key one physical plan and one set of executables
+    are shared under), the lifted ``values`` and their ``dtypes`` slot by
+    slot, how many literals stayed ``baked``, and the objects the
+    fingerprint names by identity (``pinned``)."""
+
+    __slots__ = ("plan", "fingerprint", "values", "dtypes", "baked",
+                 "pinned")
+
+    def __init__(self, plan, fingerprint, values, dtypes, baked, pinned):
+        self.plan = plan
+        self.fingerprint = fingerprint
+        self.values = values
+        self.dtypes = dtypes
+        self.baked = baked
+        self.pinned = pinned
+
+
+@functools.lru_cache(maxsize=None)
+def _lift_through() -> frozenset:
+    """Expression classes (exact types) a literal may sit under, at any
+    depth, and still be lifted: each evaluates its children through
+    ``tpu_eval``/``cpu_eval`` alone — no read of a child's ``value`` when it
+    is built, tagged, planned or traced — and rebuilds itself whole from
+    ``with_children``.  Everything else (``round`` scales, ``substring``
+    positions, LIKE patterns, array needles, CASE WHEN, window frames,
+    cast targets as attributes) keeps the literals below it baked."""
+    from spark_rapids_tpu.exprs import aggregates as A
+    from spark_rapids_tpu.exprs import arithmetic as AR
+    from spark_rapids_tpu.exprs import nullexprs as N
+    from spark_rapids_tpu.exprs import predicates as P
+    from spark_rapids_tpu.exprs.base import Alias
+    from spark_rapids_tpu.exprs.cast import Cast
+    from spark_rapids_tpu.exprs.conditional import If
+    return frozenset({
+        P.Equals, P.NotEquals, P.LessThan, P.LessThanOrEqual,
+        P.GreaterThan, P.GreaterThanOrEqual, P.EqualNullSafe, P.And, P.Or,
+        P.Not, P.In,
+        AR.Add, AR.Subtract, AR.Multiply, AR.Divide, AR.IntegralDivide,
+        AR.Remainder, AR.Pmod, AR.UnaryMinus, AR.Abs, AR.UnaryPositive,
+        N.IsNull, N.IsNotNull, N.IsNan, N.Coalesce, N.NaNvl,
+        If, Cast, Alias,
+        A.Sum, A.Count, A.Min, A.Max, A.Average,
+    })
+
+
+#: fixed-width types a lifted literal may have (a string's byte length is
+#: part of a program's shape; NULL has no value to bind)
+_LIFTABLE_TYPES = (T.BOOLEAN, T.BYTE, T.SHORT, T.INT, T.LONG, T.FLOAT,
+                   T.DOUBLE, T.DATE, T.TIMESTAMP)
+
+
+def plan_shape(plan: LogicalPlan, lift: bool = True) -> PlanShape:
+    """Split ``plan`` into a shape and the values of its liftable literals
+    (the plan cache's key and what an execution binds).
+
+    A literal is lifted when it is non-NULL, of a fixed-width type, held in
+    a ``Filter`` condition, a ``Project`` expression or an ``Aggregate``
+    argument, below nothing but :func:`_lift_through` classes, and neither
+    the whole expression nor the direct argument of an aggregate function
+    (``count(1)``).  Everywhere else it reaches, or may reach, planning or
+    tracing by its value — LIMIT and sample counts, join and sort
+    expressions, grouping keys (they become exchange partitionings),
+    window specs, filters pushed into a file scan — and stays baked: part
+    of the fingerprint, compiled as a constant.  The type is part of the
+    shape: ``24`` and ``24.5`` are two shapes.  One ``Literal`` object in
+    two liftable places takes one slot.  ``lift=False`` lifts nothing: the
+    shape is the plan's value fingerprint.
+
+    Non-mutating: ``plan`` is untouched and unchanged subtrees are shared.
+    """
+    import copy
+
+    from spark_rapids_tpu.exprs.aggregates import AggregateFunction
+    from spark_rapids_tpu.exprs.base import Literal
+    through = _lift_through()
+    values: List = []
+    dtypes: List = []
+    slotted: Dict[int, Literal] = {}
+
+    def slotted_copy(lit: Literal) -> Literal:
+        if lit.slot is not None or lit.dtype not in _LIFTABLE_TYPES or \
+                type(lit.value) not in (bool, int, float):
+            return lit
+        new = slotted.get(id(lit))
+        if new is None:
+            new = slotted[id(lit)] = Literal(lit.value, lit.dtype,
+                                             slot=len(values))
+            values.append(lit.value)
+            dtypes.append(lit.dtype)
+        return new
+
+    def expr(e):
+        if type(e) not in through:
+            return e
+        keep_literals = isinstance(e, AggregateFunction)
+        kids = [c if type(c) is Literal and keep_literals
+                else slotted_copy(c) if type(c) is Literal else expr(c)
+                for c in e.children]
+        if all(n is o for n, o in zip(kids, e.children)):
+            return e
+        return e.with_children(kids)
+
+    def exprs(es):
+        new = [expr(e) for e in es]
+        return es if all(n is o for n, o in zip(new, es)) else new
+
+    def node(n: LogicalPlan) -> LogicalPlan:
+        children = tuple(node(c) for c in n.children)
+        new = {}
+        if type(n) is Filter:
+            new["condition"] = expr(n.condition)
+        elif type(n) is Project:
+            new["exprs"] = exprs(n.exprs)
+        elif type(n) is Aggregate:
+            fns = exprs([a.fn for a in n.aggs])
+            new["aggs"] = n.aggs if all(
+                f is a.fn for a, f in zip(n.aggs, fns)) else [
+                AggregateExpression(f, a.output_name)
+                for a, f in zip(n.aggs, fns)]
+        if all(v is getattr(n, k) for k, v in new.items()) and all(
+                a is b for a, b in zip(children, n.children)):
+            return n
+        clone = copy.copy(n)
+        vars(clone).update(new)
+        clone.children = children
+        return clone
+
+    shaped = node(plan) if lift else plan
+    pinned: List = []
+    baked: List = []
+    fingerprint = plan_fingerprint(shaped, pinned, baked)
+    return PlanShape(shaped, fingerprint, tuple(values), tuple(dtypes),
+                     len(baked), pinned)
 
 
 class Generate(LogicalPlan):

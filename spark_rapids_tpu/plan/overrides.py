@@ -110,6 +110,29 @@ class PlanMeta:
         return lines
 
 
+class PlanExplain:
+    """The explain text of a planned shape.  The tagging lines are the
+    shape's; what names literal values is rendered for one query: its own
+    folds, and the absorbed filter conditions with that query's bound
+    values in place of the lifted literals."""
+
+    def __init__(self, lines: List[str], absorbed: List[Expression]):
+        self.lines = lines
+        self.absorbed = absorbed
+
+    def render(self, folded: List[str], values: tuple = ()) -> str:
+        from spark_rapids_tpu.utils import params
+        lines = list(self.lines)
+        if folded:
+            lines.append(f"folded {len(folded)}: " + ", ".join(folded))
+        if self.absorbed:
+            with params.showing(values):
+                lines.append("filter applied inside the keyless aggregate "
+                             "above it: " + ", ".join(
+                                 repr(c) for c in self.absorbed))
+        return "\n".join(lines)
+
+
 class TpuOverrides:
     """The plan rewriter: logical plan -> physical plan with per-operator
     TPU/CPU placement, exchanges and transitions."""
@@ -117,6 +140,7 @@ class TpuOverrides:
     def __init__(self, conf: RapidsConf):
         self.conf = conf
         self.last_explain: str = ""
+        self.explain: Optional[PlanExplain] = None
 
     # ------------------------------------------------------------------ tag
 
@@ -215,7 +239,15 @@ class TpuOverrides:
 
     # -------------------------------------------------------------- convert
 
-    def apply(self, plan: L.LogicalPlan) -> PhysicalOp:
+    def rewrite_logical(self, plan: L.LogicalPlan
+                        ) -> Tuple[L.LogicalPlan, List[str]]:
+        """The logical rewrites whose result depends on a query's literal
+        values, in planning order: UDF compilation, scan pushdown (on the
+        plan as written) and constant folding.  ``session.plan_bound`` runs
+        them for every new plan object, BEFORE it splits the plan into a
+        shape and values: ``to_date('1994-01-01')`` becomes a DATE literal
+        first and is lifted then.  Returns the plan and one description a
+        fold.  Non-mutating (but for the UDF compiler's in-place edit)."""
         if self.conf.get("spark.rapids.sql.udfCompiler.enabled", False):
             plan = _compile_plan_udfs(plan)
         if self.conf.get("spark.rapids.sql.scan.pushdown.enabled", True) \
@@ -226,16 +258,20 @@ class TpuOverrides:
         with span("plan", "fold") as sp:
             plan, folded = _fold_constants(plan)
             sp.set(folded=len(folded))
+        return plan, folded
+
+    def apply(self, plan: L.LogicalPlan) -> PhysicalOp:
+        return self.lower(*self.rewrite_logical(plan))
+
+    def lower(self, plan: L.LogicalPlan, folded: List[str]) -> PhysicalOp:
+        """Tag and lower a plan :meth:`rewrite_logical` has been over
+        (lifted literals, where the caller shares the result among the
+        queries of a shape, already slotted)."""
         plan, absorbed = _filters_into_keyless_aggregates(plan)
         meta = PlanMeta(plan, self.conf)
         self.tag(meta)
-        lines = meta.explain_lines()
-        if folded:
-            lines.append(f"folded {len(folded)}: " + ", ".join(folded))
-        if absorbed:
-            lines.append("filter applied inside the keyless aggregate "
-                         "above it: " + ", ".join(absorbed))
-        self.last_explain = "\n".join(lines)
+        self.explain = PlanExplain(meta.explain_lines(), absorbed)
+        self.last_explain = self.explain.render(folded)
         if self.conf.explain_enabled:
             # routed through the obs sink (a logger by default) instead of
             # print(): library embedders and pytest capture aren't spammed,
@@ -251,8 +287,8 @@ class TpuOverrides:
         # hands out a tree whose op ids are its pre-order positions
         from spark_rapids_tpu.plan.physical import assign_op_ids
         phys = assign_op_ids(phys)
-        # kept with the plan: every query that runs it (hit or miss of
-        # the plan cache) publishes it as last_metrics["foldedExprs"]
+        # what the plan was FIRST built with; a query publishes its own
+        # count as last_metrics["foldedExprs"]
         phys.folded_exprs = len(folded)
         return phys
 
@@ -779,7 +815,8 @@ def _fold_constants(plan: L.LogicalPlan
 
 
 def _filters_into_keyless_aggregates(plan: L.LogicalPlan
-                                     ) -> Tuple[L.LogicalPlan, List[str]]:
+                                     ) -> Tuple[L.LogicalPlan,
+                                                List[Expression]]:
     """``SELECT sum(x) … WHERE p`` plans as ``sum(if(p, x, NULL))`` over
     the filter's input: a Filter directly under a keyless Aggregate whose
     functions all skip NULL arguments becomes a condition on each
@@ -799,7 +836,7 @@ def _filters_into_keyless_aggregates(plan: L.LogicalPlan
     :func:`_pushdown_scan_filters`; it runs after the pushdown, so a file
     scan keeps the conjuncts it skips row groups by."""
     import copy
-    absorbed: List[str] = []
+    absorbed: List[Expression] = []
 
     def context_free(e: Expression) -> bool:
         return not e.collect(lambda x: not x.context_free)
@@ -820,7 +857,7 @@ def _filters_into_keyless_aggregates(plan: L.LogicalPlan
                 absorbable(node, node.children[0].condition):
             below = node.children[0]
             cond = below.condition
-            absorbed.append(repr(cond))
+            absorbed.append(cond)
             node = L.Aggregate([], [], [
                 AggregateExpression(a.fn.with_children([If(
                     cond, a.fn.child, Literal(None, a.fn.child.dtype))]),

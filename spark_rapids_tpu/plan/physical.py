@@ -73,6 +73,11 @@ class ExecContext:
         # analysis/plan_verify.check_adaptive_events: every event must
         # point at a live plan op and respect join-type legality
         self.adaptive_events: List = []
+        # this execution's literal values for the shared plan's slots
+        # (utils.params.BoundParams; the session executes under them) and
+        # the plan facts it publishes as metrics (session.plan_bound)
+        self.bound_params = None
+        self.plan_facts: Dict[str, int] = {}
 
     def note_adaptive(self, op_id: str, mechanism: str) -> None:
         self.adaptive_events.append((op_id, mechanism))
@@ -327,10 +332,12 @@ class HostToDeviceExec(TpuExec):
             # transfers/events attribute to THIS query even when several
             # queries are in flight (serve runtime)
             scope = obs_events.current_scope()
+            from spark_rapids_tpu.utils import params
+            bound = params.current()
 
             def worker():
                 try:
-                    with obs_events.adopt(scope):
+                    with obs_events.adopt(scope), params.executing(bound):
                         for hb in part:
                             if chan.stopped:
                                 return

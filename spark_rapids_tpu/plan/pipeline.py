@@ -53,7 +53,9 @@ from spark_rapids_tpu.batch import (
     round_up_capacity,
 )
 from spark_rapids_tpu.plan.physical import ExecContext, PhysicalOp, TpuExec
-from spark_rapids_tpu.utils.compile_registry import instrumented_jit
+from spark_rapids_tpu.utils.compile_registry import (
+    instrumented_jit, plan_jit,
+)
 from spark_rapids_tpu.utils.tracing import device_dispatch, span
 
 
@@ -479,6 +481,11 @@ def _stage_program(root: PhysicalOp, ctx: ExecContext, variant: str,
     ``donate_argnums`` hands the donated buffers' HBM to XLA for reuse —
     a consumed input batch then never holds a second full copy across the
     dispatch.
+
+    The program is a ``plan_jit``: besides the batches it takes the
+    executing query's bound literal values (``utils/params``), so the
+    executables that hang on this root op serve every query of the plan's
+    shape, whatever its literals.
     """
     cache = getattr(root, "_stage_cache", None)
     if not isinstance(cache, dict):
@@ -512,8 +519,7 @@ def _stage_program(root: PhysicalOp, ctx: ExecContext, variant: str,
                 return tuple(fn(shrunk))
         jit_kw = {"donate_argnums": (0,)} if any(dmask) else {}
         cache[key] = (sources,
-                      instrumented_jit(run, label=f"stage:{root.name}",
-                                       **jit_kw))
+                      plan_jit(run, label=f"stage:{root.name}", **jit_kw))
     return cache[key]
 
 

@@ -185,12 +185,17 @@ def run_python_task(ctx, task, inputs, in_schemas, out_schema):
         os.close(out_w)
 
         feed_error = []
+        # the feeder drives the upstream plan: under the query's bound
+        # literal parameters, like the thread that spawned it
+        from spark_rapids_tpu.utils import params
+        bound = params.current()
 
         def feed():
             try:
-                for sidx, hb in inputs:
-                    _write_frame(in_w, _MSG_BATCH, sidx,
-                                 serialize_host_batch(hb))
+                with params.executing(bound):
+                    for sidx, hb in inputs:
+                        _write_frame(in_w, _MSG_BATCH, sidx,
+                                     serialize_host_batch(hb))
                 _write_frame(in_w, _MSG_END, 0, b"")
             except BrokenPipeError:
                 pass  # worker died; the read loop reports it

@@ -38,7 +38,6 @@ from __future__ import annotations
 import socketserver
 import threading
 import time
-from collections import OrderedDict
 from typing import Any, Dict, Optional, Tuple
 
 from spark_rapids_tpu.serve import protocol
@@ -133,16 +132,6 @@ class FrontDoorServer:
         self._admission_min_runs = SERVE_ADMISSION_MIN_RUNS.get(self.conf)
         self._admission_mad_k = SERVE_ADMISSION_MAD_K.get(self.conf)
         self._templates: Dict[str, Any] = {}
-        # prepared-statement cache: repeated SQL text reuses ONE logical
-        # plan object.  The shared plan cache (serve/excache) ties entry
-        # lifetime to the logical plan's liveness, so a per-request
-        # parse would let the compiled executables die with each
-        # response; pinning the plan here is what makes the second
-        # client's compileCount == 0.  Bounded by the same conf as the
-        # plan cache it feeds (serve.planCache.maxPlans).
-        from spark_rapids_tpu.config import SERVE_PLAN_CACHE_MAX
-        self._stmt_max = max(1, SERVE_PLAN_CACHE_MAX.get(self.conf))
-        self._stmt_cache: "OrderedDict[str, Any]" = OrderedDict()
         self._lock = threading.Lock()
         self._connections = 0
         self._requests = 0
@@ -250,32 +239,6 @@ class FrontDoorServer:
         held = rt.semaphore.held_depth() if rt is not None else 0
         return {"ok": True, "drained": drained, "held_depth": held}
 
-    def _plan_for_sql(self, sql: str):
-        """One logical plan per (whitespace-normalized) SQL text, LRU.
-
-        Parsing is cheap; what the reuse actually buys is plan-object
-        IDENTITY — the stable anchor for the shared plan cache's weak
-        entries and the result cache's id()-keyed input identity.  Note
-        a view re-registered after a statement was cached keeps serving
-        the old binding for that text until the entry ages out; the
-        front door owns its session, so bindings are fixed for the
-        server's lifetime."""
-        key = " ".join(sql.split())
-        with self._lock:
-            plan = self._stmt_cache.get(key)
-            if plan is not None:
-                self._stmt_cache.move_to_end(key)
-                return plan
-        plan = self.session.sql(sql).plan  # parse outside the lock
-        with self._lock:
-            existing = self._stmt_cache.get(key)
-            if existing is not None:
-                return existing  # racer won; share its plan object
-            self._stmt_cache[key] = plan
-            while len(self._stmt_cache) > self._stmt_max:
-                self._stmt_cache.popitem(last=False)
-        return plan
-
     def _handle_submit(self, req: Dict[str, Any]) -> Dict[str, Any]:
         tenant = str(req.get("tenant", "default"))
         deadline_sec = float(req.get("deadline_sec", 0.0))
@@ -286,7 +249,10 @@ class FrontDoorServer:
         sql = req.get("sql")
         if not isinstance(sql, str) or not sql.strip():
             raise protocol.ProtocolError("submit needs 'sql' or 'template'")
-        plan = self._plan_for_sql(sql)
+        # a parse a request: the shared plan cache (serve/excache) keys
+        # on the plan's SHAPE and lives by LRU, so the text's executables
+        # are found again with nothing pinned here
+        plan = self.session.sql(sql).plan
         key = cache_key(self.session, plan)
         use_cache = self._cache_enabled and bool(req.get("cache", True)) \
             and key[2] is not None

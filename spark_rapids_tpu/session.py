@@ -12,11 +12,38 @@ from __future__ import annotations
 import os
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import HostBatch
 from spark_rapids_tpu.config import RapidsConf, conf as global_conf
+
+
+class _PlanNotes:
+    """What the session keeps per logical-plan OBJECT (weakly): the wall
+    of the ``sql()`` call that parsed it, and its shape — the rewritten,
+    folded and slotted plan with its fingerprint and values — so a held
+    DataFrame's next ``collect()`` neither folds nor fingerprints again
+    (a logical plan is immutable once a DataFrame holds it)."""
+
+    __slots__ = ("parse_ns", "shaped", "__weakref__")
+
+    def __init__(self):
+        self.parse_ns = 0
+        self.shaped = None   # (rewrite flags, PlanShape, fold descriptions)
+
+
+_PLAN_NOTES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_PLAN_NOTES_LOCK = threading.Lock()
+
+
+def _plan_notes(plan) -> _PlanNotes:
+    with _PLAN_NOTES_LOCK:
+        notes = _PLAN_NOTES.get(plan)
+        if notes is None:
+            notes = _PLAN_NOTES[plan] = _PlanNotes()
+        return notes
 
 
 class _MetricsFrame:
@@ -142,24 +169,60 @@ class TpuSparkSession:
     def sql(self, query: str):
         from spark_rapids_tpu.sql.parser import parse_sql
         from spark_rapids_tpu.utils.tracing import span
-        with span("plan", "parse"):
-            return parse_sql(query, self)
+        with span("plan", "parse") as sp:
+            df = parse_sql(query, self)
+        # outside queryWallNs: carried to the plan's queries as parseNs
+        _plan_notes(df.plan).parse_ns = sp.elapsed_ns
+        return df
 
     # -- execution ----------------------------------------------------------
 
     def plan_physical(self, plan):
-        """Lower a logical plan, memoized per (canonical plan fingerprint,
+        """The physical plan of :meth:`plan_bound`, for a caller that only
+        inspects it.  Executing it takes the bound parameters too."""
+        return self.plan_bound(plan)[0]
+
+    def plan_bound(self, plan):
+        """Lower a logical plan, memoized per (plan SHAPE fingerprint,
         conf state) — the canonicalized-plan-reuse role
         (GpuOverrides + Spark plan canonicalization): two structurally
         identical DataFrames (e.g. ``df.count()`` called twice, each
         building a fresh Aggregate node) share one physical plan and
-        therefore every compiled XLA kernel.  The memo is PROCESS-wide
-        (serve.excache): every session serving the same (fingerprint,
-        conf-state) shape shares one physical plan, so only the first
-        execution anywhere in the process compiles."""
-        from spark_rapids_tpu.plan.logical import plan_fingerprint
-        from spark_rapids_tpu.serve.excache import shared_plan_cache
-        key = plan_fingerprint(plan)
+        therefore every compiled XLA kernel — and so do two queries that
+        differ only in the values of their liftable literals
+        (``plan/logical.plan_shape``): the same SQL text with other
+        parameters.  The memo is PROCESS-wide (serve.excache) and lives
+        by LRU: every session serving the same shape shares one physical
+        plan, so only the first execution anywhere in the process
+        compiles.
+
+        Returns ``(phys, bound, facts)``: the shared plan, THIS plan's
+        literal values as :class:`~spark_rapids_tpu.utils.params.
+        BoundParams` — whoever drives ``phys`` does so under
+        ``params.executing(bound)`` — and the plan facts a query
+        publishes (``planShapeHit``, ``boundParams``, ``bakedLiterals``,
+        ``foldedExprs``, ``parseNs``, ``planShapeNs``, ``planBindNs``)."""
+        from spark_rapids_tpu.plan.logical import plan_shape
+        from spark_rapids_tpu.plan.overrides import TpuOverrides
+        from spark_rapids_tpu.serve.excache import (
+            PlanEntry, shared_plan_cache,
+        )
+        from spark_rapids_tpu.utils.tracing import span
+        notes = _plan_notes(plan)
+        overrides = TpuOverrides(self.conf)
+        # the logical rewrites read two conf keys; ICI-mesh programs take
+        # sharded globals and no bound parameters, so under a mesh every
+        # literal stays baked (the plan is keyed on its values, as ever)
+        lift = self._shuffle_mesh() is None
+        flags = (self.conf.get("spark.rapids.sql.udfCompiler.enabled", False),
+                 self.conf.get("spark.rapids.sql.scan.pushdown.enabled", True),
+                 lift)
+        with span("plan", "shape") as shape_span:
+            if notes.shaped is None or notes.shaped[0] != flags:
+                rewritten, folded = overrides.rewrite_logical(plan)
+                notes.shaped = (flags, plan_shape(rewritten, lift=lift),
+                                folded)
+            _flags, shape, folded = notes.shaped
         # metrics-detail and obs knobs never change the plan: excluding
         # them keeps the memo (and therefore every compiled kernel)
         # hittable when a measurement run toggles accurate device-time
@@ -170,15 +233,32 @@ class TpuSparkSession:
                     or k.startswith("spark.rapids.sql.tpu.obs."))))
 
         def _build():
-            from spark_rapids_tpu.plan.overrides import TpuOverrides
-            overrides = TpuOverrides(self.conf)
-            phys = overrides.apply(plan)
-            return plan, phys, overrides.last_explain
+            phys = overrides.lower(shape.plan, folded)
+            return PlanEntry(phys, overrides.explain, shape.dtypes,
+                             shape.pinned)
 
-        phys, explain, _hit = shared_plan_cache().get_or_build(
-            key, conf_state, _build)
-        self.last_explain = explain
-        return phys
+        entry, hit = shared_plan_cache().get_or_build(
+            shape.fingerprint, conf_state, _build)
+        with span("plan", "bind") as bind_span:
+            bound = entry.bind(shape.values)
+        self._explained = (entry.explain, folded, shape.values)
+        return entry.phys, bound, {
+            "planShapeHit": int(hit), "boundParams": len(shape.values),
+            "bakedLiterals": shape.baked, "foldedExprs": len(folded),
+            "parseNs": notes.parse_ns,
+            "planShapeNs": shape_span.elapsed_ns,
+            "planBindNs": bind_span.elapsed_ns}
+
+    @property
+    def last_explain(self) -> str:
+        """The explain output of the last plan this session lowered or
+        found in the plan cache, with THAT query's literal values and
+        folds (the tagging lines are its shape's)."""
+        explained = getattr(self, "_explained", None)
+        if explained is None:
+            return ""
+        explain, folded, values = explained
+        return explain.render(folded, values)
 
     def _plan_and_context(self, plan):
         """Everything between entry and the first dispatch: conf-shaped
@@ -202,7 +282,7 @@ class TpuSparkSession:
         # the Pallas kernel tier consults this session's conf for its
         # per-kernel gates at trace time (kernels.pallas_tier)
         pallas_tier.configure(self.conf)
-        phys = self.plan_physical(plan)
+        phys, bound, facts = self.plan_bound(plan)
         if self.conf.test_enforce_tpu:
             _assert_on_tpu(phys)
         if self.runtime is not None:
@@ -220,6 +300,10 @@ class TpuSparkSession:
         # with sql.enabled=false to replay a failed partition on the CPU
         # operator path (fault.recovery)
         ctx.logical_plan = plan
+        # this execution's literal values (the shared plan holds slots)
+        # and the plan facts _query_metrics publishes
+        ctx.bound_params = bound
+        ctx.plan_facts = facts
         self.last_physical_plan = phys
         self.last_exec_ctx = ctx
         # query-intelligence hooks (history/): seed the plan from the
@@ -266,6 +350,7 @@ class TpuSparkSession:
         from spark_rapids_tpu.kernels import pallas_tier
         from spark_rapids_tpu.plan.physical import collect_host
         from spark_rapids_tpu.utils import compile_registry as CR
+        from spark_rapids_tpu.utils import params
         from spark_rapids_tpu.utils.tracing import span
         # the query wall is partitioned from HERE to the stamp taken just
         # before critpath.compute: the scope (event ring, this query's
@@ -298,7 +383,8 @@ class TpuSparkSession:
         cat_before = dict(self.runtime.catalog.metrics) \
             if self.runtime is not None else {}
         try:
-            out = collect_host(phys, ctx)
+            with params.executing(ctx.bound_params):
+                out = collect_host(phys, ctx)
         except BaseException:
             # close the scope so a failed query can't leak its bus into
             # the next query's window
@@ -377,9 +463,19 @@ class TpuSparkSession:
         # cached executables trace nothing and count nothing)
         frame.last_metrics["pallasFallbackCount"] = \
             pallas_tier.fallback_count() - pt_before
-        # constant subtrees the planner folded to literals when it built
-        # this plan (kept on its root, so a plan-cache hit reads it too)
-        frame.last_metrics["foldedExprs"] = phys.folded_exprs
+        # planning facts of THIS query (session.plan_bound): whether its
+        # shape was in the plan cache, how many literal values it bound
+        # and how many stayed baked, the constant subtrees folded for it,
+        # the wall of the sql() call that parsed it (outside queryWallNs)
+        # and of the shape and bind spans (inside critpath's ``plan``)
+        facts = ctx.plan_facts
+        frame.last_metrics["planShapeHit"] = facts["planShapeHit"]
+        frame.last_metrics["boundParams"] = facts["boundParams"]
+        frame.last_metrics["bakedLiterals"] = facts["bakedLiterals"]
+        frame.last_metrics["foldedExprs"] = facts["foldedExprs"]
+        frame.last_metrics["parseNs"] = facts["parseNs"]
+        frame.last_metrics["planShapeNs"] = facts["planShapeNs"]
+        frame.last_metrics["planBindNs"] = facts["planBindNs"]
         frame.last_metrics["compileCount"] = d["compiles"]
         frame.last_metrics["compileWallNs"] = d["compile_wall_ns"]
         frame.last_metrics["dispatchCount"] = d["dispatches"]
@@ -649,14 +745,17 @@ class TpuSparkSession:
         """The last query's explain output; with ``metrics=True`` the
         physical tree follows, annotated per operator with the last
         profile's rollups (the SQL-UI exec-metrics analogue)."""
-        base = getattr(self, "last_explain", "") or ""
+        base = self.last_explain
         if not metrics:
             return base
         phys = getattr(self, "last_physical_plan", None)
         if phys is None or not self._query_history:
             return base
         from spark_rapids_tpu.obs.profile import annotate_plan
-        return base + "\n\n" + annotate_plan(phys, self._query_history[-1])
+        from spark_rapids_tpu.utils import params
+        with params.showing(self._explained[2]):
+            return base + "\n\n" + annotate_plan(
+                phys, self._query_history[-1])
 
     def prewarm(self, *dataframes) -> Dict[str, int]:
         """Compile the hot bucket set once, ahead of the timed path.

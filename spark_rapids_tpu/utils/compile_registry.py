@@ -59,6 +59,7 @@ holds the first execution.
 from __future__ import annotations
 
 import functools
+import inspect
 import logging
 import os
 import sys
@@ -69,6 +70,7 @@ import jax
 
 from spark_rapids_tpu.fault import inject as _fault_inject
 from spark_rapids_tpu.obs import events as _obs_events
+from spark_rapids_tpu.utils import params as _params
 from spark_rapids_tpu.utils import tracing as _tracing
 
 _LOCK = threading.Lock()
@@ -338,7 +340,7 @@ def _filter_donation_warning() -> None:
 
 
 def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
-                     **jit_kwargs) -> Callable:
+                     bound: bool = False, **jit_kwargs) -> Callable:
     """``jax.jit`` with dispatch/compile accounting.
 
     Usable as ``instrumented_jit(f, label=...)`` or as a decorator
@@ -346,9 +348,15 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
     call-compatible with the jitted function; the raw jitted callable is
     exposed as ``wrapper.jitted``.  ``donate_argnums`` passes through to
     ``jax.jit``; donated argument bytes are accumulated per dispatch.
+
+    ``bound=True`` (:func:`plan_jit`) is for a program that traces a plan's
+    expressions: the executing query's bound literal parameters
+    (``utils/params``) ride as a hidden first argument, so a lifted
+    ``Literal`` is an input of the executable and not a constant in it.
     """
     if fn is None:
-        return functools.partial(instrumented_jit, label=label, **jit_kwargs)
+        return functools.partial(instrumented_jit, label=label, bound=bound,
+                                 **jit_kwargs)
     name = label or getattr(fn, "__name__", "jit")
     program = program_name(name)
     donate = tuple(jit_kwargs.get("donate_argnums") or ())
@@ -362,11 +370,33 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
     # the jitted function carries the program name, so the device
     # timeline's ``XLA Modules`` line reads ``jit_<program>(<hash>)`` and
     # jax.monitoring's ``fun_name`` is the key of per_label_compiles()
-    @functools.wraps(fn)
-    def named(*args, **kwargs):
-        return fn(*args, **kwargs)
+    if bound:
+        @functools.wraps(fn)
+        def named(bound_params, *args, **kwargs):
+            with _params.tracing(bound_params):
+                return fn(*args, **kwargs)
+        # the hidden argument shifts the caller's positions by one; jax
+        # resolves static_argnames and checks donate_argnums against the
+        # signature, so it must see the shifted one
+        sig = inspect.signature(fn)
+        named.__signature__ = sig.replace(parameters=[inspect.Parameter(
+            "bound_params", inspect.Parameter.POSITIONAL_OR_KEYWORD)]
+            + list(sig.parameters.values()))
+        jit_kwargs = dict(jit_kwargs)
+        for key in ("donate_argnums", "static_argnums"):
+            if jit_kwargs.get(key):
+                jit_kwargs[key] = tuple(i + 1 for i in jit_kwargs[key])
+    else:
+        @functools.wraps(fn)
+        def named(*args, **kwargs):
+            return fn(*args, **kwargs)
     named.__name__ = named.__qualname__ = program
     jitted = jax.jit(named, **jit_kwargs)
+    if bound:
+        def call(*args, **kwargs):
+            return jitted(_params.dispatch_args(), *args, **kwargs)
+    else:
+        call = jitted
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
@@ -375,7 +405,7 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
             # inlines into the outer jaxpr, so it is neither a device
             # dispatch nor a separate compile — don't count it (donation
             # of a traced value is likewise meaningless and ignored)
-            return jitted(*args, **kwargs)
+            return call(*args, **kwargs)
         # fault-injection site: every real dispatch (not nested traces)
         # counts; disarmed cost is one module-global None test
         _fault_inject.maybe_fire("dispatch")
@@ -403,9 +433,9 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
                     # (deserialized executables mishandle the donation
                     # aliasing — see _install_cache_bypass)
                     with _no_persist_scope():
-                        out = jitted(*args, **kwargs)
+                        out = call(*args, **kwargs)
                 else:
-                    out = jitted(*args, **kwargs)
+                    out = call(*args, **kwargs)
                 after = _cache_size(jitted)
                 compiled = after >= 0 and after != before
                 if compiled:
@@ -422,6 +452,13 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
     wrapper.label = name
     wrapper.program = program
     return wrapper
+
+
+def plan_jit(fn: Optional[Callable] = None, **kwargs) -> Callable:
+    """:func:`instrumented_jit` for a program that traces a plan's
+    expressions (an operator's per-batch program, a stage program): it
+    takes the executing query's bound literal parameters."""
+    return instrumented_jit(fn, bound=True, **kwargs)
 
 
 # -- jax.monitoring hook (precise backend compile seconds) -------------------

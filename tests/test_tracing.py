@@ -605,12 +605,20 @@ def test_benchmark_json_gained_only_the_seven_entries():
     with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-7:] == ["plan_ms", "device_wait_ms", "host_ms_per_query",
-                          "jax_trace_s", "lower_s", "backend_compile_s",
-                          "cache_load_s"]
-    for m in bench["per_layer"][-7:]:
+    # PR 26's seven, in place; PR 28 appended four behind them, each listing
+    # the one cell whose counters it reads
+    first = names.index("plan_ms")
+    assert names[first:first + 7] == [
+        "plan_ms", "device_wait_ms", "host_ms_per_query", "jax_trace_s",
+        "lower_s", "backend_compile_s", "cache_load_s"]
+    for m in bench["per_layer"][first:first + 7]:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves"}
         assert m["source"] == "program_counter"
+    assert names[first + 7:] == ["shape_hit_pct", "parse_ms", "bind_ms",
+                                 "setup_variant_compiles"]
+    for m in bench["per_layer"][first:]:
+        assert m.get("workloads", ["tpch_sf1_qgen.q6_text"]) == \
+            ["tpch_sf1_qgen.q6_text"]
         assert os.path.exists(os.path.join(
             REPO_ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
