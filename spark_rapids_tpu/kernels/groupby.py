@@ -6,6 +6,10 @@ key equality, and run ``jax.ops.segment_*`` reductions with
 ``num_segments = capacity`` so shapes stay static.  The same machinery serves
 partial (update) and final (merge) aggregation modes — mirroring the
 reference's update/merge projections (aggregate.scala:420-431).
+
+With no grouping key there is one group and nothing to sort: the same
+segment kernels run over ONE segment in input order and the result is one
+row at ``MIN_CAPACITY`` (``_keyless_aggregate``).
 """
 
 from __future__ import annotations
@@ -16,7 +20,7 @@ from typing import List, Sequence, Tuple
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.batch import ColumnBatch, DeviceColumn
+from spark_rapids_tpu.batch import MIN_CAPACITY, ColumnBatch, DeviceColumn
 from spark_rapids_tpu.exprs.base import DevVal
 from spark_rapids_tpu.kernels.layout import (
     compaction_indices, ensure_row_layout, gather_rows,
@@ -81,6 +85,9 @@ def groupby_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
     partial buffers per aggregate (flattened by caller) and ``segment_merge``
     is used; otherwise raw inputs + ``segment_update``.
     """
+    if not key_vals:
+        return _keyless_aggregate(batch, agg_inputs, agg_fns, merge,
+                                  key_schema, buffer_schemas)
     cap = batch.capacity
     segs = group_segments(key_vals, batch.num_rows)
 
@@ -98,15 +105,10 @@ def groupby_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
 
     out_buffers: List[List[DevVal]] = []
     if merge:
-        flat_i = 0
-        for fn, bufs in zip(agg_fns, buffer_schemas):
-            n = len(bufs)
-            partials = []
-            for k in range(n):
-                v = agg_inputs[flat_i]
-                flat_i += 1
-                partials.append(DevVal(v.dtype, v.data[segs.perm],
-                                       v.validity[segs.perm]))
+        for fn, partials in zip(agg_fns,
+                                _partials_of(agg_inputs, buffer_schemas)):
+            partials = [DevVal(v.dtype, v.data[segs.perm],
+                               v.validity[segs.perm]) for v in partials]
             out_buffers.append(fn.segment_merge(partials, segs.seg_ids, cap,
                                                 segs.live))
     else:
@@ -124,6 +126,51 @@ def groupby_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
             out_buffers.append(fn.segment_update(sv, segs.seg_ids, cap,
                                                  segs.live))
     return group_keys, out_buffers
+
+
+def _partials_of(agg_inputs: List[DevVal],
+                 buffer_schemas: List[List[T.DataType]]):
+    """The flat merge-mode inputs, regrouped one list an aggregate."""
+    it = iter(agg_inputs)
+    return [[next(it) for _ in bufs] for bufs in buffer_schemas]
+
+
+def _keyless_aggregate(batch: ColumnBatch, agg_inputs: List[DevVal],
+                       agg_fns: Sequence, merge: bool, key_schema: T.Schema,
+                       buffer_schemas: List[List[T.DataType]]
+                       ) -> Tuple[ColumnBatch, List[List[DevVal]]]:
+    """No grouping key: one group, so nothing is sorted.  The rows stay in
+    input order (the identity permutation: no gather, first/last keep
+    their order), every row is segment 0 of ONE segment, and there is no
+    key column to gather or compact.  Each aggregate's own
+    ``segment_update`` / ``segment_merge`` runs unchanged over that one
+    segment; its one-row buffers leave padded to ``MIN_CAPACITY``, which
+    is the capacity of the (column-less) key batch returned.  An empty
+    input yields the identity buffers (the SQL default row)."""
+    cap = batch.capacity
+    live = jnp.arange(cap, dtype=jnp.int32) < batch.num_rows
+    seg_ids = jnp.zeros(cap, jnp.int32)
+    if merge:
+        bufs = [fn.segment_merge(partials, seg_ids, 1, live)
+                for fn, partials in zip(
+                    agg_fns, _partials_of(agg_inputs, buffer_schemas))]
+    else:
+        bufs = [fn.segment_update(v, seg_ids, 1, live)
+                for fn, v in zip(agg_fns, agg_inputs)]
+    return one_group_output(key_schema, bufs)
+
+
+def one_group_output(key_schema: T.Schema, buffers: List[List[DevVal]]
+                     ) -> Tuple[ColumnBatch, List[List[DevVal]]]:
+    """(column-less key batch of ONE row at ``MIN_CAPACITY``, the one-row
+    ``buffers`` padded to it): what a keyless aggregate hands on."""
+    def _pad(a):
+        return jnp.pad(a, (0, MIN_CAPACITY - 1))
+
+    group_keys = ColumnBatch(key_schema, [], jnp.asarray(1, jnp.int32),
+                             MIN_CAPACITY)
+    return group_keys, [[DevVal(b.dtype, _pad(b.data), _pad(b.validity))
+                         for b in bufs] for bufs in buffers]
 
 
 def _gather_str_val(v: DevVal, perm, cap: int) -> DevVal:

@@ -33,6 +33,16 @@ Exactness of the reductions:
   error is at the final f64-rounding level (~1 ulp per chunk), tighter
   than a variable-order device reduction.
 
+No grouping key (``keyless_aggregate``): one group, so there is no slot
+and no table.  The same value rows — live count, per-aggregate counts,
+8-bit limbs, fixed-point limbs, the NaN/Inf flag — are SUMMED along their
+chunk axis instead of contracted against a one-hot; the chunk totals are
+the same exact integers, so the buffers are bit-identical to slot 0 of the
+contraction under a constant key, and the partial leaves as ONE row at
+``MIN_CAPACITY``.  ``TpuHashAggregateExec`` takes it whenever its plan has
+no key expression (same gate, same flag contract as the slot path); the
+merge of such partials is kernels/groupby.py's keyless branch.
+
 Reference role: the cudf hash aggregate (aggregate.scala:456) — re-imagined
 for the MXU instead of a GPU hash table.
 """
@@ -50,6 +60,7 @@ from spark_rapids_tpu.batch import (
     ColumnBatch, DeviceColumn, round_up_capacity,
 )
 from spark_rapids_tpu.exprs.base import DevVal
+from spark_rapids_tpu.kernels.groupby import one_group_output
 from spark_rapids_tpu.kernels.layout import compaction_indices
 from spark_rapids_tpu.utils.tracing import kernel_scope
 
@@ -105,65 +116,17 @@ def _float_limb_rows(x, use, nc: int, c: int):
     return rows, scale
 
 
-@kernel_scope
-def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
-                         agg_inputs: List[DevVal], agg_fns: Sequence,
-                         key_schema: T.Schema,
-                         out_schema: T.Schema,
-                         table: int = TABLE_SLOTS):
-    """(group-key batch, per-agg buffer lists, n_groups, fallback flag).
-
-    Buffer layout matches the sort-based update path (consumed unchanged
-    by the merge stage).  ``fallback`` True means the key range did not
-    fit the slot table (or a float sum saw non-finite values) — the
-    caller MUST discard the result and use the sort path."""
+def _value_rows(live, agg_inputs: List[DevVal], agg_fns: Sequence,
+                nc: int, c: int, fallback):
+    """(f32 rows [cap] each, recombination plan, fallback flag): what both
+    forms of the contraction sum.  Row 0 is the live count; every sum,
+    count and average adds its own count row and its limb rows, all zero
+    on dead and NULL rows; min/max/first/last are only planned here
+    (``_buffers`` runs their own segment kernels).  ``fallback`` is ORed
+    with "a float sum saw NaN, Inf or a magnitude past 2^1000"."""
     from spark_rapids_tpu.exprs.aggregates import (
-        Average, Count, First, Last, Max, Min, Sum, unsorted_segment_ids,
+        Count, First, Last, Max, Min,
     )
-
-    cap = batch.capacity
-    c = min(_CHUNK, cap)
-    nc = cap // c
-    live = jnp.arange(cap, dtype=jnp.int32) < batch.num_rows
-
-    # ---- mixed-radix slot packing over all key columns -------------------
-    # digit_i = k_i - min_i (or range_i for NULL); radix_i = range_i +
-    # has_null_i; slot = sum(digit_i * stride_i).  Bijective onto
-    # [0, prod(radix)); fallback when the packed space exceeds table+1.
-    i64max = jnp.int64(jnp.iinfo(jnp.int64).max)
-    i64min = jnp.int64(jnp.iinfo(jnp.int64).min)
-    fallback = jnp.asarray(False)
-    slot64 = jnp.zeros(cap, jnp.int64)
-    stride = jnp.int64(1)
-    prod_f = jnp.float64(1.0)
-    key_decode = []  # (kmin, rng, radix, stride) per key, for output
-    for kv in key_vals:
-        kx = kv.data.astype(jnp.int64)
-        usek = live & kv.validity
-        any_key = jnp.any(usek)
-        has_null = jnp.any(live & ~kv.validity)
-        kmin = jnp.min(jnp.where(usek, kx, i64max))
-        kmax = jnp.max(jnp.where(usek, kx, i64min))
-        # wrap-around of (kmax - kmin) goes negative -> correctly rejected
-        key_fits = (kmax - kmin >= 0) & (kmax - kmin < table + 1)
-        fallback = fallback | (any_key & ~key_fits)
-        kmin = jnp.where(any_key & key_fits, kmin, jnp.int64(0))
-        rng = jnp.where(any_key & key_fits, kmax - kmin + 1, jnp.int64(0))
-        radix = jnp.maximum(rng + has_null.astype(jnp.int64), jnp.int64(1))
-        digit = jnp.where(usek, jnp.clip(kx - kmin, 0, table), rng)
-        slot64 = slot64 + digit * stride
-        key_decode.append((kmin, rng, radix, stride))
-        stride = stride * radix
-        prod_f = prod_f * radix.astype(jnp.float64)
-    # capacity check in f64: an int64 stride product can wrap silently
-    fallback = fallback | (prod_f > jnp.float64(table + 1))
-
-    # slots: 0..table = packed key tuples, table+1 = dead rows
-    tt = table + 2
-    slot = jnp.where(live, jnp.clip(slot64, 0, table).astype(jnp.int32),
-                     jnp.int32(table + 1))
-
-    # ---- stacked einsum rows ---------------------------------------------
     rows: List[jnp.ndarray] = [live.astype(jnp.float32)]  # per-slot count
     agg_plan = []                                         # recombination
     for fn, v in zip(agg_fns, agg_inputs):
@@ -197,13 +160,15 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
             fr, scale = _float_limb_rows(v.data, use, nc, c)
             rows.extend(fr)
             agg_plan.append(("float_sum", use_at, at, scale, type(fn)))
+    return rows, agg_plan, fallback
 
-    r_n = len(rows)
-    stacked = jnp.stack(rows, axis=0)                     # [R, cap] f32
-    stacked = stacked.reshape(r_n, nc, c).transpose(1, 0, 2)
-    oh = jax.nn.one_hot(slot.reshape(nc, c), tt, dtype=jnp.float32)
-    per_chunk = jnp.einsum("crn,cnt->crt", stacked, oh,
-                           preferred_element_type=jnp.float32)
+
+def _buffers(per_chunk, agg_plan, agg_fns: Sequence, slot, live, ng: int):
+    """(live count per slot, per-agg buffer lists over the first ``ng``
+    slots) from the chunk totals ``per_chunk`` f32[nc, R, tt] — the same
+    recombination whichever contraction produced them."""
+    from spark_rapids_tpu.exprs.aggregates import Sum, unsorted_segment_ids
+    nc, r_n, tt = per_chunk.shape
     # chunk partials are exact integers < 2^23: accumulate across chunks
     # in native i32 lanes up to 256 chunks (256 * 2^23 < 2^31), then in
     # i64 — a flat i32 sum would overflow past ~4M rows per batch
@@ -212,10 +177,6 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
         pc_i = pc_i.reshape(nc // 256, 256, r_n, tt).sum(axis=1)
     totals_i = jnp.sum(pc_i.astype(jnp.int64), axis=0)    # [R, tt]
 
-    live_cnt = totals_i[0]
-    used = live_cnt[:table + 1] > 0                       # incl NULL group
-
-    # ---- buffers ----------------------------------------------------------
     def _int_total(spec, use_at):
         total = jnp.zeros(tt, jnp.int64)
         for base_at, biased in spec:
@@ -230,7 +191,6 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
             total = total + word_sum
         return total
 
-    ng = table + 1
     ones_t = jnp.ones(ng, jnp.bool_)
     buffer_cols: List[List[DevVal]] = []
     for plan, fn in zip(agg_plan, agg_fns):
@@ -273,6 +233,75 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
                 bufs = [DevVal(T.DOUBLE, total, ones_t),
                         DevVal(T.LONG, cnt, ones_t)]
         buffer_cols.append(bufs)
+    return totals_i[0], buffer_cols
+
+
+@kernel_scope
+def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
+                         agg_inputs: List[DevVal], agg_fns: Sequence,
+                         key_schema: T.Schema,
+                         out_schema: T.Schema,
+                         table: int = TABLE_SLOTS):
+    """(group-key batch, per-agg buffer lists, n_groups, fallback flag).
+
+    Buffer layout matches the sort-based update path (consumed unchanged
+    by the merge stage).  ``fallback`` True means the key range did not
+    fit the slot table (or a float sum saw non-finite values) — the
+    caller MUST discard the result and use the sort path."""
+    cap = batch.capacity
+    c = min(_CHUNK, cap)
+    nc = cap // c
+    live = jnp.arange(cap, dtype=jnp.int32) < batch.num_rows
+
+    # ---- mixed-radix slot packing over all key columns -------------------
+    # digit_i = k_i - min_i (or range_i for NULL); radix_i = range_i +
+    # has_null_i; slot = sum(digit_i * stride_i).  Bijective onto
+    # [0, prod(radix)); fallback when the packed space exceeds table+1.
+    i64max = jnp.int64(jnp.iinfo(jnp.int64).max)
+    i64min = jnp.int64(jnp.iinfo(jnp.int64).min)
+    fallback = jnp.asarray(False)
+    slot64 = jnp.zeros(cap, jnp.int64)
+    stride = jnp.int64(1)
+    prod_f = jnp.float64(1.0)
+    key_decode = []  # (kmin, rng, radix, stride) per key, for output
+    for kv in key_vals:
+        kx = kv.data.astype(jnp.int64)
+        usek = live & kv.validity
+        any_key = jnp.any(usek)
+        has_null = jnp.any(live & ~kv.validity)
+        kmin = jnp.min(jnp.where(usek, kx, i64max))
+        kmax = jnp.max(jnp.where(usek, kx, i64min))
+        # wrap-around of (kmax - kmin) goes negative -> correctly rejected
+        key_fits = (kmax - kmin >= 0) & (kmax - kmin < table + 1)
+        fallback = fallback | (any_key & ~key_fits)
+        kmin = jnp.where(any_key & key_fits, kmin, jnp.int64(0))
+        rng = jnp.where(any_key & key_fits, kmax - kmin + 1, jnp.int64(0))
+        radix = jnp.maximum(rng + has_null.astype(jnp.int64), jnp.int64(1))
+        digit = jnp.where(usek, jnp.clip(kx - kmin, 0, table), rng)
+        slot64 = slot64 + digit * stride
+        key_decode.append((kmin, rng, radix, stride))
+        stride = stride * radix
+        prod_f = prod_f * radix.astype(jnp.float64)
+    # capacity check in f64: an int64 stride product can wrap silently
+    fallback = fallback | (prod_f > jnp.float64(table + 1))
+
+    # slots: 0..table = packed key tuples, table+1 = dead rows
+    tt = table + 2
+    slot = jnp.where(live, jnp.clip(slot64, 0, table).astype(jnp.int32),
+                     jnp.int32(table + 1))
+
+    rows, agg_plan, fallback = _value_rows(live, agg_inputs, agg_fns, nc,
+                                           c, fallback)
+    r_n = len(rows)
+    stacked = jnp.stack(rows, axis=0)                     # [R, cap] f32
+    stacked = stacked.reshape(r_n, nc, c).transpose(1, 0, 2)
+    oh = jax.nn.one_hot(slot.reshape(nc, c), tt, dtype=jnp.float32)
+    per_chunk = jnp.einsum("crn,cnt->crt", stacked, oh,
+                           preferred_element_type=jnp.float32)
+    ng = table + 1
+    live_cnt, buffer_cols = _buffers(per_chunk, agg_plan, agg_fns, slot,
+                                     live, ng)
+    used = live_cnt[:ng] > 0                              # incl NULL group
 
     # ---- compact used slots; keys reconstructed from slot indices -------
     # (mixed-radix decode: digit_i = (slot // stride_i) % radix_i; the
@@ -298,6 +327,35 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
                             _pad(b.validity[idx])) for b in bufs]
                     for bufs in buffer_cols]
     return group_keys, compact_bufs, n_groups, fallback
+
+
+@kernel_scope
+def keyless_aggregate(batch: ColumnBatch, agg_inputs: List[DevVal],
+                      agg_fns: Sequence, key_schema: T.Schema):
+    """(column-less key batch, per-agg buffer lists, fallback flag) for an
+    update batch of an aggregate with no grouping key: ONE row at
+    ``MIN_CAPACITY``.
+
+    One group, so nothing is slotted: the rows ``hash_group_aggregate``
+    would contract against ``one_hot(slot)`` are summed along their chunk
+    axis instead.  Chunk totals are exact integers below 2^24 in both
+    forms, so the buffers equal, bit for bit, slot 0 of that kernel under
+    a constant key; min/max/first/last run the aggregate's own segment
+    kernel over one segment.  An empty batch yields the identity buffers.
+    ``fallback`` True (a float sum saw NaN/Inf/over 2^1000): the caller
+    MUST discard the result and use the sort path, as for the keyed
+    kernel."""
+    cap = batch.capacity
+    c = min(_CHUNK, cap)
+    nc = cap // c
+    live = jnp.arange(cap, dtype=jnp.int32) < batch.num_rows
+    rows, agg_plan, fallback = _value_rows(live, agg_inputs, agg_fns, nc,
+                                           c, jnp.asarray(False))
+    per_chunk = jnp.stack([r.reshape(nc, c).sum(axis=1) for r in rows],
+                          axis=1)[:, :, None]             # [nc, R, 1] f32
+    _, buffer_cols = _buffers(per_chunk, agg_plan, agg_fns,
+                              jnp.zeros(cap, jnp.int32), live, 1)
+    return one_group_output(key_schema, buffer_cols) + (fallback,)
 
 
 def hash_agg_capable(mode: str, key_types: List[T.DataType],

@@ -503,6 +503,14 @@ class TpuHashAggregateExec(TpuExec):
                    (group keys + agg buffers).
     mode="merge":  partial batches (post-exchange) -> merged groups ->
                    finalized output projection.
+
+    No key expression (``sum(x)`` with no GROUP BY): one group, so neither
+    mode groups anything.  An update batch is reduced
+    (kernels/hashagg.keyless_aggregate; metric ``keylessAggBatches``, the
+    slot path's ``mxuAggBatches`` stays 0) or, where the MXU gate is off or
+    the aggregates are outside ``hash_agg_capable``, folded by the segment
+    kernels over one segment without a sort (kernels/groupby); a merge is
+    the latter.  Partials and the result are ONE row at ``MIN_CAPACITY``.
     """
 
     def __init__(self, mode: str, key_exprs: List[Expression],
@@ -644,6 +652,8 @@ class TpuHashAggregateExec(TpuExec):
         float inputs).  Any flag discards the stage and re-runs the exact
         sort variant — correctness never depends on data shape."""
         if not outs or outs[-1].schema is not _HASH_FLAGS_SCHEMA:
+            if self.mode == "update":
+                self._count_update_batches(ctx, len(outs), fast=False)
             return outs
         # a mesh-sharded stage unshards one flags pseudo-batch PER
         # device (all trailing — the flags batch is the last program
@@ -654,9 +664,20 @@ class TpuHashAggregateExec(TpuExec):
         if flagged:
             self._hash_disabled = True
             ctx.metric(self.op_id, "hashAggFallback").add(1)
-            return rerun()
-        ctx.metric(self.op_id, "mxuAggBatches").add(len(outs))
+            outs = rerun()
+        self._count_update_batches(ctx, len(outs), fast=not flagged)
         return outs
+
+    def _count_update_batches(self, ctx, n: int, fast: bool):
+        """Update batches by the form that aggregated them: the slot
+        contraction (``mxuAggBatches``, with keys) or the reduction
+        (``keylessAggBatches``, without), of ``keylessUpdateBatches`` a
+        keyless aggregate saw in all; ``fast`` False is the sort variant."""
+        if not self.key_exprs:
+            ctx.metric(self.op_id, "keylessUpdateBatches").add(n)
+        if fast:
+            ctx.metric(self.op_id, "mxuAggBatches" if self.key_exprs
+                       else "keylessAggBatches").add(n)
 
     # -- core ---------------------------------------------------------------
 
@@ -684,86 +705,65 @@ class TpuHashAggregateExec(TpuExec):
             return eval_maybe_encoded(fn.child, ctx)
         return fn.child.tpu_eval(ctx)
 
-    def _synth_key(self, batch) -> List[DevVal]:
-        """Zero grouping keys (global reduction): constant key, one group."""
-        cap = batch.capacity
-        return [DevVal(T.INT, jnp.zeros(cap, dtype=jnp.int32),
-                       jnp.ones(cap, dtype=jnp.bool_))]
+    def _partial_buffers(self, batch: ColumnBatch) -> List[DevVal]:
+        """The partial-buffer columns of a batch of update-mode outputs,
+        flat, in aggregate order (they follow the key columns)."""
+        i = len(self.key_exprs)
+        n = sum(len(bufs) for bufs in self.buffer_schemas)
+        return [DevVal.from_column(c) for c in batch.columns[i:i + n]]
+
+    def _output_batch(self, group_keys: ColumnBatch, buffers,
+                       finalize: bool = False) -> ColumnBatch:
+        """Group keys + per-aggregate buffers (or, finalized, results) as
+        one batch at the capacity the kernel gave the keys.  No key: the
+        kernel gave one row at ``MIN_CAPACITY`` — a reduction always emits
+        exactly one row, and an empty input left it the identity buffers
+        -> SQL defaults (count=0, sum=NULL...)."""
+        cols = list(group_keys.columns)
+        for a, bufs in zip(self.aggs, buffers):
+            for b in ([a.fn.finalize(bufs)] if finalize else bufs):
+                cols.append(DeviceColumn(b.dtype, b.data, b.validity,
+                                         b.offsets))
+        return ColumnBatch(self.output_schema, cols, group_keys.num_rows,
+                           group_keys.capacity)
 
     def _aggregate_batch(self, batch: ColumnBatch) -> ColumnBatch:
-        keyless = not self.key_exprs
-        key_vals = self._synth_key(batch) if keyless else \
-            self._eval_keys(batch)
-        key_schema = T.Schema([("__k", T.INT)]) if keyless else \
-            self.key_schema
-
         if self.mode == "update":
             ctx = TpuEvalCtx(batch)
             agg_inputs = [self._eval_agg_input(a.fn, ctx)
                           for a in self.aggs]
-            merge = False
         else:
-            nk = len(self.key_exprs) if not keyless else 0
-            agg_inputs = []
-            i = nk
-            for bufs in self.buffer_schemas:
-                for _ in bufs:
-                    agg_inputs.append(DevVal.from_column(batch.columns[i]))
-                    i += 1
-            merge = True
-
+            agg_inputs = self._partial_buffers(batch)
+        merge = self.mode == "merge"
         group_keys, buffers = groupby_aggregate(
-            batch, key_vals, agg_inputs, [a.fn for a in self.aggs], merge,
-            key_schema, self.buffer_schemas, self.output_schema)
-
-        num_groups = group_keys.num_rows
-        if keyless:
-            # A reduction always emits exactly one row; empty input yields
-            # the identity buffers -> SQL defaults (count=0, sum=NULL...).
-            num_groups = jnp.asarray(1, jnp.int32)
-        cap = batch.capacity
-
-        if self.mode == "update":
-            cols = [] if keyless else list(group_keys.columns)
-            for bufs in buffers:
-                for b in bufs:
-                    cols.append(DeviceColumn(b.dtype, b.data,
-                                             b.validity, b.offsets))
-            return ColumnBatch(self.output_schema, cols, num_groups, cap)
-
-        # merge mode: finalize each agg into its output column
-        cols = [] if keyless else list(group_keys.columns)
-        for a, bufs in zip(self.aggs, buffers):
-            v = a.fn.finalize(bufs)
-            cols.append(DeviceColumn(v.dtype, v.data, v.validity, v.offsets))
-        return ColumnBatch(self.output_schema, cols, num_groups, cap)
+            batch, self._eval_keys(batch), agg_inputs,
+            [a.fn for a in self.aggs], merge, self.key_schema,
+            self.buffer_schemas, self.output_schema)
+        # merge mode finalizes each agg into its output column
+        return self._output_batch(group_keys, buffers, finalize=merge)
 
     def _aggregate_batch_hash(self, batch: ColumnBatch):
-        """(partial batch, fallback flag) via the MXU slot kernel — same
-        output layout as the sort-based update path.  flag=True means the
-        result is INVALID (key range exceeded the slot table, or a float
-        sum saw NaN/Inf) and the caller must re-run the sort path."""
-        from spark_rapids_tpu.kernels.hashagg import hash_group_aggregate
-        keyless = not self.key_exprs
-        key_vals = self._synth_key(batch) if keyless else \
-            self._eval_keys(batch)
-        key_schema = T.Schema([("__k", T.INT)]) if keyless else \
-            self.key_schema
+        """(partial batch, fallback flag) — same output layout as the
+        sort-based update path.  With grouping keys: the MXU slot kernel.
+        With none there is one group and nothing to slot: the kernel's
+        limb rows are reduced, not contracted (``keyless_aggregate``).
+        flag=True means the result is INVALID (key range exceeded the slot
+        table, or a float sum saw NaN/Inf) and the caller must re-run the
+        sort path."""
+        from spark_rapids_tpu.kernels.hashagg import (
+            hash_group_aggregate, keyless_aggregate,
+        )
         ctx = TpuEvalCtx(batch)
         agg_inputs = [self._eval_agg_input(a.fn, ctx) for a in self.aggs]
-        group_keys, buffers, num_groups, collided = hash_group_aggregate(
-            batch, key_vals, agg_inputs, [a.fn for a in self.aggs],
-            key_schema, self.output_schema, table=self._mxu_table)
-        if keyless:
-            num_groups = jnp.asarray(1, jnp.int32)
-        cols = [] if keyless else list(group_keys.columns)
-        for bufs in buffers:
-            for b in bufs:
-                cols.append(DeviceColumn(b.dtype, b.data, b.validity,
-                                         b.offsets))
-        out = ColumnBatch(self.output_schema, cols, num_groups,
-                          group_keys.capacity)
-        return out, collided
+        fns = [a.fn for a in self.aggs]
+        if self.key_exprs:
+            group_keys, buffers, _, flagged = hash_group_aggregate(
+                batch, self._eval_keys(batch), agg_inputs, fns,
+                self.key_schema, self.output_schema, table=self._mxu_table)
+        else:
+            group_keys, buffers, flagged = keyless_aggregate(
+                batch, agg_inputs, fns, self.key_schema)
+        return self._output_batch(group_keys, buffers), flagged
 
     def partitions(self, ctx):
         child_schema = self.children[0].output_schema
@@ -846,52 +846,32 @@ class TpuHashAggregateExec(TpuExec):
         return [gen(p) for p in self.children[0].partitions(ctx)]
 
     def _update_partials(self, ctx, batches):
-        """Per-batch partials, preferring the MXU slot path; any flagged
-        batch (key range over the slot table, or NaN/Inf float inputs —
-        device-verified) re-runs on the exact sort path, and the MXU path
-        turns off for this exec."""
-        if not self._hash_active(ctx):
-            return [self._run(db) for db in batches]
-        pairs = [self._run_hash(db) for db in batches]
-        flags = device_read("hashagg_flags", [f for _, f in pairs]) \
-            if pairs else []
-        if not any(bool(f) for f in flags):
-            ctx.metric(self.op_id, "mxuAggBatches").add(len(pairs))
-            return [p for p, _ in pairs]
-        self._hash_disabled = True
-        ctx.metric(self.op_id, "hashAggFallback").add(1)
+        """Per-batch partials, preferring the MXU slot path (keyless: the
+        reduction); any flagged batch (key range over the slot table, or
+        NaN/Inf float inputs — device-verified) re-runs on the exact sort
+        path, and the fast path turns off for this exec."""
+        if self._hash_active(ctx):
+            pairs = [self._run_hash(db) for db in batches]
+            flags = device_read("hashagg_flags", [f for _, f in pairs]) \
+                if pairs else []
+            if not any(bool(f) for f in flags):
+                self._count_update_batches(ctx, len(pairs), fast=True)
+                return [p for p, _ in pairs]
+            self._hash_disabled = True
+            ctx.metric(self.op_id, "hashAggFallback").add(1)
+        self._count_update_batches(ctx, len(batches), fast=False)
         return [self._run(db) for db in batches]
 
     def _merge_partials(self, merged: ColumnBatch) -> ColumnBatch:
         """Merge concatenated update-mode outputs back to one partial batch
         per partition (keys + buffers -> keys + buffers)."""
-        keyless = not self.key_exprs
-        key_vals = self._synth_key(merged) if keyless else [
-            DevVal.from_column(merged.columns[i])
-            for i in range(len(self.key_exprs))
-        ]
-        key_schema = T.Schema([("__k", T.INT)]) if keyless else \
-            self.key_schema
-        nk = 0 if keyless else len(self.key_exprs)
-        agg_inputs = []
-        i = nk
-        for bufs in self.buffer_schemas:
-            for _ in bufs:
-                agg_inputs.append(DevVal.from_column(merged.columns[i]))
-                i += 1
+        key_vals = [DevVal.from_column(c)
+                    for c in merged.columns[:len(self.key_exprs)]]
         group_keys, buffers = groupby_aggregate(
-            merged, key_vals, agg_inputs, [a.fn for a in self.aggs], True,
-            key_schema, self.buffer_schemas, self.output_schema)
-        num_groups = group_keys.num_rows
-        if keyless:
-            num_groups = jnp.asarray(1, jnp.int32)
-        cols = [] if keyless else list(group_keys.columns)
-        for bufs in buffers:
-            for b in bufs:
-                cols.append(DeviceColumn(b.dtype, b.data, b.validity,
-                                         b.offsets))
-        return ColumnBatch(self.output_schema, cols, num_groups,
-                           merged.capacity)
+            merged, key_vals, self._partial_buffers(merged),
+            [a.fn for a in self.aggs], True, self.key_schema,
+            self.buffer_schemas, self.output_schema)
+        return self._output_batch(group_keys, buffers)
 
 
 def _eval_join_keys(exprs, batch, dict_keys: bool):

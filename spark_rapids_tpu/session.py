@@ -564,6 +564,12 @@ class TpuSparkSession:
         def _scan_sum(key):
             return sum(ms[key].value for ms in ctx.metrics.values()
                        if key in ms)
+        # aggregate economics (TpuHashAggregateExec): update batches that
+        # took the slot contraction (keyed) or the reduction (keyless), of
+        # the update batches keyless aggregates saw in all
+        for key in ("mxuAggBatches", "keylessAggBatches",
+                    "keylessUpdateBatches"):
+            frame.last_metrics[key] = _scan_sum(key)
         frame.last_metrics["scanDecodeWallNs"] = _scan_sum("scanDecodeWallNs")
         frame.last_metrics["scanH2dOverlapNs"] = _scan_sum("scanH2dOverlapNs")
         frame.last_metrics["scanBytesDecoded"] = _scan_sum("scanBytesDecoded")

@@ -1,14 +1,16 @@
 """Compile for the chip without the chip (on-chip-measurement guide, §2.3).
 
 The TPU compiler is installed here and compiles for a v5e that is
-DESCRIBED, not attached.  Two things no CPU test can see are pinned:
+DESCRIBED, not attached.  Three things no CPU test can see are pinned:
 
 * every registered Pallas kernel's default gate is ON if and only if the
   v5e compiler accepts the kernel at the shapes TPC-H SF1 produces
   (interpret mode accepts all four; the chip's compiler does not), and
 * the branches taken only when ``jax.default_backend() == "tpu"`` (the
   float-float f64 sort words, the LSD argsort, the 3-word double count in
-  the join key encoding) compile for the chip.
+  the join key encoding) compile for the chip, and
+* Q6's keyless update batch at SF1's shape compiles to reductions, with
+  no contraction left in the v5e program.
 
 Nothing here runs on a device, so nothing here is a result or a time.
 The topology is described inside a module-scoped fixture — never at
@@ -223,3 +225,31 @@ def test_flagship_groupby_stage_compiles(as_tpu):
     shapes = jax.tree_util.tree_map(
         lambda a: as_tpu(a.shape, a.dtype), big)
     _compile(step, shapes)
+
+
+def test_keyless_update_batch_compiles_to_reductions(on_chip):
+    """Q6's update batch at SF1's shape (a million rows, f64 columns, the
+    predicate inside the sum's argument) through ``keyless_aggregate``:
+    the v5e program holds reductions and no contraction — no ``dot`` or
+    ``convolution``, nothing 8,194 slots wide (PR 29; PERF.md section 5)."""
+    from spark_rapids_tpu.batch import ColumnBatch
+    from spark_rapids_tpu.exprs.aggregates import Sum
+    from spark_rapids_tpu.exprs.base import ColumnRef
+    from spark_rapids_tpu.kernels.hashagg import keyless_aggregate
+    cap, none = BRANCH_ROWS, T.Schema([])
+    fns = [Sum(ColumnRef("revenue", T.DOUBLE))]
+
+    def update(price, discount, quantity, ok, rows):
+        keep = (discount >= 0.05) & (discount <= 0.07) & (quantity < 24.0)
+        value = DevVal(T.DOUBLE, price * discount, ok & keep)
+        _keys, bufs, flag = keyless_aggregate(
+            ColumnBatch(none, [], rows, cap), [value], fns, none)
+        return [(b.data, b.validity) for bs in bufs for b in bs], flag
+
+    f64 = on_chip((cap,), jnp.float64)
+    text = _compile(update, f64, f64, f64, on_chip((cap,), jnp.bool_),
+                    on_chip((), jnp.int32)).as_text()
+    entry = text[text.index("ENTRY"):]
+    assert " dot(" not in text and " convolution(" not in text
+    assert "8194" not in entry
+    assert " reduce(" in text or "reduce_fusion" in entry

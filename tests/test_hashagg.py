@@ -2,14 +2,18 @@
 CPU oracle, engagement on eligible plans, and the exact-fallback paths
 (wide key range, NaN floats, unsupported aggs)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.batch import MIN_CAPACITY, ColumnBatch
 from spark_rapids_tpu.dataframe import Column
 from spark_rapids_tpu.exprs.aggregates import (
     Average, Count, First, Last, Max, Min, Sum,
 )
-from spark_rapids_tpu.exprs.base import Alias, ColumnRef
+from spark_rapids_tpu.exprs.base import Alias, ColumnRef, DevVal
 
 from compare import assert_tpu_cpu_equal, cpu_session, tpu_session
 
@@ -17,6 +21,20 @@ from compare import assert_tpu_cpu_equal, cpu_session, tpu_session
 def _mxu_engaged(session) -> bool:
     return any(isinstance(ms, dict) and ms.get("mxuAggBatches", 0) > 0
                for ms in session.last_metrics.values())
+
+
+def _update_aggs(session):
+    from spark_rapids_tpu.ops.tpu_exec import TpuHashAggregateExec
+    found = []
+
+    def walk(node):
+        if isinstance(node, TpuHashAggregateExec) and node.mode == "update":
+            found.append(node)
+        for ch in getattr(node, "children", []):
+            walk(ch)
+
+    walk(session.last_physical_plan)
+    return found
 
 
 def _data(n=4000, key_range=97, with_nan=False):
@@ -59,16 +77,7 @@ def test_mxu_agg_engages_and_is_exact_for_ints():
     # int sum + count EXACT (limb recombination is bit-exact)
     assert t_rows == c_rows
     # the update agg really took the hash variant (sticky flag untouched)
-    from spark_rapids_tpu.ops.tpu_exec import TpuHashAggregateExec
-    aggs = []
-
-    def walk(node):
-        if isinstance(node, TpuHashAggregateExec) and node.mode == "update":
-            aggs.append(node)
-        for ch in getattr(node, "children", []):
-            walk(ch)
-
-    walk(tpu.last_physical_plan)
+    aggs = _update_aggs(tpu)
     assert aggs and all(a._hash_capable and not a._hash_disabled
                         for a in aggs)
 
@@ -279,3 +288,196 @@ def test_mxu_agg_negative_and_date_keys():
         lambda s: s.create_dataframe(data, num_partitions=2)
         .group_by("k").agg(Column(Alias(Sum(ColumnRef("v")), "sv")),
                            Column(Alias(Count(ColumnRef("v")), "cv"))))
+
+
+# -- no grouping key: the rows are reduced, not contracted (PR 29) ------------
+
+FLOAT_AGG = {"spark.rapids.sql.variableFloatAgg.enabled": True}
+
+
+def _flat(session, key):
+    return session.last_metrics.get(key, 0)
+
+
+def _keyless_case(name):
+    """(rows, capacity, {column: (dtype, values, validity)}, aggregates,
+    slot table of the contraction it is compared with)."""
+    from spark_rapids_tpu.kernels.hashagg import TABLE_SLOTS
+    rng = np.random.RandomState(29)
+    L, D = ColumnRef("l", T.LONG), ColumnRef("d", T.DOUBLE)
+
+    def cols(n, l_valid=None, d_valid=None):
+        lv = rng.randint(-2**62, 2**62, n).astype(np.int64)
+        dv = (rng.rand(n) * 2e6 - 1e6) * 10.0 ** rng.randint(-8, 9, n)
+        return {"l": (T.LONG, lv, np.arange(n) % 7 != 0
+                      if l_valid is None else l_valid),
+                "d": (T.DOUBLE, dv, np.arange(n) % 5 != 0
+                      if d_valid is None else d_valid)}
+
+    if name == "long_sum_count":
+        return 5000, 8192, cols(5000), [Sum(L), Count(L), Average(L)], \
+            TABLE_SLOTS
+    if name == "double_sum_avg":
+        return 5000, 8192, cols(5000), [Sum(D), Average(D), Count(D)], \
+            TABLE_SLOTS
+    if name == "min_max_first_last":
+        return 3000, 4096, cols(3000), [
+            Min(L), Max(L), Min(D), Max(D), First(L), Last(L),
+            First(D, ignore_nulls=True), Last(D, ignore_nulls=True)], 126
+    if name == "empty":
+        return 0, 1024, cols(1024), [Sum(L), Count(L), Sum(D), Average(D),
+                                     Min(D), First(L)], TABLE_SLOTS
+    if name == "all_null":
+        none = np.zeros(2000, np.bool_)
+        return 2000, 2048, cols(2000, none, none), [
+            Sum(L), Count(L), Sum(D), Average(D), Max(L), Last(D)], 126
+    if name == "two_to_the_20_rows":
+        # 64 chunks: the cross-chunk recombination.  A table of 6 slots
+        # keeps the CPU's one-hot einsum short; slot 0 holds every live
+        # row and the last slot every dead one whatever the table's size
+        n = 1 << 20
+        return n - 12345, n, cols(n), [Sum(L), Sum(D), Average(D),
+                                       Count(L), Min(D)], 6
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "long_sum_count", "double_sum_avg", "min_max_first_last", "empty",
+    "all_null", "two_to_the_20_rows"])
+def test_keyless_reduction_equals_slot_contraction_bit_for_bit(name):
+    """With no key there is one group: summing each limb row along its
+    chunk gives exactly what contracting it against one_hot(constant
+    slot) gives, so every buffer has the parent kernel's bits."""
+    from spark_rapids_tpu.kernels.hashagg import (
+        hash_group_aggregate, keyless_aggregate,
+    )
+    n, cap, columns, fns, table = _keyless_case(name)
+
+    def padded(a):
+        return jnp.asarray(np.concatenate(
+            [a[:min(len(a), cap)], np.zeros(cap - min(len(a), cap),
+                                            a.dtype)]))
+
+    vals = {c: DevVal(dt, padded(v), padded(ok))
+            for c, (dt, v, ok) in columns.items()}
+    inputs = [vals[fn.child.column] for fn in fns]
+    batch = ColumnBatch(T.Schema([]), [], jnp.asarray(n, jnp.int32), cap)
+    none, one_key = T.Schema([]), T.Schema([("__k", T.INT)])
+
+    @jax.jit
+    def contracted(batch, inputs):
+        key = [DevVal(T.INT, jnp.zeros(cap, jnp.int32),
+                      jnp.ones(cap, jnp.bool_))]
+        _keys, bufs, _n, flag = hash_group_aggregate(
+            batch, key, inputs, fns, one_key, none, table=table)
+        return bufs, flag
+
+    @jax.jit
+    def reduced(batch, inputs):
+        keys, bufs, flag = keyless_aggregate(batch, inputs, fns, none)
+        return keys.num_rows, bufs, flag
+
+    want, want_flag = contracted(batch, inputs)
+    rows, got, got_flag = reduced(batch, inputs)
+    assert int(rows) == 1 and not bool(want_flag) and not bool(got_flag)
+    for fn, wb, gb in zip(fns, want, got):
+        assert len(wb) == len(gb) == len(fn.buffers())
+        for w, g in zip(wb, gb):
+            assert g.data.shape == (MIN_CAPACITY,), (fn, g.data.shape)
+            assert g.data.dtype == w.data.dtype, fn
+            # row 0 is the one group; bytes, not values: -0.0 and NaN too
+            assert np.asarray(g.data[:1]).tobytes() == \
+                np.asarray(w.data[:1]).tobytes(), (name, fn, w.data[0],
+                                                   g.data[0])
+            assert bool(g.validity[0]) == bool(w.validity[0]), (name, fn)
+
+
+def test_keyless_plan_counts_reductions_and_keyed_plan_contractions():
+    data = _data(n=3000)
+
+    def keyless(s):
+        return s.create_dataframe(data, num_partitions=3).agg(
+            Column(Alias(Sum(ColumnRef("f")), "sf")),
+            Column(Alias(Count(ColumnRef("v")), "cv")),
+            Column(Alias(Max(ColumnRef("v")), "mv")))
+
+    assert_tpu_cpu_equal(keyless, approx=True, confs=FLOAT_AGG)
+    tpu = tpu_session(**FLOAT_AGG)
+    keyless(tpu).collect()
+    assert _flat(tpu, "keylessAggBatches") > 0, tpu.last_metrics
+    assert _flat(tpu, "keylessAggBatches") == \
+        _flat(tpu, "keylessUpdateBatches")
+    assert _flat(tpu, "mxuAggBatches") == 0 and not _mxu_engaged(tpu)
+    assert not any(a._hash_disabled for a in _update_aggs(tpu))
+
+    _q(tpu, data).collect()     # the same columns, grouped by k
+    assert _flat(tpu, "mxuAggBatches") > 0 and _mxu_engaged(tpu)
+    assert _flat(tpu, "keylessAggBatches") == 0
+    assert _flat(tpu, "keylessUpdateBatches") == 0
+
+
+@pytest.mark.parametrize("mxu", [True, False])
+def test_keyless_partials_and_result_leave_at_min_capacity(mxu):
+    """One row at MIN_CAPACITY from the reduction and from the sort variant
+    alike (keyless, the sort variant sorts nothing either), so the merge
+    works on a few dozen rows and the answer's D2H is a few hundred bytes,
+    not a padded megabyte."""
+    conf = dict(FLOAT_AGG, **{"spark.rapids.sql.agg.mxuHash.enabled": mxu})
+    data = _data(n=20000)
+
+    def q(s):
+        return s.create_dataframe(data, num_partitions=3).agg(
+            Column(Alias(Sum(ColumnRef("f")), "sf")),
+            Column(Alias(Average(ColumnRef("v")), "av")),
+            Column(Alias(Count(ColumnRef("k")), "ck")))
+
+    assert_tpu_cpu_equal(q, approx=True, confs=conf)
+    s = tpu_session(**conf)
+    q(s).collect()
+    assert 0 < s.last_metrics["d2hBytes"] <= 1024, s.last_metrics["d2hBytes"]
+    assert _flat(s, "keylessUpdateBatches") > 0
+    assert _flat(s, "keylessAggBatches") == \
+        (_flat(s, "keylessUpdateBatches") if mxu else 0)
+
+
+def test_keyless_groupby_kernel_sorts_nothing_and_pads_to_min_capacity():
+    from spark_rapids_tpu.kernels.groupby import groupby_aggregate
+    n, cap = 900, 1024
+    v = np.arange(cap, dtype=np.int64) - 400
+    ok = np.arange(cap) % 3 != 0
+    fns = [Sum(ColumnRef("l", T.LONG)), Last(ColumnRef("l", T.LONG))]
+    batch = ColumnBatch(T.Schema([]), [], jnp.asarray(n, jnp.int32), cap)
+
+    def run(batch, val):
+        return groupby_aggregate(
+            batch, [], [val, val], fns, False, T.Schema([]),
+            [[b.dtype for b in fn.buffers()] for fn in fns], T.Schema([]))
+
+    val = DevVal(T.LONG, jnp.asarray(v), jnp.asarray(ok))
+    keys, bufs = jax.jit(run)(batch, val)
+    assert keys.capacity == MIN_CAPACITY and int(keys.num_rows) == 1
+    assert not keys.columns
+    assert all(b.data.shape == (MIN_CAPACITY,) for bs in bufs for b in bs)
+    assert int(bufs[0][0].data[0]) == int(v[:n][ok[:n]].sum())
+    assert int(bufs[1][0].data[0]) == int(v[n - 1])      # input order kept
+    text = jax.jit(run).lower(batch, val).as_text()
+    assert "stablehlo.sort" not in text
+
+
+def test_keyless_nan_batch_raises_the_flag_and_takes_the_exact_path():
+    data = _data(n=1500, with_nan=True)
+
+    def q(s):
+        return s.create_dataframe(data, num_partitions=2).agg(
+            Column(Alias(Sum(ColumnRef("f")), "sf")),
+            Column(Alias(Count(ColumnRef("f")), "cf")),
+            Column(Alias(Sum(ColumnRef("v")), "sv")))
+
+    tpu, cpu = tpu_session(**FLOAT_AGG), cpu_session(**FLOAT_AGG)
+    (t,), (c,) = q(tpu).collect(), q(cpu).collect()
+    assert t[0] != t[0] and c[0] != c[0]          # NaN, as the CPU oracle
+    assert t[1:] == c[1:]
+    assert any(isinstance(ms, dict) and "hashAggFallback" in ms
+               for ms in tpu.last_metrics.values()), tpu.last_metrics
+    assert _flat(tpu, "keylessAggBatches") == 0
+    assert _flat(tpu, "keylessUpdateBatches") > 0

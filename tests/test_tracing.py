@@ -56,14 +56,76 @@ def test_stage_program_module_name_and_scopes(monkeypatch):
     for name in stage:
         assert re.search(rf"module @jit_{name}\b", texts[name])
     assert not any(n == "run" for n in texts)   # no more jit_run(<hash>)
+    # no grouping key: the update runs the reduction, under its own scope
+    assert not any("k.hashagg.hash_group_aggregate" in t
+                   for t in texts.values())
     update = next(t for t in texts.values()
-                  if "k.hashagg.hash_group_aggregate" in t)
+                  if "k.hashagg.keyless_aggregate" in t)
     # the planner applies Q6's filter inside the update aggregate: its
     # scope is the aggregate's, <Class>.<pre-order position>
     assert re.search(r"TpuHashAggregateExec\.\d+/", update)
     assert "e.Multiply" in update or "e.And" in update   # expression scopes
     assert not re.search(r"@[0-9a-f]{6,}|0x[0-9a-f]{6,}", " ".join(
         re.findall(r'loc\("([^"]*)"', update)))
+
+
+def _widest_dimension(text):
+    """The largest dimension of any tensor type in a lowered module."""
+    return max((int(d) for dims in re.findall(r"tensor<((?:\d+x)+)", text)
+                for d in dims.split("x") if d), default=0)
+
+
+def test_keyless_aggregate_lowers_without_contraction_or_sort(monkeypatch,
+                                                              tmp_path):
+    """A q6-shaped plan: the update program reduces (no one-hot
+    ``dot_general``), the merge tail sorts nothing and holds nothing wider
+    than its 64 concatenated rows.  With a grouping key the update program
+    keeps its contraction against ``table + 2`` slots."""
+    from spark_rapids_tpu.kernels.hashagg import TABLE_SLOTS
+    n_batches = 6
+
+    path = str(tmp_path / "kl.parquet")
+    tpu_session().create_dataframe({
+        "kl_price": [float(100 + i % 50) for i in range(3000)],
+        "kl_discount": [0.01 * (i % 10) for i in range(3000)]}
+    ).write_parquet(path)
+
+    def keyless(s):     # six batches of 500 rows, as SF1's six of a million
+        return (s.read.parquet(path).filter(F.col("kl_discount") >= 0.05)
+                .agg(F.sum(F.col("kl_price") * F.col("kl_discount"))
+                     .alias("kl_sum"))
+                .select((F.col("kl_sum") * 1.0).alias("revenue")))
+
+    s, texts = lowered_stage_texts(
+        monkeypatch, keyless, **FLOAT_AGG,
+        **{"spark.rapids.sql.reader.batchSizeRows": 3000 // n_batches})
+    assert s.last_metrics["keylessAggBatches"] == n_batches
+    assert s.last_metrics["mxuAggBatches"] == 0
+    stages = {n: t for n, t in texts.items() if n.startswith("stage_")}
+    update = next(t for t in stages.values()
+                  if "k.hashagg.keyless_aggregate" in t)
+    assert "dot_general" not in update and "stablehlo.sort" not in update
+    tails = [t for t in stages.values() if t is not update]
+    assert len(tails) == 1, list(texts)
+    (tail,) = tails
+    assert "k.groupby.groupby_aggregate" in tail
+    assert "stablehlo.sort" not in tail and "dot_general" not in tail
+    assert 0 < _widest_dimension(tail) <= 64, _widest_dimension(tail)
+
+    with monkeypatch.context() as m:
+        s2, keyed = lowered_stage_texts(
+            m, lambda s: s.create_dataframe({
+                "kd_k": [i % 7 for i in range(3000)],
+                "kd_v": [float(i) for i in range(3000)]})
+            .group_by("kd_k").agg(F.sum(F.col("kd_v")).alias("sv")),
+            **FLOAT_AGG)
+    assert s2.last_metrics["mxuAggBatches"] > 0
+    assert s2.last_metrics["keylessAggBatches"] == 0
+    update = next(t for t in keyed.values()
+                  if "k.hashagg.hash_group_aggregate" in t)
+    assert "k.hashagg.keyless_aggregate" not in update
+    contraction = re.search(r"stablehlo\.dot_general.*", update)
+    assert contraction and f"x{TABLE_SLOTS + 2}xf32>" in contraction.group(0)
 
 
 def test_filter_operator_has_its_own_scope(monkeypatch):
@@ -606,7 +668,8 @@ def test_benchmark_json_gained_only_the_seven_entries():
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
     # PR 26's seven, in place; PR 28 appended four behind them, each listing
-    # the one cell whose counters it reads
+    # the one cell whose counters it reads; PR 29 one more, in every cell
+    # that reports ``rows_per_s``
     first = names.index("plan_ms")
     assert names[first:first + 7] == [
         "plan_ms", "device_wait_ms", "host_ms_per_query", "jax_trace_s",
@@ -616,7 +679,8 @@ def test_benchmark_json_gained_only_the_seven_entries():
                           "moves"}
         assert m["source"] == "program_counter"
     assert names[first + 7:] == ["shape_hit_pct", "parse_ms", "bind_ms",
-                                 "setup_variant_compiles"]
+                                 "setup_variant_compiles",
+                                 "keyless_reduce_pct"]
     for m in bench["per_layer"][first:]:
         assert m.get("workloads", ["tpch_sf1_qgen.q6_text"]) == \
             ["tpch_sf1_qgen.q6_text"]
