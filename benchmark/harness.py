@@ -57,6 +57,8 @@ import time
 import traceback
 from typing import Dict, List, Optional
 
+import numpy as np
+
 import checks
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -67,6 +69,9 @@ TRACE_DIR = os.path.join(HERE, "trace_tmp")
 #: of six seeds (and a few traced ones) in one checkout, and the second set
 #: and the other cells of the same configuration find the first's files
 KEEP_DATASETS = 8
+#: the window's latency percentiles the result line's ``info`` carries beside
+#: min, median and max (``tools/spread.py`` reads them; no metric reads ``info``)
+LATENCY_QUANTILES = (1, 5, 10, 25, 75, 95, 99)
 
 
 def _module(path: str, name: str):
@@ -407,8 +412,12 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     result["info"] = {
         "workload": cell_name, "seed": seed, "rehearsal": rehearsal,
         "rows": rows, "window_s": window_s, "queries": attempted,
-        "latency_s": {"min": lat[0], "median": lat[len(lat) // 2],
-                      "max": lat[-1]} if lat else None,
+        "latency_s": dict(
+            {"min": lat[0], "median": lat[len(lat) // 2], "max": lat[-1],
+             "count": len(lat)},
+            **{f"p{q}": float(v) for q, v in zip(
+                LATENCY_QUANTILES, np.percentile(lat, LATENCY_QUANTILES))})
+        if lat else None,
         "setup_s": setup["setup_s"], "first_query_s": setup["first_query_s"],
         "setup_parts_s": {"to_data": t_data - t_start,
                           "data": t_session - t_data,
