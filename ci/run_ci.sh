@@ -46,100 +46,6 @@ fi
 echo "== full test suite"
 python -m pytest tests/ -q
 
-echo "== bench smoke (tiny rows, CPU backend): JSON must parse and carry"
-echo "   the data-plane fields (donated_bytes / h2d_gb_per_sec / ...)"
-BENCH_ROWS=4096 BENCH_PARTS=1 BENCH_PLATFORM=cpu \
-BENCH_REPIN=1 python - << 'PY'
-import json
-import subprocess
-import sys
-
-out = subprocess.run([sys.executable, "bench.py"], capture_output=True,
-                     text=True, timeout=600)
-assert out.returncode == 0, f"bench.py failed:\n{out.stderr[-3000:]}"
-lines = [ln for ln in out.stdout.splitlines() if ln.startswith("{")]
-assert lines, f"no JSON line in bench output:\n{out.stdout[-2000:]}"
-j = json.loads(lines[-1])
-for key in ("value", "donated_bytes", "h2d_gb_per_sec", "d2h_gb_per_sec",
-            "shuffle_gb_per_sec", "shuffle_split_dispatches",
-            "shuffle_syncs", "async_partitions", "dispatch_count",
-            "retry_count", "device_lost_count", "partition_fallbacks",
-            "faults_injected", "spill_gb_per_sec", "spill_sync_gb_per_sec",
-            "spill_async_speedup", "spill_queue_depth_max",
-            "aqe_rows_per_sec", "aqe_speedup", "aqe_parity",
-            "aqe_coalesced_partitions", "aqe_broadcast_switches",
-            "aqe_skew_splits", "aqe_estimate_error_pct",
-            "obs_event_count", "obs_overhead_pct",
-            "serve_queries_per_sec", "serve_p50_ms", "serve_p99_ms",
-            "serve_batched_queries", "serve_vs_serial", "serve_parity",
-            "serve_second_session_compiles", "serve_tenants",
-            "scan_gb_per_sec", "scan_decode_gb_per_sec",
-            "scan_h2d_overlap_pct", "scan_chunks_skipped",
-            "scan_v2_vs_v1", "readahead_depth_effective",
-            "shuffle_wire_gb_per_sec", "shuffle_encoded_bytes_saved",
-            "mesh_rows_per_sec_by_devices",
-            "mesh_spmd_vs_hostdriven", "mesh_backend",
-            "mesh_join_fused", "mesh_join_rows_per_sec_by_devices",
-            "mesh_fallback_count",
-            "pallas_kernels_enabled", "pallas_speedup_by_kernel",
-            "pallas_fallback_count",
-            "history_warm_speedup", "fragment_cache_hits",
-            "telemetry_overhead_pct", "critpath_top_site",
-            "regression_alerts",
-            "frontend_queries_per_sec", "frontend_p50_ms",
-            "frontend_p99_ms", "frontend_vs_serial", "frontend_parity",
-            "frontend_second_client_compiles", "result_cache_hits",
-            "admission_shed"):
-    assert key in j, f"bench JSON missing {key}: {sorted(j)}"
-assert isinstance(j["critpath_top_site"], str) and j["critpath_top_site"], j
-assert isinstance(j["telemetry_overhead_pct"], float), j
-assert isinstance(j["regression_alerts"], int) and \
-    j["regression_alerts"] >= 0, j
-assert j["value"] > 0, j
-assert j["scan_gb_per_sec"] > 0, j
-assert j["shuffle_encoded_bytes_saved"] >= 0, j
-assert j["readahead_depth_effective"] >= 1, j
-assert j["spill_gb_per_sec"] > 0, j
-assert j["aqe_parity"] is True, j
-assert j["aqe_coalesced_partitions"] > 0, j
-assert j["serve_parity"] is True, j
-assert j["serve_batched_queries"] > 0, j
-assert j["serve_second_session_compiles"] == 0, j
-assert j["frontend_parity"] is True, j
-assert j["frontend_second_client_compiles"] == 0, j
-assert j["result_cache_hits"] > 0, j
-assert float(j["frontend_queries_per_sec"]) > 0, j
-assert isinstance(j["mesh_rows_per_sec_by_devices"], dict), j
-# fused-join lane gates: the shuffled hash join must actually compile
-# into the fused program, with zero overflow/compat fallbacks at the
-# default growth factor
-assert j["mesh_join_fused"] >= 1, j
-assert isinstance(j["mesh_join_rows_per_sec_by_devices"], dict), j
-assert j["mesh_fallback_count"] == 0, j
-assert j["fragment_cache_hits"] > 0, j
-assert j["history_warm_speedup"] > 0, j
-# pallas kernel-tier lane gates: only the kernel the v5e compiler
-# accepts is conf-enabled by default (tests/test_chip_compile.py),
-# every kernel measured, and on a non-TPU backend the default-conf
-# probe must pay (and count) its backend fallback
-assert sorted(j["pallas_kernels_enabled"]) == ["strings"], j
-assert isinstance(j["pallas_speedup_by_kernel"], dict) and \
-    sorted(j["pallas_speedup_by_kernel"]) == [
-        "gatherScatter", "joinProbe", "stringHash", "strings"], j
-assert all(v > 0 for v in j["pallas_speedup_by_kernel"].values()), j
-assert j["pallas_fallback_count"] >= 1, j
-# fused-vs-host-driven ratio is recorded, NOT gated: CPU virtual devices
-# emulate ICI through host collectives, so the ratio is informational
-print("mesh spmd vs host-driven (informational):",
-      j["mesh_spmd_vs_hostdriven"], "backend:", j["mesh_backend"],
-      "curve:", j["mesh_rows_per_sec_by_devices"])
-print("bench smoke ok:", {k: j[k] for k in (
-    "value", "donated_bytes", "h2d_gb_per_sec", "d2h_gb_per_sec",
-    "shuffle_gb_per_sec", "shuffle_split_dispatches", "shuffle_syncs",
-    "async_partitions", "retry_count", "device_lost_count",
-    "spill_gb_per_sec", "spill_sync_gb_per_sec")})
-PY
-
 echo "== serve smoke: rapidsserve with 2 weighted tenants and a per-query"
 echo "   dispatch:oom@2 fault — every served query must recover with"
 echo "   correct rows, latencies parseable, per-tenant counts consistent"
@@ -281,9 +187,8 @@ finally:
 assert proc.returncode == 0, (proc.returncode, proc.stderr.read()[-3000:])
 PY
 
-echo "== obs smoke: event log -> rapidsprof report + Perfetto-loadable trace"
+echo "== obs smoke: event log -> rapidsprof report"
 python - << 'PY'
-import json
 import os
 import subprocess
 import sys
@@ -306,19 +211,14 @@ assert s.query_history(), "no profile recorded"
 logs = [os.path.join(log_dir, f) for f in os.listdir(log_dir)]
 assert len(logs) == 1, logs
 
-trace = os.path.join(log_dir, "trace.json")
 out = subprocess.run(
-    [sys.executable, "tools/rapidsprof.py", logs[0], "--chrome", trace],
+    [sys.executable, "tools/rapidsprof.py", logs[0]],
     capture_output=True, text=True, timeout=300)
 assert out.returncode == 0, f"rapidsprof failed:\n{out.stderr[-2000:]}"
 assert "Exec" in out.stdout, f"report names no operator:\n{out.stdout}"
-with open(trace) as f:
-    tdoc = json.load(f)
-assert tdoc["traceEvents"], "empty Chrome trace"
 print("obs smoke ok:", {
     "events": s.last_metrics["obsEventCount"],
-    "dropped": s.last_metrics["obsEventsDropped"],
-    "trace_events": len(tdoc["traceEvents"])})
+    "dropped": s.last_metrics["obsEventsDropped"]})
 PY
 
 echo "== telemetry smoke: flushed JSONL -> rapidstop --once renders >=1"

@@ -428,24 +428,21 @@ class ConfRegistrySyncRule(ProjectRule):
                     "remove it", self.severity)
 
 
-_CAMEL_RE = re.compile(r"^[a-z][a-z0-9]*(?:[A-Z][a-zA-Z0-9]*)+$")
 _DOC_TOKEN_RE = re.compile(r"^\|\s*`([A-Za-z_][A-Za-z0-9_.]*)`")
 
 
 class MetricsKeySyncRule(ProjectRule):
-    """R8: ``session.last_metrics`` keys, bench JSON fields and
-    ``docs/metrics.md`` agree.
+    """R8: ``session.last_metrics`` keys and ``docs/metrics.md`` agree.
 
     Source of truth is the set of keys session.execute assigns into
-    ``last_metrics``.  bench.py may only read camelCase keys from that
-    set; docs/metrics.md must table every session key and every bench
-    JSON field, and must not document keys that don't exist.
+    ``last_metrics``: docs/metrics.md must table every one of them and
+    must not document keys that don't exist.
     """
 
     id = "R8"
     name = "metrics-key-sync"
-    description = ("session.last_metrics keys / bench JSON fields / "
-                   "docs/metrics.md out of sync")
+    description = ("session.last_metrics keys / docs/metrics.md out of "
+                   "sync")
 
     DOC = "docs/metrics.md"
 
@@ -453,7 +450,6 @@ class MetricsKeySyncRule(ProjectRule):
                       repo_root: str) -> Iterator[Finding]:
         by_path = {sf.path: sf for sf in files}
         session = by_path.get("spark_rapids_tpu/session.py")
-        bench = by_path.get("bench.py")
         if session is None:
             return
 
@@ -468,47 +464,13 @@ class MetricsKeySyncRule(ProjectRule):
                         if k is not None:
                             session_keys[k] = node.lineno
 
-        bench_reads: Dict[str, int] = {}
-        bench_fields: Dict[str, int] = {}
-        if bench is not None:
-            for node in ast.walk(bench.tree):
-                if isinstance(node, ast.Call) and \
-                        isinstance(node.func, ast.Attribute) and \
-                        node.func.attr == "get" and node.args:
-                    k = str_const(node.args[0])
-                    if k and _CAMEL_RE.match(k):
-                        bench_reads[k] = node.lineno
-                elif isinstance(node, ast.Subscript):
-                    k = str_const(node.slice)
-                    if k and _CAMEL_RE.match(k):
-                        bench_reads[k] = node.lineno
-                elif isinstance(node, ast.Dict):
-                    keys = [str_const(k) for k in node.keys
-                            if k is not None]
-                    keyset = {k for k in keys if k}
-                    # the econ dict and the benchmark record dict are the
-                    # two shipped JSON surfaces
-                    if "compile_s" in keyset or "vs_baseline" in keyset:
-                        for kn in node.keys:
-                            k = str_const(kn) if kn is not None else None
-                            if k:
-                                bench_fields[k] = kn.lineno
-
-        for k, line in sorted(bench_reads.items()):
-            if k not in session_keys:
-                yield Finding(
-                    self.id, "bench.py", line,
-                    f"bench reads session metric `{k}` which "
-                    "session.execute never sets — it silently reads the "
-                    "default forever", self.severity)
-
         doc_path = os.path.join(repo_root, self.DOC)
         if not os.path.exists(doc_path):
             yield Finding(
                 self.id, self.DOC, 0,
                 f"{self.DOC} is missing: the metrics contract "
-                "(session.last_metrics keys + bench JSON fields) must "
-                "be documented there", self.severity)
+                "(session.last_metrics keys) must be documented there",
+                self.severity)
             return
         with open(doc_path, encoding="utf-8") as f:
             doc_lines = f.read().splitlines()
@@ -524,19 +486,12 @@ class MetricsKeySyncRule(ProjectRule):
                     self.id, "spark_rapids_tpu/session.py", line,
                     f"session.last_metrics key `{k}` is undocumented in "
                     f"{self.DOC}", self.severity)
-        for k, line in sorted(bench_fields.items()):
-            if k not in doc_tokens:
-                yield Finding(
-                    self.id, "bench.py", line,
-                    f"bench JSON field `{k}` is undocumented in "
-                    f"{self.DOC}", self.severity)
-        known = set(session_keys) | set(bench_fields)
         for k, line in sorted(doc_tokens.items()):
-            if k not in known:
+            if k not in session_keys:
                 yield Finding(
                     self.id, self.DOC, line,
-                    f"{self.DOC} documents `{k}` but neither "
-                    "session.last_metrics nor bench.py produces it",
+                    f"{self.DOC} documents `{k}` but "
+                    "session.last_metrics never carries it",
                     self.severity)
 
 

@@ -476,7 +476,7 @@ def device_to_host_many(batches: Sequence[ColumnBatch],
     # every leaf's copy is started with copy_to_host_async (as
     # jax.device_get does) before anything blocks, so the whole pytree
     # rides a single sync.  Per-column gets serialize one round trip
-    # each, which dominated query wall time (see profile_bench.py).
+    # each, which dominated query wall time.
     # The wait is split by cause: ``device_wait`` ends when the programs
     # that produce the buffers have finished (block_until_ready — it
     # would block there anyway), ``d2h`` times what is left of the copy.

@@ -450,16 +450,6 @@ COALESCE_TARGET_ROWS = conf_int(
 UDF_COMPILER_ENABLED = conf_bool(
     "spark.rapids.sql.udfCompiler.enabled", False,
     "Compile python row UDFs into columnar expressions when possible.")
-PIPELINE_ENABLED = conf_bool(
-    "spark.rapids.sql.tpu.pipeline.enabled", True,
-    "Run all-TPU plan subtrees as whole-pipeline XLA programs (the "
-    "whole-stage-codegen analogue): O(1) dispatched programs per query "
-    "stage instead of one per operator per batch.")
-FUSION_ENABLED = conf_bool(
-    "spark.rapids.sql.fusion.enabled", True,
-    "Collapse chains of per-batch map operators (project/filter) into one "
-    "compiled program and absorb them into aggregate/sort/exchange "
-    "consumers (dispatch-count optimizer).")
 EXCHANGE_COLLAPSE_LOCAL = conf_bool(
     "spark.rapids.sql.tpu.exchange.collapseLocal", True,
     "Collapse shuffle exchanges to a single logical partition in "
@@ -499,29 +489,6 @@ JOIN_DICT_KEYS_ENABLED = conf_bool(
     "Divergent dictionaries whose entry-pair table would exceed ~4M "
     "cells skip translation and hash entry content through the codes "
     "instead (still encoded, no materialization).")
-PIPELINE_FUSE_TAIL = conf_bool(
-    "spark.rapids.sql.tpu.pipeline.fuseTail.enabled", True,
-    "Fuse the stage-break re-bucketing gather into the consuming (tail) "
-    "stage program: the final merge-aggregate/sort/limit tail then runs "
-    "in one jitted dispatch instead of shrink + tail (lower dispatchCount "
-    "per query; the tail program is cached per shrunk-bucket signature).")
-PIPELINE_ASYNC_PARTITIONS = conf_bool(
-    "spark.rapids.sql.tpu.pipeline.asyncPartitions.enabled", True,
-    "Dispatch every pipeline source's stage program (and every collected "
-    "partition's work) before taking any blocking host sync, then batch "
-    "the stage-break size syncs and the final device->host copy into one "
-    "round trip each.  Off restores the sequential "
-    "dispatch/sync-per-source order.")
-DONATION_ENABLED = conf_bool(
-    "spark.rapids.sql.tpu.donation.enabled", True,
-    "Donate consumed input buffers to the stage programs and stage-break "
-    "shrink gathers (jax donate_argnums): XLA reuses the input HBM for "
-    "outputs instead of holding input + output live across the dispatch. "
-    "Only buffers the engine provably never touches again are donated "
-    "(fresh host->device stagings and stage-break intermediates — never "
-    "cached or spill-catalog batches); a donated dispatch that hits a "
-    "device OOM fails fast instead of spill-retrying, since its inputs "
-    "are already consumed.")
 PIPELINE_SHRINK_BYTES = conf_bytes(
     "spark.rapids.sql.tpu.pipeline.shrinkBytes", 4 << 20,
     "Padded stage outputs at or below this byte total skip the sizes "
@@ -531,7 +498,7 @@ COMPILE_CACHE_DIR = conf_str(
     "Directory for JAX's persistent XLA compilation cache.  When set, "
     "compiled executables survive the process so re-runs (and "
     "session.prewarm()) skip recompilation; empty leaves persistence "
-    "to the entry point (bench.py, chip_smoke.py and the tests use "
+    "to the entry point (benchmark/run.py, chip_smoke.py and the tests use "
     "<checkout>/.jax_cache).  Ignored, with one log line, where the "
     "JAX_COMPILATION_CACHE_DIR environment variable is set: the "
     "operator has placed the cache from outside.")
@@ -605,12 +572,6 @@ SORT_STRING_PREFIX_BYTES = conf_int(
     "Bytes of each string sort key encoded into u32 comparison words "
     "(kernels.sortkeys): order beyond the prefix is approximate "
     "(documented incompat), larger values cost sort bandwidth.")
-METRICS_DETAIL = conf_bool(
-    "spark.rapids.sql.tpu.metrics.detailEnabled", False,
-    "Accurate device-time metrics: block on dispatched outputs so "
-    "deviceTimeNs/shuffleWallNs measure real device execution instead of "
-    "async-dispatch lower bounds.  Costs a host sync per dispatch (kills "
-    "async overlap) — leave off outside measurement runs.")
 OBS_ENABLED = conf_bool(
     "spark.rapids.sql.tpu.obs.enabled", True,
     "Observability event bus (obs.events): instrumentation chokepoints "

@@ -52,7 +52,6 @@ AGG_MAX_RUNS = 32
 #: what the regression sentinel must see compared against the same
 #: fingerprint's clean baseline, not forked into a separate one).
 _SIG_EXCLUDE_PREFIXES = (
-    "spark.rapids.sql.tpu.metrics.",
     "spark.rapids.sql.tpu.obs.",
     "spark.rapids.sql.tpu.history.",
     "spark.rapids.sql.tpu.sentinel.",

@@ -1,5 +1,5 @@
 """Observability subsystem: query event bus, per-operator profiles,
-Chrome-trace/JSONL export, and the ``tools/rapidsprof.py`` analysis CLI.
+JSONL event-log export, and the ``tools/rapidsprof.py`` analysis CLI.
 
 The package is deliberately engine-free (stdlib only, relative imports)
 so ``rapidsprof`` can load it standalone the way ``rapidslint`` loads

@@ -1,16 +1,11 @@
-"""Event-log and Chrome-trace export.
+"""Event-log export.
 
-Two output shapes for one event stream:
-
-* **JSONL event log** (the Spark event-log analogue, conf
-  ``spark.rapids.sql.tpu.obs.eventLogDir``): one ``{"type": "query"}``
-  header line per query followed by its ``{"type": "event"}`` lines —
-  append-only, so one file accumulates a session's queries and
-  ``tools/rapidsprof.py`` post-processes it offline.
-* **Chrome ``trace_event`` JSON** (Perfetto/chrome://tracing loadable):
-  spans as complete ``"X"`` events, instants as ``"i"``, one track per
-  (site, thread) pair named via ``"M"`` thread-name metadata, sorted by
-  timestamp.
+The **JSONL event log** (the Spark event-log analogue, conf
+``spark.rapids.sql.tpu.obs.eventLogDir``): one ``{"type": "query"}``
+header line per query followed by its ``{"type": "event"}`` lines —
+append-only, so one file accumulates a session's queries and
+``tools/rapidsprof.py`` post-processes it offline.  (A timeline next to
+the device's operations is the profiler's: ``rapidsprof --xplane``.)
 
 Engine-free (stdlib only) and duck-typed over events — Event objects
 in-process, dicts after a JSONL round-trip.
@@ -20,9 +15,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, Iterable, List, Tuple
-
-from .events import SPAN, field
+from typing import Any, Dict, Iterable, List
 
 
 def _event_dict(ev) -> Dict[str, Any]:
@@ -30,63 +23,6 @@ def _event_dict(ev) -> Dict[str, Any]:
         return ev
     return ev.to_dict()
 
-
-# -- chrome trace -------------------------------------------------------------
-
-def events_to_chrome(events: Iterable) -> Dict[str, Any]:
-    """Build a Chrome ``trace_event`` document.  Timestamps convert from
-    monotonic ns to the format's microseconds; tracks (tids) are one per
-    (site, thread) so e.g. the async spill writer's spans never overlap
-    the driver's dispatch spans."""
-    tids: Dict[Tuple[str, str], int] = {}
-    out: List[Dict[str, Any]] = []
-    meta: List[Dict[str, Any]] = []
-
-    def tid_for(site: str, thread: str) -> int:
-        key = (site, thread)
-        tid = tids.get(key)
-        if tid is None:
-            tid = tids[key] = len(tids) + 1
-            meta.append({
-                "name": "thread_name", "ph": "M", "pid": 1, "tid": tid,
-                "args": {"name": f"{site}/{thread}"},
-            })
-        return tid
-
-    for ev in events:
-        site = field(ev, "site") or "?"
-        thread = field(ev, "thread") or "?"
-        t0 = int(field(ev, "t0", 0) or 0)
-        t1 = int(field(ev, "t1", 0) or 0)
-        name = field(ev, "name") or site
-        op_id = field(ev, "op_id") or ""
-        args = dict(field(ev, "payload") or {})
-        if op_id:
-            args["op_id"] = op_id
-        rec: Dict[str, Any] = {
-            "name": name, "cat": site, "pid": 1,
-            "tid": tid_for(site, thread), "ts": t0 / 1e3,
-        }
-        if args:
-            rec["args"] = args
-        if field(ev, "kind") == SPAN:
-            rec["ph"] = "X"
-            rec["dur"] = max(0, t1 - t0) / 1e3
-        else:
-            rec["ph"] = "i"
-            rec["s"] = "t"
-        out.append(rec)
-    out.sort(key=lambda r: r["ts"])
-    return {"traceEvents": meta + out, "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(path: str, events: Iterable) -> None:
-    doc = events_to_chrome(events)
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(doc, f)
-
-
-# -- JSONL event log ----------------------------------------------------------
 
 def write_event_log(path: str, query_record: Dict[str, Any],
                     events: Iterable) -> None:

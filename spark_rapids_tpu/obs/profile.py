@@ -195,9 +195,9 @@ def _fmt_rollup(r: Dict[str, Any], ms: Dict[str, Any]) -> str:
         if r["adaptive"]:
             parts.append("adaptive=" + ",".join(
                 f"{k}x{v}" for k, v in sorted(r["adaptive"].items())))
-    # per-op metric dict entries the events don't carry (e.g. an
-    # exchange's shuffleWallNs, AQE stats) ride along from last_metrics
-    for key in ("shuffleWallNs", "aqeCoalescedPartitions", "aqeSkewSplits"):
+    # per-op metric dict entries the events don't carry (AQE stats) ride
+    # along from last_metrics
+    for key in ("aqeCoalescedPartitions", "aqeSkewSplits"):
         v = ms.get(key)
         if v:
             parts.append(f"{key}={v}")

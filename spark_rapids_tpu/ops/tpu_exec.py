@@ -551,10 +551,10 @@ class TpuHashAggregateExec(TpuExec):
         self._run_hash = run_hash
         # the merge input is always a fresh >1-way concat this exec built
         # (never a cached/spill-held batch) and is consumed here: donate
-        # its buffers so concat + merge don't hold two full copies
-        self._merge_run = plan_jit(self._merge_partials,
-                                           label="TpuHashAggregate:merge")
-        self._merge_run_donate = plan_jit(
+        # its buffers so concat + merge don't hold two full copies (a
+        # process that cannot donate gets the jit without the donation:
+        # compile_registry.instrumented_jit)
+        self._merge_run = plan_jit(
             self._merge_partials, label="TpuHashAggregate:merge",
             donate_argnums=(0,))
         self._input_fns = []
@@ -830,7 +830,6 @@ class TpuHashAggregateExec(TpuExec):
             # buffers (no per-batch host sync); the downstream pipeline
             # break right-sizes them in one round trip.
             def gen(part):
-                from spark_rapids_tpu.plan.pipeline import _donation_enabled
                 batches = list(part)
                 partials = self._update_partials(ctx, batches)
                 if not partials:
@@ -839,9 +838,7 @@ class TpuHashAggregateExec(TpuExec):
                     yield partials[0]
                     return
                 merged = _concat_all(partials, self.output_schema)
-                run = self._merge_run_donate if _donation_enabled(ctx) \
-                    else self._merge_run
-                yield run(merged)
+                yield self._merge_run(merged)
 
         return [gen(p) for p in self.children[0].partitions(ctx)]
 

@@ -163,10 +163,6 @@ def run_distributed_query_demo(n_devices: int, n_rows: int = 4000) -> dict:
     tpu = (TpuSparkSession.builder()
            .config("spark.rapids.shuffle.ici.enabled", True)
            .config("spark.rapids.sql.variableFloatAgg.enabled", True)
-           # accurate-sync metrics: shuffleWallNs must measure the real
-           # all_to_all (the demo REPORTS shuffle_gb_per_sec from it; the
-           # default async lower bound would inflate it arbitrarily)
-           .config("spark.rapids.sql.tpu.metrics.detailEnabled", True)
            .config("spark.sql.shuffle.partitions", n_devices)
            .get_or_create())
     got_rows = build(tpu).collect()
@@ -216,14 +212,13 @@ def run_distributed_scale_demo(n_devices: int,
                                n_rows: int = 1_000_000) -> dict:
     """The dryrun's SCALE leg: >=1M rows through the planner-built mesh
     pipeline with a deliberately small spill budget, reporting shuffle
-    bytes moved and GB/s (the reference surfaces the same per-read
-    shuffle accounting, RapidsCachingReader.scala:125-133; spill tiers
-    are the "data > HBM" answer, SURVEY.md section 2.4).
+    bytes moved (the reference surfaces the same per-read shuffle
+    accounting, RapidsCachingReader.scala:125-133; spill tiers are the
+    "data > HBM" answer, SURVEY.md section 2.4).
 
     Asserts the mesh exchange carried >= the live payload of the rows and
     that the spill catalog actually fired.  Returns the stats dict the
-    dryrun prints (shuffle_gb_per_sec is the wall-clock figure on
-    whatever backend runs it — virtual CPU mesh in the driver's dryrun).
+    dryrun prints.
     """
     import jax
     from spark_rapids_tpu import functions as F
@@ -240,10 +235,6 @@ def run_distributed_scale_demo(n_devices: int,
     tpu = (TpuSparkSession.builder()
            .config("spark.rapids.shuffle.ici.enabled", True)
            .config("spark.rapids.sql.variableFloatAgg.enabled", True)
-           # accurate-sync metrics: shuffleWallNs must measure the real
-           # all_to_all (the demo REPORTS shuffle_gb_per_sec from it; the
-           # default async lower bound would inflate it arbitrarily)
-           .config("spark.rapids.sql.tpu.metrics.detailEnabled", True)
            .config("spark.sql.shuffle.partitions", n_devices)
            .get_or_create())
     from spark_rapids_tpu import types as T
@@ -273,18 +264,16 @@ def run_distributed_scale_demo(n_devices: int,
     assert len(rows) == len(np.unique(keys)), \
         (len(rows), len(np.unique(keys)))
 
-    sh_bytes = sh_wall = wire = 0
+    sh_bytes = wire = 0
     for op, ms in tpu.last_metrics.items():
         if op == "memory" or not isinstance(ms, dict):
             continue
         sh_bytes += ms.get("shuffleBytes", 0)
         wire += ms.get("shuffleWireBytes", 0)
-        sh_wall += ms.get("shuffleWallNs", 0)
     # the exchange carries PARTIAL-AGG output (100K distinct keys x agg
     # buffers), not raw rows — still megabytes at this scale
     assert sh_bytes >= 1 << 20, \
         f"mesh shuffle moved only {sh_bytes}B for {n_rows} rows"
-    assert sh_wall > 0
     mem = tpu.last_metrics.get("memory", {})
     spilled = (mem.get("spilled_to_host", 0) - mem0["spilled_to_host"]) \
         + (mem.get("spilled_to_disk", 0) - mem0["spilled_to_disk"])
@@ -292,10 +281,7 @@ def run_distributed_scale_demo(n_devices: int,
     assert spilled > 0, f"spill never fired: {mem} (baseline {mem0})"
     assert unspilled > 0, \
         f"measured run never unspilled: {mem} (baseline {mem0})"
-    gbps = sh_bytes / sh_wall  # bytes/ns == GB/s
     return {"devices": n_devices, "rows": n_rows,
             "shuffle_bytes": int(sh_bytes), "wire_bytes": int(wire),
-            "shuffle_wall_ms": round(sh_wall / 1e6, 1),
-            "shuffle_gb_per_sec": round(gbps, 3),
             "spilled_batches": int(spilled),
             "unspilled_batches": int(unspilled)}

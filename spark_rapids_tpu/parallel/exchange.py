@@ -28,7 +28,7 @@ from spark_rapids_tpu.plan.physical import (
 )
 from spark_rapids_tpu.obs import events as obs_events
 from spark_rapids_tpu.utils.compile_registry import plan_jit
-from spark_rapids_tpu.utils.tracing import device_read, device_wait, span
+from spark_rapids_tpu.utils.tracing import device_read, span
 
 def _range_sample_limit(ctx) -> int:
     from spark_rapids_tpu.config import CPU_RANGE_PARTITIONING_SAMPLE
@@ -390,22 +390,15 @@ class TpuShuffleExchangeExec(TpuExec):
             pid = part.device_partition_ids(merged, d)
             local_batches.append(merged)
             pids_list.append(jnp.asarray(pid, jnp.int32))
-        from spark_rapids_tpu.utils.tracing import metrics_detail
         stats: dict = {}
         with span("exchange", "mesh", self.op_id) as sp:
             out = mesh_exchange_batches(mesh, local_batches, pids_list,
                                         self.output_schema, stats=stats)
-            # No unconditional host sync here: blocking on the all_to_all
-            # kills its async overlap with downstream dispatch (the whole
-            # point of the collective path).  Default shuffleWallNs is
-            # therefore a dispatch-wall LOWER BOUND; the accurate-sync path
-            # rides the metrics-detail conf for measurement runs.
-            if out and metrics_detail(ctx.conf):
-                device_wait("mesh_exchange", out, self.op_id)
-                ctx.metric(self.op_id, "shuffleWallSyncs").add(1)
+            # No host sync here: blocking on the all_to_all kills its
+            # async overlap with downstream dispatch (the whole point of
+            # the collective path), so this span is the enqueue's wall.
             sp.set(bytes=stats.get("payload_bytes", 0), devices=n,
                    bytes_per_device=stats.get("bytes_per_device"))
-        wall_ns = sp.elapsed_ns
         ctx.metric(self.op_id, "meshExchanges").add(1)
         ctx.metric(self.op_id, "meshDevices").add(n)
         # shuffle throughput accounting (RapidsCachingReader.scala:125-133
@@ -414,7 +407,6 @@ class TpuShuffleExchangeExec(TpuExec):
             stats.get("payload_bytes", 0))
         ctx.metric(self.op_id, "shuffleWireBytes").add(
             stats.get("wire_bytes", 0))
-        ctx.metric(self.op_id, "shuffleWallNs").add(wall_ns)
         if stats.get("encoded_materialized"):
             # the encoded-corridor gap at mesh boundaries, measured:
             # dict-encoded columns give up their codes here (the
@@ -515,7 +507,6 @@ class TpuShuffleExchangeExec(TpuExec):
             sp.set(bytes=sum(self._last_part_bytes),
                    rows=sum(self._last_part_rows),
                    pieces=sum(len(p) for p in out), partitions=n)
-        ctx.metric(self.op_id, "shuffleWallNs").add(sp.elapsed_ns)
         # planner-error accounting: the static size estimate the planner
         # used for this exchange's input (stashed by overrides) vs. the
         # actual materialized bytes just recorded — pure host arithmetic

@@ -70,9 +70,7 @@ from spark_rapids_tpu.parallel.mesh_shuffle import (
     DATA_AXIS, _fit_1d, _unshard,
 )
 from spark_rapids_tpu.utils.compile_registry import instrumented_jit
-from spark_rapids_tpu.utils.tracing import (
-    device_dispatch, device_read, span,
-)
+from spark_rapids_tpu.utils.tracing import device_read, span
 
 
 def _is_varlen(f) -> bool:
@@ -245,7 +243,8 @@ def run_mesh_stage(root, ctx, variant: str,
     devices = list(mesh.devices.flat)
     sources, fn = PL._stage_build(root, ctx, variant)
     exchanges, replicated, joins = root._mesh_stage_info[variant]
-    mats = PL._materialize_sources(sources, ctx, fuse=False)
+    mats = PL._materialize_sources(sources, ctx)
+    PL.shrink_materialized(mats, ctx)
 
     sh_rep = NamedSharding(mesh, P())
     flat_globals: List = []
@@ -389,8 +388,7 @@ def run_mesh_stage(root, ctx, variant: str,
         out_schema = root.output_schema
         overflowed = False
         results: List[ColumnBatch] = []
-        with device_dispatch(ctx, "pipeline", root.name,
-                             obs_op=root.op_id) as holder:
+        with span("stage", root.name, root.op_id):
             out_lists, ovf_g = PL._run_oom_guarded(
                 ctx, lambda: program(tuple(flat_globals)), args=(),
                 retryable=True)
@@ -402,7 +400,6 @@ def run_mesh_stage(root, ctx, variant: str,
                 overflowed = bool(device_read(
                     "join_overflow", ovf_g, root.op_id).any())
             if overflowed:
-                holder["outputs"] = []
                 out_lists = []
             # one catalog handle per stacked output global, closed right
             # after unsharding: per-shard HBM accounting without exposing a
@@ -430,8 +427,6 @@ def run_mesh_stage(root, ctx, variant: str,
                         sch, arrs, cap, squeeze=False))
             for h in handles:
                 h.close()
-            if not overflowed:
-                holder["outputs"] = results
         mesh_span.set(devices=n, fused_boundaries=len(exchanges),
                       fused_joins=len(joins),
                       bytes_per_device=bytes_per_device)

@@ -280,9 +280,7 @@ class TpuOverrides:
             explain_sink(self.last_explain)
         phys = self._convert(meta)
         phys = _insert_transitions(phys)
-        from spark_rapids_tpu.config import FUSION_ENABLED
-        if FUSION_ENABLED.get(self.conf):
-            phys = _fuse_map_chains(phys)
+        phys = _fuse_map_chains(phys)
         # last: every planner (session, ml, the recovery's CPU re-lowering)
         # hands out a tree whose op ids are its pre-order positions
         from spark_rapids_tpu.plan.physical import assign_op_ids

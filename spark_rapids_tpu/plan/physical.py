@@ -512,11 +512,6 @@ def _collect_device_bulk(root: PhysicalOp, ctx: ExecContext
                 ctx, sum(1 for b in flat if isinstance(b, ColumnBatch)))
 
 
-def _async_collect_enabled(ctx: ExecContext) -> bool:
-    from spark_rapids_tpu.config import PIPELINE_ASYNC_PARTITIONS
-    return PIPELINE_ASYNC_PARTITIONS.get(ctx.conf)
-
-
 def _history_cached_collect(op: PhysicalOp, ctx: ExecContext
                             ) -> Optional[HostBatch]:
     """Serve the whole collect from the cross-query fragment cache
@@ -578,20 +573,12 @@ def collect_host(op: PhysicalOp, ctx: ExecContext) -> HostBatch:
                 hb = run_pipeline_with_recovery(op, ctx)
             if hb is not None:
                 return hb
-            if _async_collect_enabled(ctx):
-                t0 = time.monotonic()
-                batches = _collect_device_bulk(op, ctx)
-                ctx.metric("collect", "wallTimeNs").add(
-                    int((time.monotonic() - t0) * 1e9))
-                if not batches:
-                    return HostBatch(op.output_schema, [
-                        _empty_host_col(f) for f in op.output_schema.fields
-                    ])
-                return concat_result(batches)
-        root = op if not op.is_tpu else DeviceToHostExec(op)
         t0 = time.monotonic()
-        batches: List[HostBatch] = _drive_partitions(
-            root, ctx, release_partial=False)
+        # a device root that inlines nothing (a join at the root) is
+        # collected in bulk; a root on the host drives its partitions
+        batches: List[HostBatch] = _collect_device_bulk(op, ctx) \
+            if op.is_tpu else _drive_partitions(
+                op, ctx, release_partial=False)
         ctx.metric("collect", "wallTimeNs").add(
             int((time.monotonic() - t0) * 1e9))
         if not batches:

@@ -15,11 +15,13 @@ Stage boundaries ("stage breaks") sit where live rows collapse far below
 capacity (aggregate partials): the driver syncs the live sizes once (one
 round trip), re-buckets the shrunk batches and feeds them to the next stage
 — otherwise padded capacities would snowball through concats and every
-downstream sort would pay O(padded).  With
-``spark.rapids.sql.tpu.pipeline.fuseTail.enabled`` (default) the
-re-bucketing gather is not a separate dispatched program: it compiles INTO
-the consuming tail stage (cached per shrunk-bucket signature), so the
-final merge-aggregate/order-by/limit tail costs one dispatch, not two.
+downstream sort would pay O(padded).  The re-bucketing gather is not a
+separate dispatched program: it compiles INTO the consuming tail stage
+(cached per shrunk-bucket signature), so the final
+merge-aggregate/order-by/limit tail costs one dispatch, not two.  Only a
+consumer that cannot compile it in (a mesh stage's shard_map program,
+:func:`shrink_materialized`) and a directly collected root
+(:func:`_shrink_outputs`) dispatch it alone.
 
 Ops that cannot be inlined (host transitions, joins needing host-visible
 output sizing, samples with host RNG) become pipeline *sources*: their
@@ -27,15 +29,16 @@ iterator path materializes batches that feed the program as arguments.
 
 Data-plane economics (docs/dataplane.md): consumed source batches —
 stage-break intermediates and fresh host->device stagings — are DONATED
-to the stage program (``donate_argnums``), so XLA reuses their HBM for
-outputs instead of holding two full copies; with
-``spark.rapids.sql.tpu.pipeline.asyncPartitions.enabled`` every source's
-program is dispatched before any blocking sync and all stage-break size
-fetches ride one batched round trip.
+to the stage program (``donate_argnums``) wherever the process can donate
+(``compile_registry.donation_supported``), so XLA reuses their HBM for
+outputs instead of holding two full copies; every source's program is
+dispatched before any blocking sync and all stage-break size fetches ride
+one batched round trip.
 
-Every stage program dispatch is counted and device-timed
-(utils/compile_registry + utils/tracing), feeding the per-query
-``dispatchCount`` / ``compileCount`` / ``deviceTimeNs`` metrics.
+Every stage program dispatch is counted (utils/compile_registry: the
+per-query ``dispatchCount`` / ``compileCount``) and runs inside a
+``srt/stage/<root>`` span: the host's wall of the enqueue and of any
+size read-back inside it, never device time.
 """
 
 from __future__ import annotations
@@ -54,9 +57,9 @@ from spark_rapids_tpu.batch import (
 )
 from spark_rapids_tpu.plan.physical import ExecContext, PhysicalOp, TpuExec
 from spark_rapids_tpu.utils.compile_registry import (
-    instrumented_jit, plan_jit,
+    donation_supported, instrumented_jit, plan_jit,
 )
-from spark_rapids_tpu.utils.tracing import device_dispatch, span
+from spark_rapids_tpu.utils.tracing import span
 
 
 def concat_static(batches: List[ColumnBatch], schema: T.Schema
@@ -206,26 +209,6 @@ def _shrink_threshold(ctx: ExecContext) -> int:
     return PIPELINE_SHRINK_BYTES.get(ctx.conf)
 
 
-def _fuse_tail_enabled(ctx: ExecContext) -> bool:
-    from spark_rapids_tpu.config import PIPELINE_FUSE_TAIL
-    return PIPELINE_FUSE_TAIL.get(ctx.conf)
-
-
-def _donation_enabled(ctx: ExecContext) -> bool:
-    from spark_rapids_tpu.config import DONATION_ENABLED
-    from spark_rapids_tpu.utils.compile_registry import donation_supported
-    # donation_supported() guards the fallback where the persistent-cache
-    # bypass could not install and instrumented_jit strips donate_argnums:
-    # the "donating" jits then don't donate, and treating them as donating
-    # here would needlessly disable the OOM spill-retry (retryable=False)
-    return DONATION_ENABLED.get(ctx.conf) and donation_supported()
-
-
-def _async_partitions(ctx: ExecContext) -> bool:
-    from spark_rapids_tpu.config import PIPELINE_ASYNC_PARTITIONS
-    return PIPELINE_ASYNC_PARTITIONS.get(ctx.conf)
-
-
 def _stage_may_rerun(root: PhysicalOp, ctx: ExecContext) -> bool:
     """True when the stage's epilogue may re-dispatch on the SAME
     materialized inputs (hash-agg exact fallback): those inputs must then
@@ -311,10 +294,11 @@ def _shrink_spec(outs: List[ColumnBatch], ctx: ExecContext):
 def _apply_shrink(outs: List[ColumnBatch], spec: tuple, ctx: ExecContext,
                   guard: bool = False) -> List[ColumnBatch]:
     """One compiled gather re-bucketing every batch to ``spec`` (inputs
-    donated when enabled — they are consumed).  ``guard=True`` runs the
-    dispatch under the OOM→spill→retry guard for call sites not already
-    inside one (standalone stage-break shrinks); a donating shrink still
-    fails fast on OOM — its inputs are consumed at dispatch."""
+    donated where the process can donate — they are consumed).
+    ``guard=True`` runs the dispatch under the OOM→spill→retry guard for
+    call sites not already inside one (:func:`shrink_materialized`); a
+    donating shrink still fails fast on OOM — its inputs are consumed at
+    dispatch."""
     caps = tuple(c for c, _ in spec)
     bcapss = tuple(bc for _, bc in spec)
     devs = set()
@@ -336,7 +320,10 @@ def _apply_shrink(outs: List[ColumnBatch], spec: tuple, ctx: ExecContext,
             return _run_oom_guarded(ctx, per_batch, (outs,),
                                     retryable=True)
         return per_batch()
-    jit = _shrink_jit_donate if _donation_enabled(ctx) else _shrink_jit
+    # where the cache bypass could not install, instrumented_jit strips
+    # donate_argnums: calling that jit "donating" would needlessly turn
+    # the OOM spill-retry off (retryable=False)
+    jit = _shrink_jit_donate if donation_supported() else _shrink_jit
     if jit is _shrink_jit_donate:
         leaves = jax.tree_util.tree_leaves(tuple(outs))
         if len({id(leaf) for leaf in leaves}) != len(leaves):
@@ -376,18 +363,17 @@ def _shrink_outputs_sharded(outs: List[ColumnBatch], ctx: ExecContext
         for b, (cap, bcaps) in zip(outs, spec)]
 
 
-def _materialize_sources(sources: List[PhysicalOp], ctx: ExecContext,
-                         fuse: bool) -> List[list]:
+def _materialize_sources(sources: List[PhysicalOp], ctx: ExecContext
+                         ) -> List[list]:
     """Materialize every stage source -> [[batches, shrink_spec,
     donatable], ...].
 
     Dispatch-then-sync: every source's stage program (and iterator path)
     is driven FIRST; the stage-break sizes fetch — the only blocking host
     sync — is then taken for ALL sources in one batched ``host_sizes``
-    round trip (asyncPartitions conf; off = one fetch per source, the old
-    order).  With tail fusion on, stage-break sources return RAW outputs
-    plus the re-bucketing spec the consumer compiles into its own program;
-    with it off the shrink gather is dispatched standalone here.
+    round trip.  A stage-break source returns its RAW outputs plus the
+    re-bucketing spec (None where the padded total is not worth a shrink)
+    for the consumer to compile into its own program.
 
     ``donatable`` marks sources whose batches this stage consumes
     outright: stage-break intermediates and fresh host->device stagings.
@@ -395,33 +381,15 @@ def _materialize_sources(sources: List[PhysicalOp], ctx: ExecContext,
     builds) may be referenced again and must never be donated.
     """
     from spark_rapids_tpu.plan.physical import HostToDeviceExec
-    async_on = _async_partitions(ctx)
     mats: List[list] = []
     pending: List[Tuple[int, List[ColumnBatch]]] = []
-
-    def resolve(i: int, spec: tuple) -> None:
-        if fuse:
-            ctx.metric("pipeline", "fusedShrinks").add(1)
-            mats[i][1] = spec
-        else:
-            ctx.metric("pipeline", "shrinks").add(1)
-            mats[i][0] = _apply_shrink(mats[i][0], spec, ctx, guard=True)
 
     for src in sources:
         if getattr(src, "pipeline_stage_break", False):
             outs = _run_stage(src, ctx, shrink=False)
             mats.append([outs, None, True])
             if _worth_shrinking(outs, ctx):
-                if async_on:
-                    pending.append((len(mats) - 1, outs))
-                else:
-                    # sync-per-source: sizes fetch (and shrink) taken
-                    # right here, before the next source dispatches —
-                    # the old sequential order the conf's off position
-                    # promises to restore
-                    src_sizes = host_sizes(outs)
-                    _record_break_stats(ctx, src_sizes)
-                    resolve(len(mats) - 1, _spec_of(src_sizes))
+                pending.append((len(mats) - 1, outs))
         else:
             batches = []
             for part in src.partitions(ctx):
@@ -439,9 +407,21 @@ def _materialize_sources(sources: List[PhysicalOp], ctx: ExecContext,
         _record_break_stats(ctx, sizes)
         pos = 0
         for i, outs in pending:
-            resolve(i, _spec_of(sizes[pos:pos + len(outs)]))
+            mats[i][1] = _spec_of(sizes[pos:pos + len(outs)])
             pos += len(outs)
     return mats
+
+
+def shrink_materialized(mats: List[list], ctx: ExecContext) -> None:
+    """Dispatch the re-bucketing gather of every stage-break source in
+    ``mats`` alone, in place — for a consumer that cannot compile it into
+    its own program (a mesh stage: its shard_map program takes packed
+    globals, parallel.mesh_spmd)."""
+    for m in mats:
+        if m[1] is not None:
+            ctx.metric("pipeline", "shrinks").add(1)
+            m[0] = _apply_shrink(m[0], m[1], ctx, guard=True)
+            m[1] = None
 
 
 def _stage_build(root: PhysicalOp, ctx: ExecContext, variant: str):
@@ -467,7 +447,7 @@ def _stage_build(root: PhysicalOp, ctx: ExecContext, variant: str):
 
 
 def _stage_program(root: PhysicalOp, ctx: ExecContext, variant: str,
-                   spec: Optional[tuple], dmask: Tuple[bool, ...]):
+                   spec: tuple, dmask: Tuple[bool, ...]):
     """(sources, jitted) for (variant, tail-fusion shrink spec, donation
     mask).
 
@@ -506,7 +486,7 @@ def _stage_program(root: PhysicalOp, ctx: ExecContext, variant: str,
                     ki += 1
             return tuple(args)
 
-        if spec is None or all(s is None for s in spec):
+        if all(s is None for s in spec):
             def run(dargs, kargs):
                 return tuple(fn(assemble(dargs, kargs)))
         else:
@@ -608,14 +588,16 @@ def _run_stage_host(root: PhysicalOp, ctx: ExecContext, variant: str,
                     sources: List[PhysicalOp], shrink: bool,
                     unfused: bool = False) -> List[ColumnBatch]:
     variant_fn = getattr(root, "stage_variant", None)
-    fuse = _fuse_tail_enabled(ctx)
     with span("stage_inputs", root.name):
-        mats = _materialize_sources(sources, ctx, fuse)
+        mats = _materialize_sources(sources, ctx)
     args = tuple(tuple(bs) for bs, _, _ in mats)
-    spec = tuple(sp for _, sp, _ in mats) if fuse else None
+    spec = tuple(sp for _, sp, _ in mats)
+    fused = sum(sp is not None for sp in spec)
+    if fused:
+        ctx.metric("pipeline", "fusedShrinks").add(fused)
     from spark_rapids_tpu.batch import colocate_batches
     args = tuple(tuple(bs) for bs in colocate_batches(args))
-    donate = _donation_enabled(ctx) and not _stage_may_rerun(root, ctx)
+    donate = donation_supported() and not _stage_may_rerun(root, ctx)
     dmask = tuple(bool(donate and d) for _, _, d in mats)
     if any(dmask):
         leaves = jax.tree_util.tree_leaves(
@@ -630,15 +612,14 @@ def _run_stage_host(root: PhysicalOp, ctx: ExecContext, variant: str,
         ctx.metric("pipeline", "programs").add(1)
         dargs = tuple(a for a, m in zip(args, dmask) if m)
         kargs = tuple(a for a, m in zip(args, dmask) if not m)
-        with device_dispatch(ctx, "pipeline", root.name,
-                             obs_op=root.op_id) as holder:
-            outs = _run_oom_guarded(
+        # the jitted calls inside open their own ``enqueue`` spans and
+        # inherit the stage root as their operator
+        with span("stage", root.name, root.op_id):
+            return _run_oom_guarded(
                 ctx,
                 lambda: _shrink_outputs(list(jitted(dargs, kargs)), ctx)
                 if shrink else list(jitted(dargs, kargs)),
                 args, retryable=not any(dmask))
-            holder["outputs"] = outs
-        return outs
 
     outs = dispatch(variant)
     post = getattr(root, "postprocess_stage_outputs", None)
@@ -660,11 +641,9 @@ def _run_stage_host(root: PhysicalOp, ctx: ExecContext, variant: str,
 def pipeline_collect(root: PhysicalOp, ctx: ExecContext
                      ) -> Optional[HostBatch]:
     """Try to run ``root`` as a whole-pipeline program; None if the plan
-    doesn't inline anything (caller falls back to the iterator path)."""
-    from spark_rapids_tpu.config import PIPELINE_ENABLED
+    doesn't inline anything or its root is not on the device (caller
+    falls back to the iterator path)."""
     if not root.is_tpu:
-        return None
-    if not PIPELINE_ENABLED.get(ctx.conf):
         return None
 
     probe = getattr(root, "_pipeline_viable", None)

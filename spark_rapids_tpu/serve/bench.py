@@ -1,5 +1,4 @@
-"""Serving benchmark core: the workload behind ``tools/rapidsserve.py``
-and the ``serve`` lane of ``tools/bench.py``.
+"""Serving benchmark core: the workload behind ``tools/rapidsserve.py``.
 
 The lane answers the serving runtime's three headline claims with one
 deterministic template workload (filter+project over per-request row
@@ -196,8 +195,8 @@ def frontend_demo_session(tenants: Optional[Dict[str, float]] = None,
                           history_dir: str = "", rows: int = 4096,
                           conf=None):
     """A session with the deterministic front-door demo view registered
-    — shared by this lane, ``rapidsserve --server`` and the CI smoke so
-    every client speaks the same schema."""
+    — shared by ``rapidsserve --server``, the CI smoke and
+    ``tests/test_frontend.py`` so every client speaks the same schema."""
     from spark_rapids_tpu.dataframe import DataFrame
     from spark_rapids_tpu.plan.logical import InMemoryScan
     from spark_rapids_tpu.session import TpuSparkSession
@@ -220,151 +219,3 @@ def frontend_demo_session(tenants: Optional[Dict[str, float]] = None,
     session.register_view(FRONTEND_VIEW, DataFrame(
         InMemoryScan(parts, parts[0].schema, num_partitions=2), session))
     return session
-
-
-def run_frontend_bench(queries: int = 24, rows: int = 4096,
-                       tenants: Optional[Dict[str, float]] = None,
-                       max_concurrency: int = 2,
-                       conf=None) -> Dict[str, Any]:
-    """The network lane: the demo workload through a real TCP front
-    door (serve/frontend.py), client threads on real sockets.  Covers
-    the PR-16 headline claims: socket results bit-identical to
-    in-process, a second client connection compiling nothing, a warm
-    repeat answering from the result cache with zero dispatches, and a
-    sentinel-predicted deadline miss shed before executing."""
-    import shutil
-    import tempfile
-    import threading
-    from spark_rapids_tpu.serve.frontend import FrontDoorServer
-    from spark_rapids_tpu.serve.protocol import FrontDoorClient
-    from spark_rapids_tpu.serve.resultcache import result_cache
-    from spark_rapids_tpu.serve.scheduler import DeadlineExceeded
-    tenants = tenants or {"a": 2.0, "b": 1.0}
-    tenant_names = sorted(tenants)
-    hist = tempfile.mkdtemp(prefix="rapids-frontend-bench-")
-    try:
-        session = frontend_demo_session(tenants, history_dir=hist,
-                                        rows=rows, conf=conf)
-        expected = {
-            sql: _rows_sorted(session.execute_with_metrics(
-                session.sql(sql).plan)[0])
-            for sql in FRONTEND_SQLS}
-        result_cache().clear()
-        from spark_rapids_tpu.serve.scheduler import ServeScheduler
-        server = FrontDoorServer(session, scheduler=ServeScheduler(
-            session, max_concurrency=max_concurrency))
-        server.start()
-        host, port = "127.0.0.1", server.port
-
-        def submit(client, i, cache=False, deadline=0.0):
-            return client.submit_sql(
-                FRONTEND_SQLS[i % len(FRONTEND_SQLS)],
-                tenant=tenant_names[i % len(tenant_names)],
-                cache=cache, deadline_sec=deadline)
-
-        # warm pass (cache=false): compiles every plan AND appends the
-        # history records the admission predictor needs (>= minRuns per
-        # fingerprint) — a result-cache hit skips execution entirely and
-        # would leave the baseline empty
-        with FrontDoorClient(host, port) as warm_client:
-            for _r in range(3):
-                for i in range(len(FRONTEND_SQLS)):
-                    submit(warm_client, i)
-
-            # serial baseline: one connection, strictly one request in
-            # flight, caching off
-            t0 = time.monotonic()
-            serial_ok = all(
-                _rows_sorted(submit(warm_client, i)[0])
-                == expected[FRONTEND_SQLS[i % len(FRONTEND_SQLS)]]
-                for i in range(queries))
-            serial_wall = time.monotonic() - t0
-
-        # concurrent phase: one client (connection + thread) per tenant,
-        # still caching off — this measures the serving path, not the
-        # result cache
-        lat_ms: List[float] = []
-        lat_lock = threading.Lock()
-        errors: List[str] = []
-
-        def worker(t_idx: int):
-            try:
-                with FrontDoorClient(host, port) as c:
-                    for i in range(t_idx, queries, len(tenant_names)):
-                        q0 = time.monotonic()
-                        out, _m = submit(c, i)
-                        ms = (time.monotonic() - q0) * 1e3
-                        ok = _rows_sorted(out) == \
-                            expected[FRONTEND_SQLS[i % len(FRONTEND_SQLS)]]
-                        with lat_lock:
-                            lat_ms.append(ms)
-                            if not ok:
-                                errors.append(f"parity:{i}")
-            except Exception as e:
-                with lat_lock:
-                    errors.append(f"{type(e).__name__}: {e}")
-
-        threads = [threading.Thread(target=worker, args=(t_idx,))
-                   for t_idx in range(len(tenant_names))]
-        t0 = time.monotonic()
-        for t in threads:
-            t.start()
-        for t in threads:
-            while t.is_alive():
-                t.join(0.25)
-        wall = time.monotonic() - t0
-        lat_ms.sort()
-
-        # second client connection, caching off: the shared plan cache
-        # is process-wide behind the front door, so it compiles nothing
-        with FrontDoorClient(host, port) as c2:
-            _out, m2 = submit(c2, 0)
-            second_compiles = int(m2.get("compileCount", -1))
-
-            # warm repeat through the result cache: first cache=true
-            # submission executes and inserts, the repeat answers with
-            # zero compiles and zero dispatches
-            submit(c2, 0, cache=True)
-            _hit, mh = submit(c2, 0, cache=True)
-            cache_hit_dispatches = int(mh.get("dispatchCount", -1))
-
-            # intentionally doomed: the admission predictor's baseline
-            # says this fingerprint takes ms, the deadline allows 1us
-            shed = 0
-            try:
-                submit(c2, 1, deadline=1e-6)
-            except DeadlineExceeded:
-                shed = 1
-            fstats = c2.stats()["frontend"]
-            d = c2.drain()
-        server.close()
-
-        parity = serial_ok and not errors
-        return {
-            "frontend_queries": queries,
-            "frontend_wall_s": round(wall, 4),
-            "frontend_serial_wall_s": round(serial_wall, 4),
-            "frontend_queries_per_sec":
-                round(queries / wall, 2) if wall else 0.0,
-            "frontend_vs_serial":
-                round(serial_wall / wall, 3) if wall else 0.0,
-            "frontend_p50_ms": round(_percentile_ms(lat_ms, 0.50), 3),
-            "frontend_p99_ms": round(_percentile_ms(lat_ms, 0.99), 3),
-            "frontend_parity": bool(parity),
-            "frontend_second_client_compiles": second_compiles,
-            "frontend_cache_hit_dispatches": cache_hit_dispatches,
-            "result_cache_hits": int(fstats.get("result_cache_hits", 0)),
-            "admission_shed": int(fstats.get("admission_shed", 0))
-                if shed else 0,
-            "frontend_drained": bool(d["drained"]),
-            "frontend_held_depth": int(d["held_depth"]),
-        }
-    finally:
-        shutil.rmtree(hist, ignore_errors=True)
-
-
-def _percentile_ms(sorted_vals: List[float], q: float) -> float:
-    if not sorted_vals:
-        return 0.0
-    idx = min(len(sorted_vals) - 1, int(round(q * (len(sorted_vals) - 1))))
-    return sorted_vals[idx]

@@ -223,14 +223,12 @@ class TpuSparkSession:
                 notes.shaped = (flags, plan_shape(rewritten, lift=lift),
                                 folded)
             _flags, shape, folded = notes.shaped
-        # metrics-detail and obs knobs never change the plan: excluding
-        # them keeps the memo (and therefore every compiled kernel)
-        # hittable when a measurement run toggles accurate device-time
-        # syncing or the observability bus
+        # obs knobs never change the plan: excluding them keeps the memo
+        # (and therefore every compiled kernel) hittable when a
+        # measurement run toggles the observability bus
         conf_state = tuple(sorted(
             (k, str(v)) for k, v in self.conf._settings.items()
-            if not (k.startswith("spark.rapids.sql.tpu.metrics.")
-                    or k.startswith("spark.rapids.sql.tpu.obs."))))
+            if not k.startswith("spark.rapids.sql.tpu.obs.")))
 
         def _build():
             phys = overrides.lower(shape.plan, folded)
@@ -496,12 +494,9 @@ class TpuSparkSession:
         frame.last_metrics["h2dTimeNs"] = d["h2d_ns"]
         frame.last_metrics["d2hBytes"] = d["d2h_bytes"]
         frame.last_metrics["d2hTimeNs"] = d["d2h_ns"]
-        frame.last_metrics["deviceTimeNs"] = sum(
-            ms["deviceTimeNs"].value for ms in ctx.metrics.values()
-            if "deviceTimeNs" in ms)
         # shuffle split economics, summed over every exchange op: split
         # programs dispatched, blocking host syncs paid, catalog pieces
-        # registered, and the bytes/wall the split moved (GB/s derivable)
+        # registered, and the bytes the split moved
         frame.last_metrics["shuffleSplitDispatches"] = sum(
             ms["shuffleSplitDispatches"].value for ms in ctx.metrics.values()
             if "shuffleSplitDispatches" in ms)
@@ -514,9 +509,6 @@ class TpuSparkSession:
         frame.last_metrics["shuffleBytes"] = sum(
             ms["shuffleBytes"].value for ms in ctx.metrics.values()
             if "shuffleBytes" in ms)
-        frame.last_metrics["shuffleWallNs"] = sum(
-            ms["shuffleWallNs"].value for ms in ctx.metrics.values()
-            if "shuffleWallNs" in ms)
         # dict-aware shuffle economics: materialized string bytes the
         # split did NOT move because pieces stayed dictionary-encoded
         # (codes + merged dictionary instead of raw bytes); 0 when the

@@ -14,8 +14,8 @@ round trip.  This module makes both quantities *measured*:
   user actually waits for).
 * The process-wide tallies are snapshotted around each query by
   ``session.execute`` into ``last_metrics`` (``compileCount``,
-  ``compileWallNs``, ``dispatchCount``, ``compiledShapes``) and surfaced
-  by ``bench.py`` as ``compile_s``.
+  ``compileWallNs``, ``dispatchCount``, ``compiledShapes``); the
+  benchmark's ``compile_s`` reads them.
 * :func:`enable_persistent_cache` owns the placement of JAX's persistent
   compilation cache (``JAX_COMPILATION_CACHE_DIR`` wins, else conf
   ``spark.rapids.sql.tpu.compileCacheDir``, else ``<checkout>/.jax_cache``)
@@ -32,8 +32,8 @@ Data-plane accounting rides the same snapshot/delta machinery:
   :func:`guard_check`) raises.
 * :func:`record_transfer` accumulates host<->device staging bytes and
   wall time (``h2d_bytes``/``h2d_ns``/``d2h_bytes``/``d2h_ns``) from the
-  batch staging layer, feeding bench.py's ``h2d_gb_per_sec`` /
-  ``d2h_gb_per_sec``.
+  batch staging layer (``last_metrics``' ``h2dBytes`` / ``h2dTimeNs`` /
+  ``d2hBytes`` / ``d2hTimeNs``).
 
 The compile wall is also split by phase from ``jax.monitoring``'s
 duration events, routed by exact event name (jax 0.9.0), per query scope

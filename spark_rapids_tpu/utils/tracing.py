@@ -18,12 +18,11 @@ annotation is one flag test; the ring append is what it was.
 
 Sites (``obs.critpath.SITE_PRIORITY`` ranks them): ``device_wait`` — the
 host blocked on the chip (a size read-back, the wait before a D2H copy,
-an exchange's sync, the ``metrics.detailEnabled`` sync); ``h2d`` /
-``d2h`` — staging copies; ``enqueue`` — ONE span per jitted call
-(``compile_registry.instrumented_jit``), the host wall of an
-asynchronous enqueue (compile-inclusive on a first call), never device
-time; ``stage`` — a stage program's whole dispatch (enqueue + any size
-read-back inside it; its wall is the ``deviceTimeNs`` metric);
+an exchange's sync); ``h2d`` / ``d2h`` — staging copies; ``enqueue`` —
+ONE span per jitted call (``compile_registry.instrumented_jit``), the
+host wall of an asynchronous enqueue (compile-inclusive on a first
+call), never device time; ``stage`` — a stage program's whole dispatch (enqueue + any size
+read-back inside it: the host's wall, like every span here);
 ``stage_inputs``, ``plan``, ``result``, ``bookkeeping`` — the host parts
 of ``session.execute_with_metrics``; ``scan``, ``io``, ``exchange``,
 ``mesh``, ``spill``, ``unspill``, ``pallas``, ``retry``,
@@ -36,23 +35,16 @@ import contextlib
 import functools
 import threading
 import time
-from typing import Callable, Optional
+from typing import Callable
 
 import jax.profiler
 
-from spark_rapids_tpu.config import METRICS_DETAIL
 from spark_rapids_tpu.obs import events as obs_events
 
 #: every program span's profiler name starts with this
 PREFIX = "srt"
 
 _CURRENT = threading.local()
-
-
-def metrics_detail(conf) -> bool:
-    """True when the accurate-sync metrics path is enabled (the cheap
-    lower-bound path is the default)."""
-    return METRICS_DETAIL.get(conf)
 
 
 class span:
@@ -177,44 +169,6 @@ def trace_range(site: str, name: str, metric=None):
     finally:
         if metric is not None:
             metric.add(sp.elapsed_ns)
-
-
-@contextlib.contextmanager
-def device_dispatch(ctx, op_id: str, name: str,
-                    obs_op: Optional[str] = None):
-    """One stage program's dispatch: span ``srt/stage/<name>`` whose wall
-    goes into ``ctx.metric(op_id, 'deviceTimeNs')``.
-
-    That wall is the HOST's: the asynchronous enqueue(s) plus any size
-    read-back the body takes — not device time (docs/metrics.md).  The
-    jitted calls inside open their own ``enqueue`` spans and inherit
-    ``obs_op`` as their operator.  The body sets ``holder['outputs']``
-    to the dispatched result; with the metrics-detail conf on they are
-    blocked on — a ``device_wait`` span — before the clock stops, and
-    ``deviceTimeSyncs`` counts those samples.
-
-    The elapsed time is recorded in a ``finally`` so a dispatch that
-    raises (an injected fault, an OOM about to be retried) still shows
-    in the metric and the profile instead of vanishing; the failed
-    attempt's span is tagged ``error``.  ``obs_op`` names the
-    physical-plan node the span is attributed to when the metric op_id
-    is a shared bucket (the pipeline dispatcher passes the stage root's
-    op_id here while keeping the metric under ``"pipeline"``).
-    """
-    holder: dict = {}
-    op = obs_op or op_id
-    sp = span("stage", name, op)
-    try:
-        with sp:
-            yield holder
-            if metrics_detail(ctx.conf) and \
-                    holder.get("outputs") is not None:
-                device_wait("metrics_detail", holder["outputs"], op)
-                ctx.metric(op_id, "deviceTimeSyncs").add(1)
-    finally:
-        ctx.metric(op_id, "deviceTimeNs").add(sp.elapsed_ns)
-        if sp.payload.get("error"):
-            ctx.metric(op_id, "deviceTimeErrors").add(1)
 
 
 def start_profile(logdir: str):

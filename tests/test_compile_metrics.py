@@ -1,15 +1,13 @@
 """Compile/dispatch economics tests: the per-query compile-miss /
-dispatch-count / device-time accounting (utils/compile_registry +
-utils/tracing), the shared shape-bucket policy, tail-stage fusion, and
-session.prewarm()."""
+dispatch-count accounting (utils/compile_registry), the shared
+shape-bucket policy, the stage breaks' one order (sizes fetched once,
+re-bucketing compiled into the consumer), and session.prewarm()."""
 
 import pytest
 
 from spark_rapids_tpu import functions as F
-from spark_rapids_tpu.config import RapidsConf
-from spark_rapids_tpu.session import TpuSparkSession
 
-from compare import tpu_session
+from compare import cpu_session, tpu_session
 
 
 def _headline_query(s, rows=1000):
@@ -37,7 +35,7 @@ def test_metrics_present_for_jitted_query():
     assert rows
     m = s.last_metrics
     for key in ("compileCount", "compileWallNs", "dispatchCount",
-                "compiledShapes", "deviceTimeNs"):
+                "compiledShapes"):
         assert key in m, f"last_metrics missing {key}: {sorted(m)}"
     assert m["compileCount"] > 0  # first run of fresh execs compiles
     assert m["compileWallNs"] > 0
@@ -58,42 +56,80 @@ def test_repeated_query_reports_zero_new_compiles():
     assert m["dispatchCount"] > 0  # still dispatches, just from cache
 
 
-def test_metrics_detail_toggle_keeps_plan_cache_warm():
-    """The metrics-detail conf is excluded from the plan-cache fingerprint:
-    flipping it must not recompile anything (bench relies on this for the
-    accurate device-time capture run)."""
-    s = tpu_session()
-    q = _headline_query(s)
-    q.collect()
-    s.set_conf("spark.rapids.sql.tpu.metrics.detailEnabled", True)
-    q.collect()
-    m = s.last_metrics
-    assert m["compileCount"] == 0
-    assert m["deviceTimeNs"] > 0
+#: every stage break is worth shrinking at test scale, and so is the
+#: collected root's own output
+_SHRINK_ALL = {"spark.rapids.sql.tpu.pipeline.shrinkBytes": 0}
 
 
-def _dispatches(fuse: bool):
-    conf = RapidsConf({
-        "spark.rapids.sql.enabled": True,
-        "spark.sql.shuffle.partitions": 4,
-        # force the stage-break shrink so the fused-vs-separate dispatch
-        # difference is observable at test scale
-        "spark.rapids.sql.tpu.pipeline.shrinkBytes": 0,
-        "spark.rapids.sql.tpu.pipeline.fuseTail.enabled": fuse,
-    })
-    s = TpuSparkSession(conf)
-    q = _headline_query(s)
-    rows = q.collect()
-    assert rows
-    return s.last_metrics["dispatchCount"], rows
+def test_tail_fusion_dispatches_no_shrink_for_a_break():
+    """The headline query's one stage break is re-bucketed inside the
+    tail program: the only ``pipeline:shrink`` program dispatched alone is
+    the collected root's own, and the rows are the oracle's."""
+    s = tpu_session(**_SHRINK_ALL)
+    rows = _headline_query(s).collect()
+    m = s.last_metrics["pipeline"]
+    assert m["fusedShrinks"] == 1, m
+    assert m["shrinks"] == 1, m      # the root's own output, no break's
+    assert m["programs"] == 2, m
+    enqueued = [e.name for e in s.query_history()[-1].events
+                if e.kind == "span" and e.site == "enqueue"]
+    assert enqueued.count("pipeline:shrink") == 1, enqueued
+    assert enqueued.count("stage:TpuSortExec") == 1, enqueued
+    assert rows == _headline_query(cpu_session()).collect()
 
 
-def test_tail_fusion_reduces_dispatch_count():
-    fused_d, fused_rows = _dispatches(fuse=True)
-    plain_d, plain_rows = _dispatches(fuse=False)
-    assert fused_rows == plain_rows  # fusion is a pure dispatch optimizer
-    assert fused_d < plain_d, \
-        f"tail fusion did not reduce dispatches: {fused_d} vs {plain_d}"
+def _keyless(s):
+    df = s.create_dataframe({"q": [i % 50 for i in range(1000)],
+                             "v": list(range(1000))}, num_partitions=3)
+    return df.filter(df["q"] < 40).agg(
+        F.sum(df["v"] * df["q"]).alias("sw"), F.count("v").alias("c"))
+
+
+def _join_under_aggregate(s):
+    fact = s.create_dataframe({"k": [i % 7 for i in range(500)],
+                               "v": list(range(500))}, num_partitions=2)
+    dim = s.create_dataframe({"k": list(range(7)),
+                              "w": [i * 10 for i in range(7)]})
+    return (fact.join(dim, on="k").group_by("w")
+            .agg(F.sum("v").alias("sv")).order_by("w"))
+
+
+def _union_of_aggregates(s):
+    def side(mod, rows):
+        df = s.create_dataframe({"k": [i % mod for i in range(rows)],
+                                 "v": list(range(rows))}, num_partitions=2)
+        return df.group_by("k").agg(F.sum("v").alias("sv"))
+    return side(7, 500).union(side(5, 300)).order_by("k", "sv")
+
+
+@pytest.mark.parametrize("build,breaks", [
+    (_keyless, 1), (_headline_query, 1), (_join_under_aggregate, 1),
+    (_union_of_aggregates, 2),
+], ids=["keyless_aggregate", "keyed_aggregate_sort",
+        "join_under_aggregate", "two_aggregates_under_a_union"])
+def test_stage_breaks_take_one_sizes_round_trip(build, breaks, monkeypatch):
+    """The one order of a stage's breaks: every break's program is
+    dispatched, then ONE ``host_sizes`` round trip fetches the live sizes
+    of all of them together (the second one a query is the collected
+    root's own output), and each break's re-bucketing is compiled into
+    the consumer — none dispatched alone.  Rows equal the oracle's."""
+    from spark_rapids_tpu.plan import pipeline
+
+    fetched = []
+    real = pipeline.host_sizes
+
+    def counted(batches):
+        fetched.append(len(batches))
+        return real(batches)
+
+    monkeypatch.setattr(pipeline, "host_sizes", counted)
+    s = tpu_session(**_SHRINK_ALL)
+    rows = build(s).collect()
+    m = s.last_metrics["pipeline"]
+    assert m["fusedShrinks"] == breaks, m
+    assert m["shrinks"] == 1, m
+    assert len(fetched) == 2 and fetched[0] >= breaks, fetched
+    assert rows == build(cpu_session()).collect()
 
 
 def test_prewarm_compiles_hot_set_once():

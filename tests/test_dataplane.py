@@ -22,7 +22,7 @@ from spark_rapids_tpu.kernels.layout import (
 from spark_rapids_tpu.session import TpuSparkSession
 from spark_rapids_tpu.utils import compile_registry as CR
 
-from compare import tpu_session
+from compare import assert_tpu_cpu_equal, tpu_session
 from conftest import assert_batches_equal
 
 
@@ -199,14 +199,12 @@ def test_donation_safe_with_cached_input_repeat():
     assert first == second
 
 
-def test_donation_conf_off_parity():
-    on = tpu_session()
-    off = tpu_session(**{"spark.rapids.sql.tpu.donation.enabled": False})
-    for q_on, q_off in zip(_pipeline_queries(on), _pipeline_queries(off)):
-        assert q_on.collect() == q_off.collect()
-    # with donation disabled nothing may be donated
-    _pipeline_queries(off)[0].collect()
-    assert off.last_metrics["donatedBytes"] == 0
+def test_donating_paths_match_the_oracle():
+    """Every pipeline path family, donating where the process can, returns
+    the CPU oracle's rows (in order, where the query fixes one)."""
+    for i, ordered in enumerate((True, True, False, True)):
+        assert_tpu_cpu_equal(lambda s, i=i: _pipeline_queries(s)[i],
+                             ignore_order=not ordered)
 
 
 def test_donating_programs_bypass_persistent_cache():
@@ -254,27 +252,36 @@ def _multi_part_query(s):
               .order_by("k"))
 
 
-def test_async_partitions_parity():
-    on = tpu_session()
-    off = tpu_session(
-        **{"spark.rapids.sql.tpu.pipeline.asyncPartitions.enabled": False})
-    assert _multi_part_query(on).collect() == \
-        _multi_part_query(off).collect()
+def test_multi_partition_dispatch_matches_the_oracle():
+    """Four partitions' programs dispatched before the one sizes sync:
+    the rows are the CPU oracle's, in order."""
+    assert_tpu_cpu_equal(_multi_part_query, ignore_order=False)
 
 
-def test_async_bulk_collect_join_root():
-    """A join as the plan root is not pipeline-viable: it exercises the
-    bulk-collect path (all partitions dispatched, one sizes sync, one bulk
-    D2H) — results must match the sequential per-batch path."""
+def test_bulk_collect_join_root(monkeypatch):
+    """A nested-loop join as the plan root inlines nothing, so the plan is
+    not pipeline-viable: it takes the bulk collect (all partitions
+    dispatched, one sizes sync, one bulk D2H) and returns the CPU
+    oracle's rows."""
+    from spark_rapids_tpu.plan import physical
+
+    roots = []
+    bulk = physical._collect_device_bulk
+
+    def counted(root, ctx):
+        roots.append(type(root).__name__)
+        return bulk(root, ctx)
+
+    monkeypatch.setattr(physical, "_collect_device_bulk", counted)
+
     def q(s):
-        left = s.create_dataframe({"k": [1, 2, 3, 4], "l": [10, 20, 30, 40]})
-        right = s.create_dataframe({"k": [2, 3, 5], "r": [200, 300, 500]})
-        return left.join(right, on="k").order_by("k").collect()
+        left = s.create_dataframe({"k": [1, 2, 3, 4], "l": [10, 20, 30, 40]},
+                                  num_partitions=2)
+        right = s.create_dataframe({"j": [2, 3, 5], "r": [200, 300, 500]})
+        return left.cross_join(right)
 
-    on = tpu_session()
-    off = tpu_session(
-        **{"spark.rapids.sql.tpu.pipeline.asyncPartitions.enabled": False})
-    assert q(on) == q(off)
+    assert_tpu_cpu_equal(q)
+    assert roots == ["TpuNestedLoopJoinExec"], roots
 
 
 def test_transfer_metrics_reported():

@@ -164,11 +164,11 @@ def test_conf_signature_excludes_inert_namespaces():
     base = [("spark.rapids.sql.enabled", True),
             ("spark.sql.shuffle.partitions", 4)]
     sig = store.conf_signature(base)
-    # metrics./obs./history. knobs never change plans -> same signature
+    # obs./history. knobs never change plans -> same signature
     assert store.conf_signature(base + [
         ("spark.rapids.sql.tpu.history.dir", "/x"),
         ("spark.rapids.sql.tpu.obs.eventLogDir", "/y"),
-        ("spark.rapids.sql.tpu.metrics.detailEnabled", True)]) == sig
+        ("spark.rapids.sql.tpu.obs.ring.maxEvents", 4096)]) == sig
     # anything else does
     assert store.conf_signature(base + [
         ("spark.sql.autoBroadcastJoinThreshold", -1)]) != sig

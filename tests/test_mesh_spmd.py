@@ -64,6 +64,21 @@ def test_spmd_groupby_parity():
         sess.last_metrics
 
 
+def test_spmd_stage_break_source_is_shrunk_alone():
+    """A mesh stage's shard_map program takes packed globals, so it cannot
+    compile a stage break's re-bucketing in: the break's gather is
+    dispatched alone (``shrinks``: the break's and the collected root's
+    own), none is counted as fused, and the rows are the oracle's."""
+    confs = {**SPMD_CONFS, "spark.rapids.sql.tpu.pipeline.shrinkBytes": 0}
+    assert_tpu_cpu_equal(_groupby, approx=True, confs=confs)
+    s = tpu_session(**confs)
+    _groupby(s).collect()
+    m = s.last_metrics
+    assert m["meshProgramDispatches"] == 1, m
+    assert m["pipeline"]["shrinks"] == 2, m["pipeline"]
+    assert "fusedShrinks" not in m["pipeline"], m["pipeline"]
+
+
 def test_spmd_repartition_roundrobin_parity():
     def build(s):
         return _people_df(s, n=200).repartition(6).select("age")

@@ -1,8 +1,7 @@
 """Observability subsystem tests: event-bus epochs, ring bounds, profile
-parity against last_metrics, Chrome/JSONL export, the rapidsprof CLI, and
+parity against last_metrics, JSONL export, the rapidsprof CLI, and
 the zero-overhead disabled path (ISSUE PR 10 acceptance list)."""
 
-import json
 import os
 import subprocess
 import sys
@@ -108,10 +107,14 @@ def test_rollup_matches_last_metrics_on_shuffle_spill_query():
         # `device`/`dispatch` span over the same call)
         assert p.site("enqueue")["count"] == m["dispatchCount"]
         assert p.site("dispatch")["count"] == p.site("device")["count"] == 0
-        # deviceTimeNs is the host wall of the stage dispatches: the
-        # `stage` spans add the exact same elapsed values, so it is exact
-        assert m["deviceTimeNs"] > 0
-        assert p.site("stage")["wall_ns"] == m["deviceTimeNs"]
+        # stage: one span per stage program, and the site's wall is the
+        # spans' own widths to the nanosecond
+        stages = [e for e in p.events
+                  if e.kind == "span" and e.site == "stage"]
+        assert len(stages) == p.site("stage")["count"] == \
+            m["pipeline"]["programs"] > 0
+        assert p.site("stage")["wall_ns"] == \
+            sum(e.t1 - e.t0 for e in stages) > 0
         # every enqueue nanosecond is tied to an operator or a program
         assert p.attributed_enqueue_ns == p.site("enqueue")["wall_ns"]
         # shuffle: exchange split/mesh spans carry the same bytes the
@@ -131,26 +134,6 @@ def test_rollup_matches_last_metrics_on_shuffle_spill_query():
         DeviceRuntime.reset()
 
 
-def test_chrome_trace_valid_json_sorted():
-    s = tpu_session()
-    _simple_query(s).collect()
-    p = s.query_history()[-1]
-    doc = json.loads(json.dumps(obs_export.events_to_chrome(p.events)))
-    evs = doc["traceEvents"]
-    assert evs
-    body = [e for e in evs if e["ph"] != "M"]
-    assert body
-    assert all(e["ph"] in ("X", "i", "M") for e in evs)
-    # spans sorted by timestamp, durations non-negative
-    ts = [e["ts"] for e in body]
-    assert ts == sorted(ts)
-    assert all(e.get("dur", 0) >= 0 for e in body)
-    # every track has thread metadata naming its site/thread
-    tids = {e["tid"] for e in body}
-    meta_tids = {e["tid"] for e in evs if e["ph"] == "M"}
-    assert tids <= meta_tids
-
-
 def test_jsonl_roundtrip_through_rapidsprof(tmp_path):
     log_dir = str(tmp_path / "obslog")
     s = tpu_session(**{"spark.rapids.sql.tpu.obs.eventLogDir": log_dir})
@@ -167,18 +150,14 @@ def test_jsonl_roundtrip_through_rapidsprof(tmp_path):
     assert queries[0]["event_count"] == s.last_metrics["obsEventCount"]
     assert len(queries[0]["events"]) == queries[0]["event_count"]
 
-    # and the runtime-free CLI renders a report + a loadable Chrome trace
-    trace = str(tmp_path / "trace.json")
+    # and the runtime-free CLI renders a report
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO_ROOT, "tools", "rapidsprof.py"),
-         logs[0], "--chrome", trace],
+         logs[0]],
         capture_output=True, text=True, cwd=REPO_ROOT, timeout=120)
     assert proc.returncode == 0, proc.stderr
     assert "top operators by enqueue wall" in proc.stdout
     assert "Exec" in proc.stdout  # names at least one real operator
-    with open(trace) as f:
-        tdoc = json.load(f)
-    assert tdoc["traceEvents"]
 
 
 def test_obs_disabled_zero_events_bit_identical():

@@ -522,21 +522,32 @@ def test_a_program_that_takes_no_parameters_refuses_to_bake_one():
     assert program.jitted._cache_size() == 1
 
 
-@pytest.mark.parametrize("confs", [
-    {"spark.rapids.sql.tpu.pipeline.enabled": False},
-    {"spark.rapids.sql.enabled": False},
-    {"spark.rapids.sql.exec.Filter": False},
+@pytest.mark.parametrize("confs,join_root", [
+    ({}, True),
+    ({"spark.rapids.sql.enabled": False}, False),
+    ({"spark.rapids.sql.exec.Filter": False}, False),
 ], ids=["iterator_path", "cpu_operators", "cpu_filter_under_tpu_aggregate"])
-def test_every_evaluation_path_reads_the_bound_value(confs, fresh_cache):
+def test_every_evaluation_path_reads_the_bound_value(confs, join_root,
+                                                     fresh_cache):
     """Operator programs outside a stage program, the CPU operators'
     ``cpu_eval``, and a CPU operator feeding the device through the
     read-ahead thread all evaluate the shared plan's lifted literals."""
     s = _session(make=cpu_session if confs.get(
         "spark.rapids.sql.enabled") is False else tpu_session, **confs)
     s.conf.set("spark.rapids.sql.test.enabled", False)
+    run = _q6
+    if join_root:
+        # a nested-loop join at the root inlines nothing, so the plan is
+        # not pipeline-viable and Q6 below it runs operator by operator
+        one = s.create_dataframe({"one": [1]})
+
+        def run(s, set_):
+            (got, _one), = s.sql(_text(*set_)).cross_join(one).collect()
+            assert got == pytest.approx(_reference(*set_), rel=1e-11), set_
+            assert "pipeline" not in s.last_metrics    # no stage program
     compiles = []
     for set_ in (SETS[0], SETS[1], SETS[2], SETS[1]):
-        _q6(s, set_)
+        run(s, set_)
         compiles.append(s.last_metrics["compileCount"])
         assert s.last_metrics["boundParams"] == 5
     assert compiles[1:] == [0, 0, 0], compiles

@@ -49,7 +49,7 @@ def test_second_session_compiles_zero_and_identical():
 
 def test_plan_cache_keyed_by_conf_state():
     """A plan-relevant conf change must NOT reuse the cached physical
-    plan (only metrics./obs. knobs are excluded from the key)."""
+    plan (only obs. knobs are excluded from the key)."""
     s1 = tpu_session()
     df = _df(s1).filter("v > 10")
     s1.execute(df.plan)
@@ -57,8 +57,8 @@ def test_plan_cache_keyed_by_conf_state():
     s2 = tpu_session(**{"spark.rapids.sql.enabled": False})
     s2.execute(df.plan)
     assert s2.last_physical_plan is not phys1
-    # metrics-detail toggles do reuse it
-    s3 = tpu_session(**{"spark.rapids.sql.tpu.metrics.detailEnabled": True})
+    # an observability knob does reuse it
+    s3 = tpu_session(**{"spark.rapids.sql.tpu.obs.ring.maxEvents": 4096})
     s3.execute(df.plan)
     assert s3.last_physical_plan is phys1
 

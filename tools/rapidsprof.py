@@ -3,15 +3,13 @@
 
 Usage:
     python tools/rapidsprof.py <events.jsonl> [more.jsonl ...]
-        [--top N] [--query ID] [--chrome out.json] [--critpath]
+        [--top N] [--query ID] [--critpath]
     python tools/rapidsprof.py --xplane <file.xplane.pb> [--top N]
 
 Reads the JSONL event log(s) a session wrote under
 ``spark.rapids.sql.tpu.obs.eventLogDir`` and prints, per query and in
 aggregate: top operators by enqueue wall, transfer/spill pressure, the
-retry/fault summary, and a per-query comparison table.  ``--chrome``
-additionally exports a Chrome ``trace_event`` JSON (load it in Perfetto
-or chrome://tracing).
+retry/fault summary, and a per-query comparison table.
 
 ``--xplane`` reads a ``jax.profiler`` trace instead (made with
 ``benchmark/run.py --trace 1 --keep-trace PATH`` or
@@ -206,8 +204,6 @@ def main(argv=None) -> int:
                     help="operators to list (default 10)")
     ap.add_argument("--query", type=int, default=None,
                     help="restrict to one query id")
-    ap.add_argument("--chrome", default=None, metavar="OUT",
-                    help="also write a Chrome trace_event JSON")
     ap.add_argument("--critpath", action="store_true",
                     help="print each query's exact critical-path "
                          "decomposition")
@@ -229,12 +225,6 @@ def main(argv=None) -> int:
         print("no queries found in", ", ".join(args.logs))
         return 2
     print(report(profiles, args.top, critpath=args.critpath))
-    if args.chrome:
-        events = [ev for p in profiles for ev in p.events]
-        obs_export.write_chrome_trace(args.chrome, events)
-        doc = obs_export.events_to_chrome(events)
-        print(f"\nwrote {args.chrome}: {len(doc['traceEvents'])} trace "
-              "events")
     return 0
 
 
