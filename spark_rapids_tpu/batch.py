@@ -472,6 +472,14 @@ def host_to_device(batch: HostBatch, capacity: Optional[int] = None,
 
 def device_to_host_many(batches: Sequence[ColumnBatch],
                         keep_dictionary: bool = False) -> List[HostBatch]:
+    return device_to_host_with(batches, (), keep_dictionary)[0]
+
+
+def device_to_host_with(batches: Sequence[ColumnBatch], riders,
+                        keep_dictionary: bool = False):
+    """(host batches, ``riders`` on the host): ``riders`` is a pytree of
+    small device values (a stage's flags) that come home in the batches'
+    own transfer — no wait and no round trip of their own."""
     # ONE bulk round trip for all batches' buffers AND num_rows scalars:
     # every leaf's copy is started with copy_to_host_async (as
     # jax.device_get does) before anything blocks, so the whole pytree
@@ -493,13 +501,13 @@ def device_to_host_many(batches: Sequence[ColumnBatch],
           else (c.data, c.validity, c.offsets) if c.offsets is not None
           else (c.data, c.validity) for c in b.columns])
         for b in batches]
-    for leaf in jax.tree_util.tree_leaves(tree):
+    for leaf in jax.tree_util.tree_leaves((tree, riders)):
         start = getattr(leaf, "copy_to_host_async", None)
         if start is not None:
             start()
-    device_wait("d2h_ready", tree)
+    device_wait("d2h_ready", (tree, riders))
     with span("d2h", "transfer") as sp:
-        host = jax.device_get(tree)
+        host, riders = jax.device_get((tree, riders))
         nbytes = sum(
             buf.nbytes
             for _num_rows, col_bufs in host
@@ -507,7 +515,7 @@ def device_to_host_many(batches: Sequence[ColumnBatch],
         sp.set(bytes=nbytes)
     record_transfer("d2h", nbytes, sp.elapsed_ns)
     with span("result", "assemble"):
-        return _host_batches(batches, host, keep_dictionary)
+        return _host_batches(batches, host, keep_dictionary), riders
 
 
 def _host_batches(batches: Sequence[ColumnBatch], host,

@@ -64,11 +64,6 @@ def shrink_to_fit(batch: ColumnBatch,
                        out_capacity=cap, out_byte_caps=byte_caps or None)
 
 
-# trailing pseudo-batch of the hash-agg pipeline stage: num_rows counts
-# collided batches (compared by object identity)
-_HASH_FLAGS_SCHEMA = T.Schema([("__hashagg_flags", T.INT)])
-
-
 def _reserve_for(ctx, batches: List[ColumnBatch], factor: int = 2) -> None:
     """Budget headroom before a large concat/gather: ask the catalog to
     evict lower-priority spillable batches so input + output fit
@@ -510,7 +505,18 @@ class TpuHashAggregateExec(TpuExec):
     slot path's ``mxuAggBatches`` stays 0) or, where the MXU gate is off or
     the aggregates are outside ``hash_agg_capable``, folded by the segment
     kernels over one segment without a sort (kernels/groupby); a merge is
-    the latter.  Partials and the result are ONE row at ``MIN_CAPACITY``.
+    the latter.  Partials and the result are ONE row at ``MIN_CAPACITY``:
+    there is nothing to re-bucket, so a keyless update is NOT a stage
+    break — it is inlined into its consumer's stage program, and update,
+    merge and the projection over them are one dispatch.
+
+    An update on the fast path (slot contraction or reduction) speculates:
+    a key range over the slot table or a NaN/Inf float input makes its
+    result invalid.  In a stage program it says so through the pipeline's
+    stage flags (plan/pipeline ``note_stage_batches``): the host reads the
+    flag where the stage's outputs are handed on — beside the answer when
+    the stage is the collected root — and answers through
+    :meth:`stage_flagged` / :meth:`stage_ran`.
     """
 
     def __init__(self, mode: str, key_exprs: List[Expression],
@@ -519,10 +525,11 @@ class TpuHashAggregateExec(TpuExec):
         assert mode in ("update", "merge")
         super().__init__([child], schema)
         self.mode = mode
-        # partial outputs have far fewer live rows than capacity: end the
-        # compiled stage here so the driver re-buckets before downstream
-        # concats/sorts pay O(padded capacity)
-        self.pipeline_stage_break = (mode == "update")
+        # keyed partial outputs have far fewer live rows than capacity: end
+        # the compiled stage here so the driver re-buckets before
+        # downstream concats/sorts pay O(padded capacity).  Keyless
+        # partials are one row at MIN_CAPACITY: nothing to re-bucket
+        self.pipeline_stage_break = (mode == "update" and bool(key_exprs))
         self.key_exprs = key_exprs
         self.key_names = key_names
         self.aggs = aggs
@@ -594,21 +601,37 @@ class TpuHashAggregateExec(TpuExec):
         return f"TpuHashAggregate({self.mode}, keys={len(self.key_exprs)})"
 
     def stage_variant(self, ctx) -> str:
-        """Key for the pipeline stage cache: the update stage compiles a
-        hash-path and a sort-path program (the latter built on demand when
-        a collided batch forces the exact fallback)."""
-        if self.mode == "update" and self._hash_active(ctx):
-            return "hash"
-        return "sort"
+        """This operator's part of the pipeline's stage key: a stage that
+        holds an update compiles a hash-path and a sort-path program (the
+        latter built on demand when a flagged batch forces the exact
+        fallback); a merge compiles one way and names none."""
+        if self.mode != "update":
+            return ""
+        return "hash" if self._hash_active(ctx) else "sort"
 
     def stage_may_rerun(self, ctx) -> bool:
-        """The MXU update stage's epilogue may re-dispatch the exact sort
-        variant on the SAME materialized inputs — the pipeline must not
-        donate them (plan/pipeline._stage_may_rerun)."""
+        """A set flag re-dispatches the stage in the exact sort variant on
+        the SAME materialized inputs — the pipeline must not donate them
+        (plan/pipeline._stage_may_rerun)."""
         return self.mode == "update" and self._hash_active(ctx)
 
+    def stage_flagged(self, ctx) -> None:
+        """The stage's flag came back set for this update (key range over
+        the slot table, NaN/Inf float inputs): the stage's outputs are
+        discarded and the fast path turns off for this exec —
+        correctness never depends on data shape."""
+        self._hash_disabled = True
+        ctx.metric(self.op_id, "hashAggFallback").add(1)
+
+    def stage_ran(self, ctx, batches: int, speculated: bool) -> None:
+        """A stage run that stands handled ``batches`` update batches
+        here, on the fast path where ``speculated``."""
+        self._count_update_batches(ctx, batches, fast=speculated)
+
     def pipeline_inline(self, ctx, build):
-        from spark_rapids_tpu.plan.pipeline import concat_static
+        from spark_rapids_tpu.plan.pipeline import (
+            concat_static, note_stage_batches,
+        )
         cf = build(self.children[0])
         child_schema = self.children[0].output_schema
         use_hash = self.mode == "update" and self._hash_active(ctx)
@@ -618,23 +641,22 @@ class TpuHashAggregateExec(TpuExec):
             for fn in self._input_fns:  # absorbed map stages
                 batches = [fn(b) for b in batches]
             if self.mode == "update":
-                # Emit per-batch partials as stage outputs: the stage break
-                # re-buckets them to live size (one sizes sync), so the
-                # downstream merge sorts a few thousand rows — merging here
-                # would concat at FULL padded capacity and sort O(sum of
-                # input caps) rows inside the program (seconds at 16M).
+                # Emit per-batch partials.  Keyed, they are the stage's
+                # outputs: the stage break re-buckets them to live size
+                # (one sizes sync), so the downstream merge sorts a few
+                # thousand rows — merging here would concat at FULL padded
+                # capacity and sort O(sum of input caps) rows inside the
+                # program (seconds at 16M).  Keyless, they are one row
+                # each and the consumer's merge follows in this program.
                 if use_hash:
                     outs, ncoll = [], jnp.asarray(0, jnp.int32)
                     for b in batches:
                         p, fl = self._aggregate_batch_hash(b)
                         outs.append(p)
                         ncoll = ncoll + fl.astype(jnp.int32)
-                    flag_col = DeviceColumn(T.INT,
-                                            jnp.zeros(16, jnp.int32),
-                                            jnp.ones(16, jnp.bool_))
-                    outs.append(ColumnBatch(_HASH_FLAGS_SCHEMA,
-                                            [flag_col], ncoll, 16))
+                    note_stage_batches(self, len(batches), ncoll)
                     return outs
+                note_stage_batches(self, len(batches))
                 return [self._aggregate_batch(b) for b in batches]
             if not batches:
                 if self.key_exprs:
@@ -645,28 +667,6 @@ class TpuHashAggregateExec(TpuExec):
             return [self._aggregate_batch(merged)]
 
         return f
-
-    def postprocess_stage_outputs(self, ctx, outs, rerun):
-        """MXU-path stage epilogue: the trailing pseudo-batch's num_rows
-        counts flagged batches (key range over the slot table, NaN/Inf
-        float inputs).  Any flag discards the stage and re-runs the exact
-        sort variant — correctness never depends on data shape."""
-        if not outs or outs[-1].schema is not _HASH_FLAGS_SCHEMA:
-            if self.mode == "update":
-                self._count_update_batches(ctx, len(outs), fast=False)
-            return outs
-        # a mesh-sharded stage unshards one flags pseudo-batch PER
-        # device (all trailing — the flags batch is the last program
-        # output) — pop and sum every one of them
-        flagged = 0
-        while outs and outs[-1].schema is _HASH_FLAGS_SCHEMA:
-            flagged += outs.pop().host_num_rows()
-        if flagged:
-            self._hash_disabled = True
-            ctx.metric(self.op_id, "hashAggFallback").add(1)
-            outs = rerun()
-        self._count_update_batches(ctx, len(outs), fast=not flagged)
-        return outs
 
     def _count_update_batches(self, ctx, n: int, fast: bool):
         """Update batches by the form that aggregated them: the slot

@@ -230,10 +230,11 @@ def _collect_overflow():
         _OVERFLOW.sink = prev
 
 
-def run_mesh_stage(root, ctx, variant: str,
-                   shrink: bool = True) -> List[ColumnBatch]:
-    """Execute a stage whose build fused >=1 exchange as ONE shard_map
-    program over ``ctx.mesh`` — plan/pipeline._run_stage's mesh divert."""
+def run_mesh_stage(root, ctx, variant: str, shrink: bool = True):
+    """Dispatch a stage whose build fused >=1 exchange as ONE shard_map
+    program over ``ctx.mesh`` — plan/pipeline._dispatch_stage's mesh
+    divert; a ``StageRun`` like the host path's, its flags one vector a
+    speculating operator with one entry a device."""
     from spark_rapids_tpu.fault import inject
     inject.maybe_fire("mesh")
     from spark_rapids_tpu.plan import pipeline as PL
@@ -312,17 +313,13 @@ def run_mesh_stage(root, ctx, variant: str,
     if not isinstance(cache, dict):
         cache = {}
         root._mesh_programs = cache
-    # per-output schemas, recorded when the program body traces: a stage
-    # fn may emit batches that are NOT root.output_schema (the MXU hash
-    # aggregate's trailing flags pseudo-batch) — rebuilding every output
-    # against the root schema would misparse their payload lists
-    scache = getattr(root, "_mesh_out_schemas", None)
-    if not isinstance(scache, dict):
-        scache = {}
-        root._mesh_out_schemas = scache
     key = (variant, n, DeviceRuntime.generation(), tuple(sig_parts))
-    program = cache.get(key)
-    if program is None:
+    if key not in cache:
+        # what the inlined operators noted at the trace (a shard handles
+        # one batch a slot: n times that over the mesh), counted again by
+        # every dispatch of the program
+        noted: list = []
+
         def body(flat):
             from spark_rapids_tpu.kernels.layout import ensure_row_layout
             args = []
@@ -345,16 +342,19 @@ def run_mesh_stage(root, ctx, variant: str,
                             schema2, flat[pos:pos + k], cap, squeeze=True))
                         pos += k
                     args.append(tuple(bs))
-            with _collect_overflow() as ovf_flags:
+            with _collect_overflow() as ovf_flags, \
+                    PL.collect_stage_notes() as notes:
                 outs = fn(tuple(args))
+            noted[:] = [(op, k * n, flag is not None)
+                        for op, k, flag in notes]
+            stage_flags = tuple(jnp.reshape(flag, (1,))
+                                for _, _, flag in notes if flag is not None)
             ovf = jnp.zeros(1, jnp.bool_)
             for flag in ovf_flags:
                 ovf = ovf | jnp.reshape(flag, (1,))
             flat_out = []
-            schemas = []
             for b in outs:
                 b = ensure_row_layout(b)
-                schemas.append(b.schema)
                 pl = []
                 for c in b.columns:
                     if c.offsets is not None:
@@ -365,8 +365,7 @@ def run_mesh_stage(root, ctx, variant: str,
                         pl += [c.data[None], c.validity[None]]
                 pl.append(jnp.asarray(b.num_rows, jnp.int32).reshape(1))
                 flat_out.append(pl)
-            scache[key] = schemas
-            return flat_out, ovf
+            return flat_out, ovf, stage_flags
 
         from jax import shard_map
         # replication checker off unconditionally (not just for the
@@ -376,7 +375,8 @@ def run_mesh_stage(root, ctx, variant: str,
             shard_map(body, mesh=mesh, in_specs=(tuple(in_specs),),
                       out_specs=P(DATA_AXIS), check_vma=False),
             label=f"meshStage:{root.name}")
-        cache[key] = program
+        cache[key] = (program, noted)
+    program, noted = cache[key]
 
     with span("mesh", "program", root.op_id) as mesh_span:
         ctx.metric("pipeline", "programs").add(1)
@@ -389,7 +389,7 @@ def run_mesh_stage(root, ctx, variant: str,
         overflowed = False
         results: List[ColumnBatch] = []
         with span("stage", root.name, root.op_id):
-            out_lists, ovf_g = PL._run_oom_guarded(
+            out_lists, ovf_g, flags_g = PL._run_oom_guarded(
                 ctx, lambda: program(tuple(flat_globals)), args=(),
                 retryable=True)
             # the ONLY host read of a fused stage, paid only when a join
@@ -405,18 +405,18 @@ def run_mesh_stage(root, ctx, variant: str,
             # after unsharding: per-shard HBM accounting without exposing a
             # long-lived spill victim that would gather every shard
             cat = DeviceRuntime.get(ctx.conf).catalog
-            out_schemas = scache.get(key) or [out_schema] * len(out_lists)
             handles = [
                 cat.register_sharded(
-                    _global_batch(sch, pl, _out_capacity(sch, pl)))
-                for sch, pl in zip(out_schemas, out_lists)]
+                    _global_batch(out_schema, pl,
+                                  _out_capacity(out_schema, pl)))
+                for pl in out_lists]
             bytes_per_device = [0] * n
             for h in handles:
                 for d, v in enumerate(h.shard_bytes):
                     bytes_per_device[d] += v
             dev_pos = {d: i for i, d in enumerate(devices)}
-            for sch, pl in zip(out_schemas, out_lists):
-                cap = _out_capacity(sch, pl)
+            for pl in out_lists:
+                cap = _out_capacity(out_schema, pl)
                 per_dev: List[list] = [[] for _ in range(n)]
                 for g in pl:
                     for shard in g.addressable_shards:
@@ -424,7 +424,7 @@ def run_mesh_stage(root, ctx, variant: str,
                 for d in range(n):
                     arrs = _unshard(per_dev[d])
                     results.append(_batch_from_payloads(
-                        sch, arrs, cap, squeeze=False))
+                        out_schema, arrs, cap, squeeze=False))
             for h in handles:
                 h.close()
         mesh_span.set(devices=n, fused_boundaries=len(exchanges),
@@ -465,4 +465,7 @@ def run_mesh_stage(root, ctx, variant: str,
     }
     if shrink:
         results = PL._shrink_outputs_sharded(results, ctx)
-    return results
+    return PL.StageRun(
+        root, results, flags_g, tuple(noted),
+        lambda: run_mesh_stage(root, ctx, PL._stage_variant(root, ctx),
+                               shrink=shrink))

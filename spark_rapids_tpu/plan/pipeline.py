@@ -12,16 +12,39 @@ TPU-native analogue of Spark whole-stage codegen, with XLA doing the
 fusion.
 
 Stage boundaries ("stage breaks") sit where live rows collapse far below
-capacity (aggregate partials): the driver syncs the live sizes once (one
-round trip), re-buckets the shrunk batches and feeds them to the next stage
-— otherwise padded capacities would snowball through concats and every
-downstream sort would pay O(padded).  The re-bucketing gather is not a
-separate dispatched program: it compiles INTO the consuming tail stage
-(cached per shrunk-bucket signature), so the final
-merge-aggregate/order-by/limit tail costs one dispatch, not two.  Only a
-consumer that cannot compile it in (a mesh stage's shard_map program,
-:func:`shrink_materialized`) and a directly collected root
-(:func:`_shrink_outputs`) dispatch it alone.
+capacity (the partials of an aggregate WITH grouping keys): the driver
+syncs the live sizes once (one round trip), re-buckets the shrunk batches
+and feeds them to the next stage — otherwise padded capacities would
+snowball through concats and every downstream sort would pay O(padded).
+The re-bucketing gather is not a separate dispatched program: it compiles
+INTO the consuming tail stage (cached per shrunk-bucket signature), so the
+final merge-aggregate/order-by/limit tail costs one dispatch, not two.
+Only a consumer that cannot compile it in (a mesh stage's shard_map
+program, :func:`shrink_materialized`) and a directly collected root
+(:func:`_shrink_outputs`) dispatch it alone.  A keyless aggregate has no
+stage break: its partials are one row each at the minimum capacity, there
+is nothing to re-bucket, and update, merge and whatever consumes them are
+ONE program.
+
+Stage flags.  An inlined operator may run a fast variant whose result is
+only valid if a flag the program computes comes back clear (the hash
+aggregate's slot table / NaN-Inf guard).  That is a notion of the stage,
+not of its root: while the stage program is traced, every such operator
+reports through :func:`note_stage_batches` how many batches it handled
+and, when it speculated, the traced flag; the flags are extra scalar
+outputs of the program, beside the batches (never a batch among them).
+The stage's variant key is the variant of EVERY inlined operator that has
+one (``stage_variant``), and a stage never donates its sources while any
+of them may ask for a rerun (``stage_may_rerun``).  The host reads the
+flags where the stage's outputs are handed on: for the collected root in
+the same transfer as the answer (:func:`pipeline_collect`: one
+``d2h_ready`` wait, no round trip of their own), for a stage that feeds
+another in one ``device_read`` (:func:`_run_stage`) before the consumer
+sees a batch.  A flag that comes back set discards the outputs, tells the
+operator (``stage_flagged``: its fast variant goes off for good) and
+re-dispatches the stage, in the variant the operators now name, on the
+same materialised sources (``pipeline.flagReruns``); only the run that
+stands is counted (``stage_ran``, ``pipeline.inlinedUpdates``).
 
 Ops that cannot be inlined (host transitions, joins needing host-visible
 output sizing, samples with host RNG) become pipeline *sources*: their
@@ -52,14 +75,14 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import (
-    BUCKETS, ColumnBatch, HostBatch, device_to_host_many, host_sizes,
+    BUCKETS, ColumnBatch, HostBatch, device_to_host_with, host_sizes,
     round_up_capacity,
 )
 from spark_rapids_tpu.plan.physical import ExecContext, PhysicalOp, TpuExec
 from spark_rapids_tpu.utils.compile_registry import (
     donation_supported, instrumented_jit, plan_jit,
 )
-from spark_rapids_tpu.utils.tracing import span
+from spark_rapids_tpu.utils.tracing import device_read, span
 
 
 def concat_static(batches: List[ColumnBatch], schema: T.Schema
@@ -114,6 +137,8 @@ def build_pipeline(op: PhysicalOp, ctx: ExecContext,
             lambda child: build_pipeline(child, ctx, sources, memo, root))
         if f is not None:
             f = _scoped(f, f"{type(op).__name__}.{k}")
+            if hasattr(op, "stage_variant"):
+                memo.setdefault(_VARIANTS, []).append(op)
     if f is None:
         idx = len(sources)
         sources.append(op)
@@ -122,8 +147,40 @@ def build_pipeline(op: PhysicalOp, ctx: ExecContext,
     return f
 
 
-#: ``memo`` key of the pre-order counter (never collides with an ``id()``)
+#: ``memo`` keys (never collide with an ``id()``): the pre-order counter,
+#: and the inlined operators that have a ``stage_variant``
 _ORDER = "order"
+_VARIANTS = "variants"
+
+
+_STAGE_NOTES = threading.local()
+
+
+def note_stage_batches(op: PhysicalOp, batches: int, flag=None) -> None:
+    """Trace-time channel from an inlined operator to its stage program:
+    ``op`` handled ``batches`` batches and, where it ran a fast variant
+    that the data may invalidate, ``flag`` is the traced count of batches
+    that did.  The program returns the flags beside its batches and the
+    host reads them where the outputs are handed on (module docstring).
+    Outside a collecting program body nobody would read the flag, and an
+    unread flag is a wrong answer waiting: that is an error."""
+    sink = getattr(_STAGE_NOTES, "sink", None)
+    if sink is None:
+        raise RuntimeError(
+            f"{op.name}: stage note outside a stage program's trace")
+    sink.append((op, batches, flag))
+
+
+@contextlib.contextmanager
+def collect_stage_notes():
+    """The notes of the operators traced inside the block, in trace
+    order: ``[(op, batches, flag-or-None), ...]``."""
+    prev = getattr(_STAGE_NOTES, "sink", None)
+    sink = _STAGE_NOTES.sink = []
+    try:
+        yield sink
+    finally:
+        _STAGE_NOTES.sink = prev
 
 
 def _scoped(f: Callable, scope: str) -> Callable:
@@ -188,19 +245,48 @@ def mesh_fusion_disabled():
 def _mesh_scoped_build(root: PhysicalOp, ctx: ExecContext,
                        sources: List[PhysicalOp]):
     """Run :func:`build_pipeline` under a :class:`MeshBuildScope` when
-    SPMD fusion is active for ``ctx``; (fn, scope-or-None)."""
+    SPMD fusion is active for ``ctx``; (fn, scope-or-None).  The first
+    build of a root also records which inlined operators have a
+    ``stage_variant`` (``root._stage_variant_ops``): what a stage inlines
+    does not depend on the variant it is built in."""
+    memo: dict = {}
+    scope = None
     if not ctx.mesh_spmd_active():
-        return build_pipeline(root, ctx, sources, {}, root), None
-    scope = MeshBuildScope(sources)
-    stack = getattr(_MESH_BUILD, "stack", None)
-    if stack is None:
-        stack = _MESH_BUILD.stack = []
-    stack.append(scope)
-    try:
-        fn = build_pipeline(root, ctx, sources, {}, root)
-    finally:
-        stack.pop()
+        fn = build_pipeline(root, ctx, sources, memo, root)
+    else:
+        scope = MeshBuildScope(sources)
+        stack = getattr(_MESH_BUILD, "stack", None)
+        if stack is None:
+            stack = _MESH_BUILD.stack = []
+        stack.append(scope)
+        try:
+            fn = build_pipeline(root, ctx, sources, memo, root)
+        finally:
+            stack.pop()
+    if getattr(root, "_stage_variant_ops", None) is None:
+        root._stage_variant_ops = tuple(memo.get(_VARIANTS, ()))
     return fn, scope
+
+
+def _variant_ops(root: PhysicalOp, ctx: ExecContext) -> tuple:
+    """The operators inlined into ``root``'s stage (``root`` too) that
+    compile in variants.  Learnt from one build that is thrown away:
+    builds are cached by variant, and the variant is theirs to name."""
+    ops = getattr(root, "_stage_variant_ops", None)
+    if ops is None:
+        _mesh_scoped_build(root, ctx, [])
+        ops = root._stage_variant_ops
+    return ops
+
+
+def _stage_variant(root: PhysicalOp, ctx: ExecContext) -> str:
+    """Key of the stage's build and programs: the variant of every
+    inlined operator that has one.  An inlined operator closes over its
+    variant at build time, so a stage keyed on its root alone would keep
+    dispatching a build whose fast variant has since gone off."""
+    return ",".join(filter(None, (
+        op.stage_variant(ctx) for op in _variant_ops(root, ctx)))
+    ) or "default"
 
 
 def _shrink_threshold(ctx: ExecContext) -> int:
@@ -210,11 +296,11 @@ def _shrink_threshold(ctx: ExecContext) -> int:
 
 
 def _stage_may_rerun(root: PhysicalOp, ctx: ExecContext) -> bool:
-    """True when the stage's epilogue may re-dispatch on the SAME
-    materialized inputs (hash-agg exact fallback): those inputs must then
-    never be donated."""
-    probe = getattr(root, "stage_may_rerun", None)
-    return bool(probe(ctx)) if probe is not None else False
+    """True when a flag read may re-dispatch the stage on the SAME
+    materialized inputs (hash-agg exact fallback, of the root or of any
+    operator inlined under it): those inputs must then never be
+    donated."""
+    return any(op.stage_may_rerun(ctx) for op in _variant_ops(root, ctx))
 
 
 def _batch_padded_bytes(b: ColumnBatch) -> int:
@@ -474,6 +560,10 @@ def _stage_program(root: PhysicalOp, ctx: ExecContext, variant: str,
     key = (variant, spec, dmask)
     if key not in cache:
         sources, fn = _stage_build(root, ctx, variant)
+        # what the inlined operators noted when the program was traced,
+        # by the number of batches each source fed: static facts of a
+        # trace that every later dispatch of it has to count again
+        noted: dict = {}
 
         def assemble(dargs, kargs, _mask=dmask):
             di, ki, args = 0, 0, []
@@ -486,20 +576,25 @@ def _stage_program(root: PhysicalOp, ctx: ExecContext, variant: str,
                     ki += 1
             return tuple(args)
 
-        if all(s is None for s in spec):
-            def run(dargs, kargs):
-                return tuple(fn(assemble(dargs, kargs)))
-        else:
-            def run(dargs, kargs, _spec=spec):
-                shrunk = tuple(
+        def run(dargs, kargs, _spec=spec):
+            args = assemble(dargs, kargs)
+            arity = tuple(len(bs) for bs in args)
+            if any(sp is not None for sp in _spec):
+                args = tuple(
                     tuple(bs) if sp is None else tuple(
                         _shrink_gather(b, cap, bcaps)
                         for b, (cap, bcaps) in zip(bs, sp))
-                    for bs, sp in zip(assemble(dargs, kargs), _spec))
-                return tuple(fn(shrunk))
+                    for bs, sp in zip(args, _spec))
+            with collect_stage_notes() as notes:
+                outs = tuple(fn(args))
+            noted[arity] = tuple((op, n, flag is not None)
+                                 for op, n, flag in notes)
+            return outs, tuple(flag for _, _, flag in notes
+                               if flag is not None)
         jit_kw = {"donate_argnums": (0,)} if any(dmask) else {}
-        cache[key] = (sources,
-                      plan_jit(run, label=f"stage:{root.name}", **jit_kw))
+        cache[key] = (
+            sources, plan_jit(run, label=f"stage:{root.name}", **jit_kw),
+            noted)
     return cache[key]
 
 
@@ -537,13 +632,65 @@ def _run_oom_guarded(ctx: ExecContext, thunk, args=(), retryable=True):
         raise
 
 
+class StageRun:
+    """One dispatch of a stage: its output batches, still unvalidated
+    while ``flags`` (device scalars, one a speculating operator; a vector
+    a device from a mesh stage) have not been read clear.  ``notes`` is
+    what the operators noted at the trace, ``[(op, batches,
+    speculated)]`` with one flag per speculating entry in order, and
+    ``redo()`` dispatches the stage again, in the variant its operators
+    name by then, on the same materialised sources."""
+
+    __slots__ = ("root", "outs", "flags", "notes", "redo")
+
+    def __init__(self, root, outs, flags, notes, redo):
+        self.root = root
+        self.outs = outs
+        self.flags = flags
+        self.notes = notes
+        self.redo = redo
+
+
+def _stands(run: StageRun, flags, ctx: ExecContext) -> bool:
+    """Judge ``run`` by its flags as read on the host.  Any set flag
+    discards it: the operators that raised one are told
+    (``stage_flagged``) and nothing is counted.  Else the run stands and
+    every noting operator counts its batches (``stage_ran``)."""
+    read = iter(flags)
+    flagged = [op for op, _n, speculated in run.notes
+               if speculated and bool(next(read).any())]
+    for op in flagged:
+        op.stage_flagged(ctx)
+    if flagged:
+        ctx.metric("pipeline", "flagReruns").add(1)
+        return False
+    inlined = 0
+    for op, n, speculated in run.notes:
+        op.stage_ran(ctx, n, speculated)
+        inlined += op is not run.root
+    if inlined:
+        ctx.metric("pipeline", "inlinedUpdates").add(inlined)
+    return True
+
+
 def _run_stage(root: PhysicalOp, ctx: ExecContext,
                shrink: bool = True) -> List[ColumnBatch]:
-    """Execute ``root``'s stage as one program.  ``shrink=True`` (the
-    default, for directly-collected stages) re-buckets the outputs;
+    """``root``'s stage as one program, for a consumer on the device:
+    where the stage holds flags they are read here, in one
+    ``device_read``, so no consumer sees an unvalidated batch.
     ``shrink=False`` hands raw outputs to a tail-fusing consumer."""
-    variant_fn = getattr(root, "stage_variant", None)
-    variant = variant_fn(ctx) if variant_fn is not None else "default"
+    run = _dispatch_stage(root, ctx, shrink)
+    while not _stands(run, device_read("stage_flags", run.flags, root.op_id)
+                      if run.flags else (), ctx):
+        run = run.redo()
+    return run.outs
+
+
+def _dispatch_stage(root: PhysicalOp, ctx: ExecContext,
+                    shrink: bool = True) -> StageRun:
+    """Dispatch ``root``'s stage as one program.  ``shrink=True`` (the
+    default, for directly-collected stages) re-buckets the outputs."""
+    variant = _stage_variant(root, ctx)
     sources, _fn = _stage_build(root, ctx, variant)
     minfo = getattr(root, "_mesh_stage_info", None)
     if isinstance(minfo, dict) and variant in minfo:
@@ -553,25 +700,12 @@ def _run_stage(root: PhysicalOp, ctx: ExecContext,
         # single-device path below would trace lax.axis_index with no
         # mesh axis bound
         from spark_rapids_tpu.parallel.mesh_spmd import run_mesh_stage
-
-        def dispatch_mesh(v: str) -> List[ColumnBatch]:
-            return run_mesh_stage(root, ctx, v, shrink=shrink)
-
-        outs = dispatch_mesh(variant)
-        post = getattr(root, "postprocess_stage_outputs", None)
-        if post is not None:
-            def rerun_mesh():
-                v2 = variant_fn(ctx) if variant_fn is not None \
-                    else "default"
-                return dispatch_mesh(v2)
-
-            outs = post(ctx, outs, rerun_mesh)
-        return outs
+        return run_mesh_stage(root, ctx, variant, shrink=shrink)
     return _run_stage_host(root, ctx, variant, sources, shrink)
 
 
 def run_stage_unfused(root: PhysicalOp, ctx: ExecContext, variant: str,
-                      shrink: bool = True) -> List[ColumnBatch]:
+                      shrink: bool = True) -> StageRun:
     """Host-driven rerun of a fused mesh stage (the bucketed-join
     overflow fallback, parallel.mesh_spmd): rebuild the stage with mesh
     fusion disabled under a distinct ``nomesh:`` variant key — the
@@ -586,8 +720,7 @@ def run_stage_unfused(root: PhysicalOp, ctx: ExecContext, variant: str,
 
 def _run_stage_host(root: PhysicalOp, ctx: ExecContext, variant: str,
                     sources: List[PhysicalOp], shrink: bool,
-                    unfused: bool = False) -> List[ColumnBatch]:
-    variant_fn = getattr(root, "stage_variant", None)
+                    unfused: bool = False) -> StageRun:
     with span("stage_inputs", root.name):
         mats = _materialize_sources(sources, ctx)
     args = tuple(tuple(bs) for bs, _, _ in mats)
@@ -605,37 +738,38 @@ def _run_stage_host(root: PhysicalOp, ctx: ExecContext, variant: str,
         if len({id(leaf) for leaf in leaves}) != len(leaves):
             # a duplicated leaf cannot be donated twice — keep everything
             dmask = tuple(False for _ in dmask)
+    arity = tuple(len(bs) for bs in args)
 
-    def dispatch(v: str) -> List[ColumnBatch]:
-        s2, jitted = _stage_program(root, ctx, v, spec, dmask)
+    def dispatch(v: str) -> StageRun:
+        s2, jitted, noted = _stage_program(root, ctx, v, spec, dmask)
         assert len(s2) == len(sources), "stage variants disagree"
         ctx.metric("pipeline", "programs").add(1)
         dargs = tuple(a for a, m in zip(args, dmask) if m)
         kargs = tuple(a for a, m in zip(args, dmask) if not m)
+
+        def call():
+            outs, flags = jitted(dargs, kargs)
+            outs = list(outs)
+            return (_shrink_outputs(outs, ctx) if shrink else outs), flags
+
         # the jitted calls inside open their own ``enqueue`` spans and
         # inherit the stage root as their operator
         with span("stage", root.name, root.op_id):
-            return _run_oom_guarded(
-                ctx,
-                lambda: _shrink_outputs(list(jitted(dargs, kargs)), ctx)
-                if shrink else list(jitted(dargs, kargs)),
-                args, retryable=not any(dmask))
+            outs, flags = _run_oom_guarded(ctx, call, args,
+                                           retryable=not any(dmask))
+        return StageRun(root, outs, flags, noted[arity], redo)
 
-    outs = dispatch(variant)
-    post = getattr(root, "postprocess_stage_outputs", None)
-    if post is not None:
-        def rerun():
-            # the op flipped its variant (e.g. hash -> exact sort);
-            # re-execute on the SAME materialized source batches
-            v2 = variant_fn(ctx) if variant_fn is not None else "default"
-            if unfused:
-                v2 = "nomesh:" + v2
-                with mesh_fusion_disabled():
-                    _stage_build(root, ctx, v2)
-            return dispatch(v2)
+    def redo() -> StageRun:
+        # an operator flipped its variant (hash -> exact sort):
+        # re-execute on the SAME materialized source batches
+        v2 = _stage_variant(root, ctx)
+        if unfused:
+            v2 = "nomesh:" + v2
+            with mesh_fusion_disabled():
+                _stage_build(root, ctx, v2)
+        return dispatch(v2)
 
-        outs = post(ctx, outs, rerun)
-    return outs
+    return dispatch(variant)
 
 
 def pipeline_collect(root: PhysicalOp, ctx: ExecContext
@@ -661,8 +795,16 @@ def pipeline_collect(root: PhysicalOp, ctx: ExecContext
 
     ctx._pipeline_h2d = 0
     try:
-        outs = _run_stage(root, ctx)
-        hbs = [hb for hb in device_to_host_many(outs) if hb.num_rows]
+        # the stage's flags come home beside the answer: one transfer,
+        # one wait, and an answer whose flag is set is thrown away
+        run = _dispatch_stage(root, ctx)
+        while True:
+            hbs, flags = device_to_host_with(run.outs, run.flags)
+            if _stands(run, flags, ctx):
+                break
+            run = run.redo()
+        outs = run.outs
+        hbs = [hb for hb in hbs if hb.num_rows]
     finally:
         from spark_rapids_tpu.plan.physical import _release_admission
         if ctx.semaphore is not None:

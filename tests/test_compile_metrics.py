@@ -103,16 +103,18 @@ def _union_of_aggregates(s):
 
 
 @pytest.mark.parametrize("build,breaks", [
-    (_keyless, 1), (_headline_query, 1), (_join_under_aggregate, 1),
+    (_keyless, 0), (_headline_query, 1), (_join_under_aggregate, 1),
     (_union_of_aggregates, 2),
 ], ids=["keyless_aggregate", "keyed_aggregate_sort",
         "join_under_aggregate", "two_aggregates_under_a_union"])
 def test_stage_breaks_take_one_sizes_round_trip(build, breaks, monkeypatch):
     """The one order of a stage's breaks: every break's program is
     dispatched, then ONE ``host_sizes`` round trip fetches the live sizes
-    of all of them together (the second one a query is the collected
+    of all of them together (the other one a query is the collected
     root's own output), and each break's re-bucketing is compiled into
-    the consumer — none dispatched alone.  Rows equal the oracle's."""
+    the consumer — none dispatched alone.  A keyless aggregate has no
+    break: one program, and only the root's own sizes are fetched.  Rows
+    equal the oracle's."""
     from spark_rapids_tpu.plan import pipeline
 
     fetched = []
@@ -126,10 +128,48 @@ def test_stage_breaks_take_one_sizes_round_trip(build, breaks, monkeypatch):
     s = tpu_session(**_SHRINK_ALL)
     rows = build(s).collect()
     m = s.last_metrics["pipeline"]
-    assert m["fusedShrinks"] == breaks, m
+    assert m.get("fusedShrinks", 0) == breaks, m
     assert m["shrinks"] == 1, m
-    assert len(fetched) == 2 and fetched[0] >= breaks, fetched
+    assert m["programs"] == breaks + 1, m
+    assert len(fetched) == (2 if breaks else 1), fetched
+    assert fetched[0] >= breaks, fetched
     assert rows == build(cpu_session()).collect()
+
+
+def _spans(s, *sites):
+    return [(e.site, e.name) for e in s.query_history()[-1].events
+            if e.kind == "span" and e.site in sites]
+
+
+def test_keyless_aggregate_is_one_program_and_one_read_back():
+    """A keyless filter-and-sum: the update is compiled into its
+    consumer's stage, so a collect is ONE dispatch, and the fast path's
+    flag comes home in the answer's transfer — the host waits for the
+    chip once, at ``d2h_ready``.  The same columns grouped by a key keep
+    their break: two programs and the one ``host_sizes`` round trip."""
+    s = tpu_session()
+    df = _keyless(s)
+    for _ in range(2):      # the one that compiles, and a warm one
+        rows = df.collect()
+        m = s.last_metrics
+        assert m["dispatchCount"] == 1, m["dispatchCount"]
+        assert m["pipeline"]["programs"] == 1, m["pipeline"]
+        assert m["pipeline"]["inlinedUpdates"] == 1, m["pipeline"]
+        assert "flagReruns" not in m["pipeline"], m["pipeline"]
+        assert _spans(s, "device_wait") == [("device_wait", "d2h_ready")]
+        (enqueued,) = _spans(s, "enqueue")
+        assert enqueued[1].startswith("stage:"), enqueued
+    assert rows == _keyless(cpu_session()).collect()
+
+    keyed = tpu_session(**_SHRINK_ALL)
+    rows = _headline_query(keyed).collect()
+    m = keyed.last_metrics
+    assert m["pipeline"]["programs"] == 2, m["pipeline"]
+    assert "inlinedUpdates" not in m["pipeline"], m["pipeline"]
+    waits = [n for _, n in _spans(keyed, "device_wait")]
+    assert waits.count("host_sizes") == 2, waits   # the break's, the root's
+    assert waits[-1] == "d2h_ready", waits
+    assert rows == _headline_query(cpu_session()).collect()
 
 
 def test_prewarm_compiles_hot_set_once():

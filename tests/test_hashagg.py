@@ -481,3 +481,160 @@ def test_keyless_nan_batch_raises_the_flag_and_takes_the_exact_path():
                for ms in tpu.last_metrics.values()), tpu.last_metrics
     assert _flat(tpu, "keylessAggBatches") == 0
     assert _flat(tpu, "keylessUpdateBatches") > 0
+
+
+# -- the flag's route: beside the answer, or where a stage hands on ----------
+
+
+def _pipeline(s):
+    return s.last_metrics.get("pipeline", {})
+
+
+def _fallbacks(s):
+    return sum(ms.get("hashAggFallback", 0)
+               for ms in s.last_metrics.values() if isinstance(ms, dict))
+
+
+def _keyless_sums(s, data, parts=2):
+    return s.create_dataframe(data, num_partitions=parts).agg(
+        Column(Alias(Sum(ColumnRef("f")), "sf")),
+        Column(Alias(Count(ColumnRef("f")), "cf")),
+        Column(Alias(Sum(ColumnRef("v")), "sv")))
+
+
+def _same(t_rows, c_rows):
+    """Rows equal: NaN equal to NaN, floats to the emulated f64's ulps."""
+    def norm(rows):
+        return [tuple("nan" if isinstance(x, float) and x != x else
+                      pytest.approx(x, rel=1e-12) if isinstance(x, float)
+                      else x for x in r) for r in rows]
+    return norm(t_rows) == norm(c_rows)
+
+
+def test_keyless_flag_rides_home_with_the_answer_and_the_rerun_is_sticky():
+    """A NaN under a keyless sum: the flag is read beside the (discarded)
+    answer, the stage is dispatched again in the exact variant on the same
+    sources, and the NEXT collect of the DataFrame is one program in that
+    variant — the stage's key follows the operator inlined into it."""
+    data = _data(n=1500, with_nan=True)
+    tpu, cpu = tpu_session(**FLOAT_AGG), cpu_session(**FLOAT_AGG)
+    df = _keyless_sums(tpu, data)
+    want = _keyless_sums(cpu, data).collect()
+    assert want[0][0] != want[0][0]                     # the oracle's NaN
+
+    assert _same(df.collect(), want)
+    assert _fallbacks(tpu) == 1
+    assert _pipeline(tpu)["flagReruns"] == 1, _pipeline(tpu)
+    assert _pipeline(tpu)["programs"] == 2, _pipeline(tpu)
+    assert tpu.last_metrics["dispatchCount"] == 2
+    assert _flat(tpu, "keylessAggBatches") == 0         # only the run that
+    assert _flat(tpu, "keylessUpdateBatches") > 0       # stands is counted
+    waits = [e.name for e in tpu.query_history()[-1].events
+             if e.kind == "span" and e.site == "device_wait"]
+    assert waits == ["d2h_ready", "d2h_ready"], waits   # no read of its own
+
+    assert _same(df.collect(), want)
+    assert all(a._hash_disabled for a in _update_aggs(tpu))
+    assert tpu.last_metrics["dispatchCount"] == 1
+    assert _pipeline(tpu)["programs"] == 1, _pipeline(tpu)
+    assert "flagReruns" not in _pipeline(tpu), _pipeline(tpu)
+    assert _fallbacks(tpu) == 0
+    assert _flat(tpu, "keylessAggBatches") == 0
+    root = tpu.last_physical_plan
+    assert sorted({k[0] for k in root._stage_cache}) == ["hash", "sort"]
+    assert sorted(root._stage_builds) == ["hash", "sort"]
+
+
+def test_keyless_aggregate_of_no_rows_yields_the_default_row():
+    def q(s):
+        df = s.create_dataframe(_data(n=300), num_partitions=2)
+        return df.filter(df["v"] > 10**12).agg(
+            Column(Alias(Sum(ColumnRef("f")), "sf")),
+            Column(Alias(Count(ColumnRef("f")), "cf")))
+
+    tpu = tpu_session(**FLOAT_AGG)
+    assert q(tpu).collect() == q(cpu_session(**FLOAT_AGG)).collect() \
+        == [(None, 0)]
+    assert tpu.last_metrics["dispatchCount"] == 1
+    assert "flagReruns" not in _pipeline(tpu)
+
+
+def _clean_and_nan_sides(s):
+    clean, bad = _data(n=700), _data(n=900, with_nan=True)
+    return _keyless_sums(s, clean), _keyless_sums(s, bad)
+
+
+def _union_of_keyless(s):
+    a, b = _clean_and_nan_sides(s)
+    return a.union(b)
+
+
+def _keyed_over_keyless(s):
+    # the keyless aggregate is fused into the KEYED update's stage, a
+    # stage break: not the collected root
+    _a, b = _clean_and_nan_sides(s)
+    return b.group_by("cf").agg(Column(Alias(Sum(ColumnRef("sf")), "x")))
+
+
+def _keyless_under_cross_join(s):
+    _a, b = _clean_and_nan_sides(s)
+    return s.create_dataframe({"w": (T.INT, [1, 2, 3])}).join(
+        b, how="cross")
+
+
+@pytest.mark.parametrize("build,reruns", [
+    (_union_of_keyless, 1), (_keyed_over_keyless, 2),
+    (_keyless_under_cross_join, None),
+], ids=["two_under_a_union", "under_a_keyed_aggregate",
+        "under_a_cross_join"])
+def test_no_consumer_sees_an_unvalidated_keyless_partial(build, reruns):
+    """Where the fused stage is not alone or not the collected root, every
+    flag is read before the stage's outputs are handed on: under a union
+    both flags ride with the answer and only the flagged side falls back;
+    under a keyed aggregate (a stage break) they are read in one
+    ``device_read`` before the consumer is dispatched; under a join the
+    aggregate runs the iterator path, which reads its own."""
+    tpu, cpu = tpu_session(**FLOAT_AGG), cpu_session(**FLOAT_AGG)
+    want = build(cpu).collect()
+    assert any(x != x for r in want for x in r if isinstance(x, float))
+    assert _same(sorted(build(tpu).collect(), key=repr),
+                 sorted(want, key=repr))
+    assert _fallbacks(tpu) >= 1
+    if reruns is not None:
+        assert _pipeline(tpu)["flagReruns"] == reruns, _pipeline(tpu)
+    flagged = [a._hash_disabled for a in _update_aggs(tpu)
+               if not a.key_exprs]
+    assert sorted(flagged) == ([False, True] if build is _union_of_keyless
+                               else [True]), flagged
+    waits = [e.name for e in tpu.query_history()[-1].events
+             if e.kind == "span" and e.site == "device_wait"]
+    if build is _keyed_over_keyless:
+        # read where the break's stage hands on, before its consumer runs
+        assert waits.count("stage_flags") == 2, waits
+        assert waits.index("stage_flags") < waits.index("d2h_ready")
+    elif build is _union_of_keyless:
+        assert waits == ["d2h_ready", "d2h_ready"], waits
+
+
+def test_a_stage_that_may_rerun_does_not_donate_its_sources():
+    """The fused stage reads fresh host->device stagings, donatable to any
+    other stage: while the inlined update speculates they are kept (the
+    rerun needs them); once it runs the exact variant they are donated
+    where the process can donate."""
+    from spark_rapids_tpu.analysis.plan_verify import verify_plan
+    from spark_rapids_tpu.plan.physical import HostToDeviceExec
+    from spark_rapids_tpu.utils.compile_registry import donation_supported
+    data = _data(n=1500, with_nan=True)
+    tpu = tpu_session(**FLOAT_AGG)
+    df = _keyless_sums(tpu, data)
+    df.collect()
+    df.collect()
+    root = tpu.last_physical_plan
+    sources = {v: b[0] for v, b in root._stage_builds.items()}
+    assert all(isinstance(src, HostToDeviceExec)
+               for srcs in sources.values() for src in srcs), sources
+    masks = {v: dmask for v, _spec, dmask in root._stage_cache}
+    assert not any(masks["hash"]), masks
+    assert all(masks["sort"]) == donation_supported(), masks
+    verify_plan(root)
+

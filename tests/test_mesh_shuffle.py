@@ -171,12 +171,12 @@ def test_mesh_shuffle_payloads_stay_on_device(monkeypatch):
     in_materialize = []
     offending = []
     real_get = jax.device_get
-    real_d2h_many = B.device_to_host_many
+    real_d2h_with = B.device_to_host_with
 
-    def patched_d2h_many(batches):
+    def patched_d2h_with(batches, riders, keep_dictionary=False):
         in_materialize.append(True)
         try:
-            return real_d2h_many(batches)
+            return real_d2h_with(batches, riders, keep_dictionary)
         finally:
             in_materialize.pop()
 
@@ -189,8 +189,8 @@ def test_mesh_shuffle_payloads_stay_on_device(monkeypatch):
         return real_get(x)
 
     monkeypatch.setattr(jax, "device_get", patched_get)
-    monkeypatch.setattr(B, "device_to_host_many", patched_d2h_many)
-    monkeypatch.setattr(PL, "device_to_host_many", patched_d2h_many)
+    monkeypatch.setattr(B, "device_to_host_with", patched_d2h_with)
+    monkeypatch.setattr(PL, "device_to_host_with", patched_d2h_with)
 
     sess = tpu_session(**MESH_CONFS,
                        **{"spark.sql.autoBroadcastJoinThreshold": 0})

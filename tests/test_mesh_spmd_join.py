@@ -122,10 +122,9 @@ def test_spmd_join_outer_hash_parity(how):
 
 def test_spmd_join_feeding_aggregation_parity():
     """join -> group_by: the fused-join stage's root is the MXU hash
-    aggregate, whose program appends a trailing flags pseudo-batch with
-    its OWN schema — the mesh unshard must rebuild each output against
-    the schema recorded at trace time (one flags batch per shard), not
-    assume root.output_schema for every payload list."""
+    aggregate, whose stage flag is an output of the mesh program beside
+    its batches — one entry a device, never a batch among the payload
+    lists, which are all of root.output_schema."""
     def q(s):
         left = _left_df(s)
         right = _right_df(s)
@@ -139,6 +138,39 @@ def test_spmd_join_feeding_aggregation_parity():
     m = s.last_metrics
     assert m["meshJoinsFused"] >= 1, m
     assert m["meshFallbacks"] == 0, m
+
+
+def test_spmd_stage_flag_set_on_one_shard_reruns_the_exact_variant():
+    """A NaN under the float sum of the fused-join stage's hash aggregate:
+    the mesh program's flag vector comes back set, the stage is
+    dispatched again in the exact variant, and the rows are the
+    oracle's."""
+    confs = {"spark.rapids.sql.variableFloatAgg.enabled": True}
+
+    def q(s):
+        right = s.create_dataframe({
+            "name": ["red", "green", "blue", None, "missing", ""],
+            "w": [1.5, float("nan"), 3.0, 4.0, 5.0, 6.0],
+        }, num_partitions=2)
+        return _left_df(s).join(right, on="name", how="inner").group_by(
+            "age").agg(F.sum(F.col("w")).alias("sw"),
+                       F.count(F.col("name")).alias("c"))
+
+    def rows(df):
+        return sorted(
+            (tuple("nan" if isinstance(x, float) and x != x else x
+                   for x in r) for r in df.collect()), key=repr)
+
+    want = rows(q(tpu_session(**{"spark.rapids.sql.enabled": False},
+                              **confs)))
+    assert any("nan" in r for r in want)
+    s = tpu_session(**{**SPMD_CONFS, **HASH_JOIN, **confs})
+    assert rows(q(s)) == want
+    m = s.last_metrics
+    assert m["meshJoinsFused"] >= 1 and m["meshFallbacks"] == 0, m
+    assert m["pipeline"]["flagReruns"] == 1, m["pipeline"]
+    assert sum(ms.get("hashAggFallback", 0) for ms in m.values()
+               if isinstance(ms, dict)) == 1
 
 
 def test_spmd_join_fused_economics():

@@ -77,10 +77,11 @@ def _widest_dimension(text):
 
 def test_keyless_aggregate_lowers_without_contraction_or_sort(monkeypatch,
                                                               tmp_path):
-    """A q6-shaped plan: the update program reduces (no one-hot
-    ``dot_general``), the merge tail sorts nothing and holds nothing wider
-    than its 64 concatenated rows.  With a grouping key the update program
-    keeps its contraction against ``table + 2`` slots."""
+    """A q6-shaped plan is ONE program: its update reduces (no one-hot
+    ``dot_general``), its merge sorts nothing, and it holds nothing wider
+    than an input batch — no 8,194-slot table, no 131,072-row concat.
+    With a grouping key the update program keeps its contraction against
+    ``table + 2`` slots."""
     from spark_rapids_tpu.kernels.hashagg import TABLE_SLOTS
     n_batches = 6
 
@@ -101,16 +102,14 @@ def test_keyless_aggregate_lowers_without_contraction_or_sort(monkeypatch,
         **{"spark.rapids.sql.reader.batchSizeRows": 3000 // n_batches})
     assert s.last_metrics["keylessAggBatches"] == n_batches
     assert s.last_metrics["mxuAggBatches"] == 0
-    stages = {n: t for n, t in texts.items() if n.startswith("stage_")}
-    update = next(t for t in stages.values()
-                  if "k.hashagg.keyless_aggregate" in t)
-    assert "dot_general" not in update and "stablehlo.sort" not in update
-    tails = [t for t in stages.values() if t is not update]
-    assert len(tails) == 1, list(texts)
-    (tail,) = tails
-    assert "k.groupby.groupby_aggregate" in tail
-    assert "stablehlo.sort" not in tail and "dot_general" not in tail
-    assert 0 < _widest_dimension(tail) <= 64, _widest_dimension(tail)
+    assert s.last_metrics["keylessUpdateBatches"] == n_batches
+    assert s.last_metrics["dispatchCount"] == 1
+    (fused,) = [t for n, t in texts.items() if n.startswith("stage_")]
+    assert "k.hashagg.keyless_aggregate" in fused       # the update
+    assert "k.groupby.groupby_aggregate" in fused       # and its merge
+    assert "dot_general" not in fused and "stablehlo.sort" not in fused
+    # an input batch (500 rows at capacity 512) is the widest thing in it
+    assert 64 < _widest_dimension(fused) <= 512, _widest_dimension(fused)
 
     with monkeypatch.context() as m:
         s2, keyed = lowered_stage_texts(
@@ -126,6 +125,33 @@ def test_keyless_aggregate_lowers_without_contraction_or_sort(monkeypatch,
     assert "k.hashagg.keyless_aggregate" not in update
     contraction = re.search(r"stablehlo\.dot_general.*", update)
     assert contraction and f"x{TABLE_SLOTS + 2}xf32>" in contraction.group(0)
+
+
+def test_keyless_batches_are_counted_by_every_dispatch(tmp_path):
+    """How many batches the inlined update handled is known when the
+    stage program is traced; a collect that traces nothing counts them
+    all the same, so ``keyless_reduce_pct`` reads 100 on every query."""
+    n_batches = 6
+    path = str(tmp_path / "kc.parquet")
+    s = tpu_session(**FLOAT_AGG, **{
+        "spark.rapids.sql.reader.batchSizeRows": 3000 // n_batches})
+    s.create_dataframe({
+        "kc_price": [float(100 + i % 50) for i in range(3000)],
+        "kc_discount": [0.01 * (i % 10) for i in range(3000)]}
+    ).write_parquet(path)
+    df = (s.read.parquet(path).filter(F.col("kc_discount") >= 0.05)
+          .agg(F.sum(F.col("kc_price") * F.col("kc_discount"))
+               .alias("revenue")))
+    first = df.collect()
+    for _ in range(2):      # the second and the third: nothing traced
+        assert df.collect() == first
+        m = s.last_metrics
+        assert m["compileCount"] == 0
+        assert m["keylessAggBatches"] == n_batches, m["keylessAggBatches"]
+        assert m["keylessUpdateBatches"] == n_batches
+        assert m["mxuAggBatches"] == 0
+        assert m["dispatchCount"] == 1
+        assert m["pipeline"]["inlinedUpdates"] == 1, m["pipeline"]
 
 
 def test_filter_operator_has_its_own_scope(monkeypatch):
