@@ -610,7 +610,7 @@ def host_sizes(batches: Sequence[ColumnBatch]) -> List[Tuple[int, List[int]]]:
     constant past num_rows by construction.
     """
     from spark_rapids_tpu.utils.compile_registry import guard_check
-    from spark_rapids_tpu.utils.tracing import device_read
+    from spark_rapids_tpu.utils.tracing import current_op, span
     guard_check(list(batches), "host_sizes")
 
     def _varlen_total(c):
@@ -624,12 +624,14 @@ def host_sizes(batches: Sequence[ColumnBatch]) -> List[Tuple[int, List[int]]]:
             return jnp.sum(jnp.where(c.validity, ent_lens[codes_c], 0))
         return c.offsets[-1]
 
-    scalars = [(b.num_rows,
-                [_varlen_total(c) for c in b.columns if c.is_varlen])
-               for b in batches]
-    # the scalars come out of programs still in flight: this read-back is
-    # where the host waits for the chip
-    host = device_read("host_sizes", scalars)
+    # the scalars come out of programs still in flight: slicing them out
+    # and this read-back are where the host waits for the chip (TPC-H Q1
+    # on a v5e sat 28.2 s in the slicing and 2 ms in the read-back)
+    with span("device_wait", "host_sizes", current_op()):
+        scalars = [(b.num_rows,
+                    [_varlen_total(c) for c in b.columns if c.is_varlen])
+                   for b in batches]
+        host = jax.device_get(scalars)
     return [(int(n), [int(t) for t in totals]) for n, totals in host]
 
 

@@ -187,14 +187,24 @@ class TpuProjectExec(TpuExec):
 
 
 class TpuFilterExec(TpuExec):
+    """Keeps the rows that pass ``condition`` by compacting the batch
+    (kernels/layout.compact: a gather of every column).  ``batch_fn``
+    goes wherever the planner fuses the filter (a consumer's absorbed
+    input, a fused map), so it is what notes the compaction to the stage
+    program that traces it (metric ``filterCompactedBatches``); a filter
+    moved into a keyless aggregate's arguments never becomes this
+    operator and compacts nothing."""
+
     def __init__(self, condition: Expression, child: PhysicalOp):
         super().__init__([child], child.output_schema)
         self.condition = condition
 
         def run(batch: ColumnBatch) -> ColumnBatch:
+            from spark_rapids_tpu.plan.pipeline import note_stage_batches
             ctx = TpuEvalCtx(batch)
             v = self.condition.tpu_eval(ctx)
             keep = v.validity & v.data.astype(jnp.bool_)
+            note_stage_batches(self, 1)
             return compact(batch, keep)
 
         self.batch_fn = run
@@ -202,6 +212,10 @@ class TpuFilterExec(TpuExec):
 
     def describe(self):
         return f"TpuFilter({self.condition!r})"
+
+    def stage_ran(self, ctx, batches: int, speculated: bool) -> None:
+        """A stage run that stands compacted ``batches`` batches here."""
+        ctx.metric(self.op_id, "filterCompactedBatches").add(batches)
 
     def pipeline_inline(self, ctx, build):
         cf = build(self.children[0])
@@ -671,10 +685,11 @@ class TpuHashAggregateExec(TpuExec):
     def _count_update_batches(self, ctx, n: int, fast: bool):
         """Update batches by the form that aggregated them: the slot
         contraction (``mxuAggBatches``, with keys) or the reduction
-        (``keylessAggBatches``, without), of ``keylessUpdateBatches`` a
-        keyless aggregate saw in all; ``fast`` False is the sort variant."""
-        if not self.key_exprs:
-            ctx.metric(self.op_id, "keylessUpdateBatches").add(n)
+        (``keylessAggBatches``, without), of the ``keyedUpdateBatches`` /
+        ``keylessUpdateBatches`` an aggregate with / without keys saw in
+        all; ``fast`` False is the sort variant."""
+        ctx.metric(self.op_id, "keyedUpdateBatches" if self.key_exprs
+                   else "keylessUpdateBatches").add(n)
         if fast:
             ctx.metric(self.op_id, "mxuAggBatches" if self.key_exprs
                        else "keylessAggBatches").add(n)

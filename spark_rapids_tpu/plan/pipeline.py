@@ -163,12 +163,15 @@ def note_stage_batches(op: PhysicalOp, batches: int, flag=None) -> None:
     that did.  The program returns the flags beside its batches and the
     host reads them where the outputs are handed on (module docstring).
     Outside a collecting program body nobody would read the flag, and an
-    unread flag is a wrong answer waiting: that is an error."""
+    unread flag is a wrong answer waiting: that is an error.  A note
+    with no flag is a count only, and outside a stage program (an
+    operator's own per-batch program on the eager route) it is dropped."""
     sink = getattr(_STAGE_NOTES, "sink", None)
-    if sink is None:
+    if sink is not None:
+        sink.append((op, batches, flag))
+    elif flag is not None:
         raise RuntimeError(
-            f"{op.name}: stage note outside a stage program's trace")
-    sink.append((op, batches, flag))
+            f"{op.name}: stage flag outside a stage program's trace")
 
 
 @contextlib.contextmanager
@@ -667,7 +670,8 @@ def _stands(run: StageRun, flags, ctx: ExecContext) -> bool:
     inlined = 0
     for op, n, speculated in run.notes:
         op.stage_ran(ctx, n, speculated)
-        inlined += op is not run.root
+        # an update below the root (a filter notes compactions only)
+        inlined += op is not run.root and hasattr(op, "stage_variant")
     if inlined:
         ctx.metric("pipeline", "inlinedUpdates").add(inlined)
     return True

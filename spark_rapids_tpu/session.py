@@ -558,9 +558,11 @@ class TpuSparkSession:
                        if key in ms)
         # aggregate economics (TpuHashAggregateExec): update batches that
         # took the slot contraction (keyed) or the reduction (keyless), of
-        # the update batches keyless aggregates saw in all
-        for key in ("mxuAggBatches", "keylessAggBatches",
-                    "keylessUpdateBatches"):
+        # the update batches keyed / keyless aggregates saw in all; and
+        # the batches a TpuFilterExec compacted (kernels/layout.compact)
+        for key in ("mxuAggBatches", "keyedUpdateBatches",
+                    "keylessAggBatches", "keylessUpdateBatches",
+                    "filterCompactedBatches"):
             frame.last_metrics[key] = _scan_sum(key)
         frame.last_metrics["scanDecodeWallNs"] = _scan_sum("scanDecodeWallNs")
         frame.last_metrics["scanH2dOverlapNs"] = _scan_sum("scanH2dOverlapNs")

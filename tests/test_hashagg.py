@@ -408,12 +408,27 @@ def test_keyless_plan_counts_reductions_and_keyed_plan_contractions():
     assert _flat(tpu, "keylessAggBatches") == \
         _flat(tpu, "keylessUpdateBatches")
     assert _flat(tpu, "mxuAggBatches") == 0 and not _mxu_engaged(tpu)
+    assert _flat(tpu, "keyedUpdateBatches") == 0
     assert not any(a._hash_disabled for a in _update_aggs(tpu))
 
     _q(tpu, data).collect()     # the same columns, grouped by k
     assert _flat(tpu, "mxuAggBatches") > 0 and _mxu_engaged(tpu)
     assert _flat(tpu, "keylessAggBatches") == 0
     assert _flat(tpu, "keylessUpdateBatches") == 0
+
+
+@pytest.mark.parametrize("mxu", [True, False])
+def test_keyed_update_batches_count_the_sort_variant_too(mxu):
+    """``keyedUpdateBatches`` is every update batch a keyed aggregate saw;
+    ``mxuAggBatches`` only those the slot contraction took."""
+    conf = dict(FLOAT_AGG, **{"spark.rapids.sql.agg.mxuHash.enabled": mxu})
+    tpu = tpu_session(**conf)
+    _q(tpu, _data(n=3000)).collect()
+    assert _flat(tpu, "keyedUpdateBatches") > 0, tpu.last_metrics
+    assert _flat(tpu, "mxuAggBatches") == \
+        (_flat(tpu, "keyedUpdateBatches") if mxu else 0)
+    assert _flat(tpu, "keylessUpdateBatches") == 0
+    assert _flat(tpu, "filterCompactedBatches") == 0    # no filter
 
 
 @pytest.mark.parametrize("mxu", [True, False])

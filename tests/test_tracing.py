@@ -154,6 +154,46 @@ def test_keyless_batches_are_counted_by_every_dispatch(tmp_path):
         assert m["pipeline"]["inlinedUpdates"] == 1, m["pipeline"]
 
 
+@pytest.mark.parametrize("key,mxu", [("kd_code", True), ("kd_code", False),
+                                     ("kd_flag", True), (None, True)])
+def test_keyed_and_compacted_batches_are_counted_by_every_dispatch(
+        tmp_path, key, mxu):
+    """A keyed aggregate's update batches (``keyedUpdateBatches``; of them
+    ``mxuAggBatches`` through the slot contraction: all with an integer
+    key, none in the sort variant, which a string key always takes) and
+    the batches its filter compacted (``filterCompactedBatches``) are
+    noted when the update stage is traced and counted by every collect.
+    A keyless plan's filter sits inside the aggregate's arguments: it
+    compacts nothing and both counters stay 0."""
+    n_batches = 6
+    path = str(tmp_path / "kd.parquet")
+    s = tpu_session(**FLOAT_AGG, **{
+        "spark.rapids.sql.agg.mxuHash.enabled": mxu,
+        "spark.rapids.sql.reader.batchSizeRows": 3000 // n_batches})
+    s.create_dataframe({
+        "kd_flag": ["ANR"[i % 3] for i in range(3000)],
+        "kd_code": [i % 3 for i in range(3000)],
+        "kd_price": [float(100 + i % 50) for i in range(3000)],
+        "kd_discount": [0.01 * (i % 10) for i in range(3000)]}
+    ).write_parquet(path)
+    df = s.read.parquet(path).filter(F.col("kd_discount") >= 0.05)
+    total = F.sum(F.col("kd_price") * F.col("kd_discount")).alias("revenue")
+    df = df.group_by(key).agg(total).order_by(key) if key else df.agg(total)
+    first = df.collect()
+    keyed = n_batches if key else 0
+    for _ in range(2):      # the second and the third: nothing traced
+        assert df.collect() == first
+        m = s.last_metrics
+        assert m["compileCount"] == 0
+        assert m["keyedUpdateBatches"] == keyed, m["keyedUpdateBatches"]
+        assert m["mxuAggBatches"] == \
+            (keyed if mxu and key == "kd_code" else 0)
+        assert m["filterCompactedBatches"] == keyed
+        assert m["keylessUpdateBatches"] == n_batches - keyed
+        # the keyed update is its stage's root; the filter notes no update
+        assert ("inlinedUpdates" in m["pipeline"]) == (key is None)
+
+
 def test_filter_operator_has_its_own_scope(monkeypatch):
     """Where a filter stays an operator of its own, ``TpuFilterExec.<k>``
     is on its operations, beside the compaction kernel's scope."""
@@ -706,9 +746,20 @@ def test_benchmark_json_gained_only_the_seven_entries():
         assert m["source"] == "program_counter"
     assert names[first + 7:] == ["shape_hit_pct", "parse_ms", "bind_ms",
                                  "setup_variant_compiles",
-                                 "keyless_reduce_pct"]
+                                 "keyless_reduce_pct",
+                                 # PR 34's five, each listing its cells
+                                 "scan_decode_ms", "scan_bytes_per_row",
+                                 "scan_overlap_pct", "keyed_contraction_pct",
+                                 "compacted_batches_per_query"]
+    q6_cells = ["tpch_sf1_cached.q6", "tpch_sf1_qgen.q6_text",
+                "tpch_sf1_parquet.q6"]       # those with a keyless aggregate
+    for m in bench["per_layer"][first:first + 12]:
+        assert m.get("workloads", ["tpch_sf1_qgen.q6_text"]) == (
+            q6_cells if m["name"] == "keyless_reduce_pct"
+            else ["tpch_sf1_qgen.q6_text"])
+    for m in bench["per_layer"][first + 12:]:
+        assert set(m["workloads"]) <= {"tpch_sf1_cached.q1",
+                                       "tpch_sf1_parquet.q6"}
     for m in bench["per_layer"][first:]:
-        assert m.get("workloads", ["tpch_sf1_qgen.q6_text"]) == \
-            ["tpch_sf1_qgen.q6_text"]
         assert os.path.exists(os.path.join(
             REPO_ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
