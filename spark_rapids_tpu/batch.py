@@ -244,9 +244,15 @@ class DeviceColumn:
     ``codes`` — int32[cap] indices into the dictionary entries that
     data/offsets then describe — plus the static ``mat_byte_cap``: the
     byte-capacity bucket the column occupies once materialized
-    (``kernels.layout.dict_decode_column``).  Encoded columns exist only
-    between scan staging and the first consuming operator; every exec
-    materializes at entry unless it is explicitly encode-aware.
+    (``kernels.layout.dict_decode_column``).  An encoded column stays
+    encoded from scan staging through every operator that only moves or
+    drops whole rows and says so — a filter's compaction, the dict-aware
+    shuffle, the cache — until an operator consumes it: every exec
+    materializes at entry (``DevVal.from_column``, ``ensure_row_layout``,
+    ``device_to_host``) unless it is explicitly encode-aware (string
+    equality and ``LIKE``, group keys, dict-key joins, ``Count``).  Codes
+    name entries of THIS column's dictionary only; dictionaries differ
+    batch to batch.
     """
 
     def __init__(self, dtype: T.DataType, data, validity, offsets=None,

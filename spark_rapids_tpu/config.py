@@ -424,7 +424,8 @@ HASH_AGG_MXU_ENABLED = conf_bool(
     "spark.rapids.sql.agg.mxuHash.enabled", True,
     "Aggregate update batches on the MXU via slot one-hot contractions "
     "when the agg list is sum/count/avg/min/max/first/last and the group "
-    "keys are integral/date/bool columns (multi-key via mixed-radix slot "
+    "keys are integral/date/bool columns or dictionary-encoded string "
+    "columns, grouped by their codes (multi-key via mixed-radix slot "
     "packing): one matmul (plus a scatter pass for min/max-class aggs) "
     "replaces the sort-based groupby's argsort + gathers + scatters.  "
     "Batches whose packed key space exceeds the slot table (or float "

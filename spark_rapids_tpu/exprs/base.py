@@ -318,13 +318,24 @@ class BoundRef(Expression):
         return CpuVal.from_column(ctx.batch.columns[self.ordinal])
 
 
+def _unaliased(expr: "Expression") -> "Expression":
+    while isinstance(expr, Alias):
+        expr = expr.children[0]
+    return expr
+
+
+def may_stay_encoded(expr: "Expression") -> bool:
+    """Static: ``eval_maybe_encoded`` can hand ``expr`` on encoded — it is
+    a bare column reference, so it is whatever its column is."""
+    return isinstance(_unaliased(expr), (ColumnRef, BoundRef))
+
+
 def eval_maybe_encoded(expr: "Expression", ctx: TpuEvalCtx) -> DevVal:
     """Evaluate ``expr``, keeping dictionary encoding when it is a bare
     column reference.  Only hash/eq-based consumers (string equality
     predicates, group keys) may call this — every other path goes through
     ``tpu_eval`` → ``from_column`` which materializes."""
-    while isinstance(expr, Alias):
-        expr = expr.children[0]
+    expr = _unaliased(expr)
     if isinstance(expr, ColumnRef):
         return DevVal.from_column_encoded(ctx.batch.column(expr.column))
     if isinstance(expr, BoundRef):

@@ -13,10 +13,21 @@ segmented reduction:
 Slotting by the key's own value range makes slot <-> key a bijection —
 no hash, no collisions, no purity machinery, and the output key columns
 are reconstructed from slot indices without touching the input again.
-Multi-column keys pack into ONE slot index by mixed radix: each
-integral/date/bool key contributes a digit (its offset from the batch
-minimum, plus a NULL digit when the column has NULLs) and the product of
-radices must fit the table.  A batch whose packed key space exceeds the
+Multi-column keys pack into ONE slot index by mixed radix: each key
+contributes a digit (its offset from the batch minimum, plus a NULL digit
+when the column has NULLs) and the product of radices must fit the table.
+An integral/date/bool key's digit comes from its value; a string key's
+from its dictionary ``codes``, when the column arrives dictionary-encoded
+(scan v2, docs/io.md) — a code is an integer that names the key within
+ITS batch, which is all a per-batch slot needs.  The output key column is
+then the slot's code looked up in the batch's own dictionary and
+materialized, so partials leave in row layout as the sort form's do and
+codes of two batches are never compared (a dictionary with duplicate
+entries merely leaves two partial groups for the merge to combine).  With
+every key encoded the dictionaries bound the packed key space statically
+and the table is no wider than that bound.  A string key that arrives
+plain has no digit: the caller takes the sort form for that batch
+(``keys_are_digits``).  A batch whose packed key space exceeds the
 table (or holds non-finite floats for a float sum) raises a
 device-visible flag and the caller re-runs the exact sort path —
 correctness never depends on data shape.
@@ -49,6 +60,7 @@ for the MXU instead of a GPU hash table.
 
 from __future__ import annotations
 
+import math
 from typing import List, Sequence, Tuple
 
 import jax
@@ -57,15 +69,18 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.batch import (
-    ColumnBatch, DeviceColumn, round_up_capacity,
+    BUCKETS, ColumnBatch, DeviceColumn, round_up_capacity,
 )
 from spark_rapids_tpu.exprs.base import DevVal
 from spark_rapids_tpu.kernels.groupby import one_group_output
-from spark_rapids_tpu.kernels.layout import compaction_indices
+from spark_rapids_tpu.kernels.layout import (
+    compaction_indices, dict_decode_column,
+)
 from spark_rapids_tpu.utils.tracing import kernel_scope
 
 TABLE_SLOTS = 8192          # key-range capacity of the slot table
 _CHUNK = 16384              # rows per exact-f32 accumulation chunk
+_LANES = 128                # the one-hot's minor dimension tiles by this
 _SIGN32 = np.uint32(0x80000000)
 
 
@@ -236,6 +251,33 @@ def _buffers(per_chunk, agg_plan, agg_fns: Sequence, slot, live, ng: int):
     return totals_i[0], buffer_cols
 
 
+def keys_are_digits(key_vals: Sequence[DevVal]) -> bool:
+    """Trace-time half of the capability check: every key the batch
+    brought can be a digit of the slot index — a fixed-width value, or a
+    string that still carries its dictionary ``codes``.  A plain string
+    key (a computed key, a format or a join that delivered row layout)
+    cannot, and its batch takes the sort form."""
+    return all(kv.codes is not None or not kv.dtype.is_string
+               for kv in key_vals)
+
+
+def _key_entries(kv: DevVal) -> int:
+    """Static count of an encoded key's dictionary entries (padding too)."""
+    return int(kv.offsets.shape[0]) - 1
+
+
+def _dictionary_table(key_vals: Sequence[DevVal], table: int) -> int:
+    """``table``, or less where dictionaries bound the packed key space:
+    with every key encoded no batch can pack past prod(entries + 1)
+    (+1: the NULL digit), a static number, so slots beyond it would only
+    widen the one-hot.  The narrowed table keeps ``table + 2`` a multiple
+    of the lane width."""
+    if not key_vals or any(kv.codes is None for kv in key_vals):
+        return table
+    space = math.prod(_key_entries(kv) + 1 for kv in key_vals)
+    return min(table, -(-(space + 1) // _LANES) * _LANES - 2)
+
+
 @kernel_scope
 def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
                          agg_inputs: List[DevVal], agg_fns: Sequence,
@@ -247,7 +289,11 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
     Buffer layout matches the sort-based update path (consumed unchanged
     by the merge stage).  ``fallback`` True means the key range did not
     fit the slot table (or a float sum saw non-finite values) — the
-    caller MUST discard the result and use the sort path."""
+    caller MUST discard the result and use the sort path.  Every key
+    must be a digit (``keys_are_digits``): an encoded string key groups
+    by its codes and leaves as a row-layout string column."""
+    assert keys_are_digits(key_vals)
+    table = _dictionary_table(key_vals, table)
     cap = batch.capacity
     c = min(_CHUNK, cap)
     nc = cap // c
@@ -265,7 +311,7 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
     prod_f = jnp.float64(1.0)
     key_decode = []  # (kmin, rng, radix, stride) per key, for output
     for kv in key_vals:
-        kx = kv.data.astype(jnp.int64)
+        kx = (kv.data if kv.codes is None else kv.codes).astype(jnp.int64)
         usek = live & kv.validity
         any_key = jnp.any(usek)
         has_null = jnp.any(live & ~kv.validity)
@@ -311,12 +357,29 @@ def hash_group_aggregate(batch: ColumnBatch, key_vals: List[DevVal],
     idx_p = jnp.pad(idx, (0, out_cap - idx.shape[0]))
     live_out = jnp.arange(out_cap, dtype=jnp.int32) < n_groups
     key_cols = []
-    for kf, (kmin, rng, radix, stride) in zip(key_schema.fields,
-                                              key_decode):
+    spaces = [ng if kv.codes is None else _key_entries(kv) + 1
+              for kv in key_vals]           # static bound on each radix
+    for i, (kf, kv, (kmin, rng, radix, stride)) in enumerate(
+            zip(key_schema.fields, key_vals, key_decode)):
         d = (idx_p.astype(jnp.int64) // stride) % radix
-        key_data = (kmin + d).astype(kf.dtype.jnp_dtype)
         key_valid = (d < rng) & live_out
-        key_cols.append(DeviceColumn(kf.dtype, key_data, key_valid, None))
+        if kv.codes is None:
+            key_data = (kmin + d).astype(kf.dtype.jnp_dtype)
+            key_cols.append(DeviceColumn(kf.dtype, key_data, key_valid,
+                                         None))
+            continue
+        # the slot's code, looked up in THIS batch's dictionary.  Bytes:
+        # an entry recurs once a combination of the other keys' digits
+        # and in no more rows than there are slots; and a group's key is
+        # some row's key, so the rows' own total bounds them too
+        others = min(ng, math.prod(spaces[:i] + spaces[i + 1:]))
+        nbytes = others * int(kv.data.shape[0])
+        if kv.mat_byte_cap > 0:
+            nbytes = min(nbytes, kv.mat_byte_cap)
+        codes = jnp.where(key_valid, kmin + d, 0).astype(jnp.int32)
+        key_cols.append(dict_decode_column(DeviceColumn(
+            kf.dtype, kv.data, key_valid, kv.offsets, codes,
+            BUCKETS.elems(nbytes))))
     group_keys = ColumnBatch(key_schema, key_cols, n_groups, out_cap)
 
     def _pad(a):
@@ -360,17 +423,22 @@ def keyless_aggregate(batch: ColumnBatch, agg_inputs: List[DevVal],
 
 def hash_agg_capable(mode: str, key_types: List[T.DataType],
                      agg_fns: Sequence) -> bool:
-    """Static capability check: the MXU path covers sum/count/avg (einsum
-    limb rows) plus min/max/first/last (slot scatter-reduce) over
-    fixed-width inputs, grouped by any number of integral/date/bool keys
-    (mixed-radix slot packing) or no key (global reduction)."""
+    """Static capability check, on types alone: the MXU path covers
+    sum/count/avg (einsum limb rows) plus min/max/first/last (slot
+    scatter-reduce) over fixed-width inputs, grouped by any number of
+    integral/date/bool/string keys (mixed-radix slot packing) or no key
+    (global reduction).  A string key is a digit only while its column
+    carries dictionary codes, which no type says: that half is
+    ``keys_are_digits``, asked of each batch when its program is
+    traced."""
     from spark_rapids_tpu.exprs.aggregates import (
         Average, Count, First, Last, Max, Min, Sum,
     )
     if mode != "update":
         return False
     for kt in key_types:
-        if not (kt.is_integral or kt in (T.DATE, T.BOOLEAN)):
+        if not (kt.is_integral or kt.is_string or
+                kt in (T.DATE, T.BOOLEAN)):
             return False
     for fn in agg_fns:
         if type(fn) in (Sum, Average, Min, Max, First, Last):

@@ -197,11 +197,15 @@ def compaction_indices(mask, num_rows):
 
 
 @kernel_scope
-def compact(batch: ColumnBatch, mask) -> ColumnBatch:
+def compact(batch: ColumnBatch, mask,
+            keep_encoded: bool = False) -> ColumnBatch:
     """Filter: keep rows where mask (bool[cap]) is True.  Single-phase —
-    output capacity = input capacity (a filter can only shrink)."""
+    output capacity = input capacity (a filter can only shrink), which is
+    also why ``keep_encoded`` is always valid here: a dictionary-encoded
+    column leaves with its codes compacted and its dictionary and
+    ``mat_byte_cap`` as they came (``gather_rows``)."""
     indices, count = compaction_indices(mask, batch.num_rows)
-    return gather_rows(batch, indices, count)
+    return gather_rows(batch, indices, count, keep_encoded=keep_encoded)
 
 
 def take_head(batch: ColumnBatch, limit) -> ColumnBatch:

@@ -193,7 +193,18 @@ class TpuFilterExec(TpuExec):
     input, a fused map), so it is what notes the compaction to the stage
     program that traces it (metric ``filterCompactedBatches``); a filter
     moved into a keyless aggregate's arguments never becomes this
-    operator and compacts nothing."""
+    operator and compacts nothing.
+
+    Encode-transparent: a dictionary-encoded string column leaves as it
+    came, its 4-byte codes compacted and its dictionary buffers shared
+    (a filter cannot grow the materialized total), so an encode-aware
+    consumer above — a group key, a dict-key join, the dict-aware
+    shuffle — still finds the codes.  What the filter hands on is what a
+    scan hands on, and is held to the same rule: a consumer that is not
+    encode-aware materializes at its own entry (``DevVal.from_column``
+    for every expression, ``ensure_row_layout`` in the row-movement and
+    join kernels, ``device_to_host`` at collection); the filter decodes
+    nothing on anyone's behalf."""
 
     def __init__(self, condition: Expression, child: PhysicalOp):
         super().__init__([child], child.output_schema)
@@ -205,7 +216,7 @@ class TpuFilterExec(TpuExec):
             v = self.condition.tpu_eval(ctx)
             keep = v.validity & v.data.astype(jnp.bool_)
             note_stage_batches(self, 1)
-            return compact(batch, keep)
+            return compact(batch, keep, keep_encoded=True)
 
         self.batch_fn = run
         self._run = plan_jit(run, label="TpuFilter")
@@ -505,13 +516,26 @@ def _buffer_schema(key_names: List[str], keys: List[Expression],
 
 
 class TpuHashAggregateExec(TpuExec):
-    """Sort-based groupby aggregation, two-mode (update/merge) like the
-    reference's Partial/Final plumbing (aggregate.scala:420-524).
+    """Groupby aggregation, two-mode (update/merge) like the reference's
+    Partial/Final plumbing (aggregate.scala:420-524).
 
     mode="update": raw rows -> per-partition partial batch
                    (group keys + agg buffers).
     mode="merge":  partial batches (post-exchange) -> merged groups ->
                    finalized output projection.
+
+    Two forms of the grouping, one partial layout.  The sort form
+    (kernels/groupby: argsort by key, segment, reduce) takes any key and
+    is every merge.  A keyed update whose aggregates are inside
+    ``hash_agg_capable`` and whose keys can each be a digit takes the slot
+    contraction instead (kernels/hashagg.hash_group_aggregate; metric
+    ``mxuAggBatches`` of the ``keyedUpdateBatches`` it saw): an
+    integral/date/bool key by its value, a string key by its dictionary
+    codes.  Whether a string key still carries codes is not a property of
+    the plan (a format, a projection or a join may have delivered row
+    layout), so it is asked of each batch as its program is traced
+    (``hashagg.keys_are_digits``): a batch whose string key arrives plain
+    takes the sort form, speculates nothing and raises no flag.
 
     No key expression (``sum(x)`` with no GROUP BY): one group, so neither
     mode groups anything.  An update batch is reduced
@@ -553,9 +577,13 @@ class TpuHashAggregateExec(TpuExec):
         ])
         self.buffer_schemas = [[s.dtype for s in a.fn.buffers()]
                                for a in aggs]
+        from spark_rapids_tpu.exprs.base import may_stay_encoded
         from spark_rapids_tpu.kernels.hashagg import hash_agg_capable
+        # a computed string key is never encoded: such a plan names no
+        # fast variant at all (no speculation, sources stay donatable)
         self._hash_capable = hash_agg_capable(
-            mode, [e.dtype for e in key_exprs], [a.fn for a in aggs])
+            mode, [e.dtype for e in key_exprs], [a.fn for a in aggs]) and \
+            all(may_stay_encoded(e) for e in key_exprs if e.dtype.is_string)
         self._hash_disabled = False  # sticky off after a collided batch
         from spark_rapids_tpu.kernels.hashagg import TABLE_SLOTS
         self._mxu_table = TABLE_SLOTS  # refreshed from conf in _hash_active
@@ -663,12 +691,19 @@ class TpuHashAggregateExec(TpuExec):
                 # program (seconds at 16M).  Keyless, they are one row
                 # each and the consumer's merge follows in this program.
                 if use_hash:
-                    outs, ncoll = [], jnp.asarray(0, jnp.int32)
+                    outs, fast, ncoll = [], 0, jnp.asarray(0, jnp.int32)
                     for b in batches:
                         p, fl = self._aggregate_batch_hash(b)
                         outs.append(p)
-                        ncoll = ncoll + fl.astype(jnp.int32)
-                    note_stage_batches(self, len(batches), ncoll)
+                        if fl is not None:
+                            fast += 1
+                            ncoll = ncoll + fl.astype(jnp.int32)
+                    # batches whose string key arrived plain took the
+                    # sort form: counted, and nothing of theirs to flag
+                    if fast:
+                        note_stage_batches(self, fast, ncoll)
+                    if fast < len(batches):
+                        note_stage_batches(self, len(batches) - fast)
                     return outs
                 note_stage_batches(self, len(batches))
                 return [self._aggregate_batch(b) for b in batches]
@@ -698,8 +733,10 @@ class TpuHashAggregateExec(TpuExec):
 
     def _eval_keys(self, batch) -> List[DevVal]:
         if self.mode == "update":
-            # String group keys stay dictionary-encoded when the scan
-            # delivered them that way: the sort-based grouping only needs
+            # String group keys stay dictionary-encoded when they arrive
+            # that way (from a scan, or through filters and the dict-aware
+            # shuffle above one): the slot contraction groups by the codes
+            # themselves, and the sort-based grouping only needs
             # lengths/hashes/prefixes, all of which gather through the
             # codes, so the dictionary is hashed once instead of per row.
             from spark_rapids_tpu.exprs.base import eval_maybe_encoded
@@ -764,16 +801,20 @@ class TpuHashAggregateExec(TpuExec):
         limb rows are reduced, not contracted (``keyless_aggregate``).
         flag=True means the result is INVALID (key range exceeded the slot
         table, or a float sum saw NaN/Inf) and the caller must re-run the
-        sort path."""
+        sort path.  flag None: a string key of this batch arrived without
+        codes, so the partial IS the sort form's and stands as it is."""
         from spark_rapids_tpu.kernels.hashagg import (
-            hash_group_aggregate, keyless_aggregate,
+            hash_group_aggregate, keyless_aggregate, keys_are_digits,
         )
+        key_vals = self._eval_keys(batch)
+        if not keys_are_digits(key_vals):
+            return self._aggregate_batch(batch), None
         ctx = TpuEvalCtx(batch)
         agg_inputs = [self._eval_agg_input(a.fn, ctx) for a in self.aggs]
         fns = [a.fn for a in self.aggs]
         if self.key_exprs:
             group_keys, buffers, _, flagged = hash_group_aggregate(
-                batch, self._eval_keys(batch), agg_inputs, fns,
+                batch, key_vals, agg_inputs, fns,
                 self.key_schema, self.output_schema, table=self._mxu_table)
         else:
             group_keys, buffers, flagged = keyless_aggregate(
@@ -864,10 +905,14 @@ class TpuHashAggregateExec(TpuExec):
         path, and the fast path turns off for this exec."""
         if self._hash_active(ctx):
             pairs = [self._run_hash(db) for db in batches]
-            flags = device_read("hashagg_flags", [f for _, f in pairs]) \
-                if pairs else []
-            if not any(bool(f) for f in flags):
-                self._count_update_batches(ctx, len(pairs), fast=True)
+            # a batch whose string key arrived plain comes back in the
+            # sort form with no flag (``_aggregate_batch_hash``)
+            flags = [f for _, f in pairs if f is not None]
+            if not flags or not any(
+                    bool(f) for f in device_read("hashagg_flags", flags)):
+                self._count_update_batches(ctx, len(flags), fast=True)
+                self._count_update_batches(ctx, len(pairs) - len(flags),
+                                           fast=False)
                 return [p for p, _ in pairs]
             self._hash_disabled = True
             ctx.metric(self.op_id, "hashAggFallback").add(1)

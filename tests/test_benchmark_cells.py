@@ -64,11 +64,12 @@ def test_new_cell_is_correct_and_drives_what_it_measures(
             assert c["filterCompactedBatches"] == 0
             assert c["keyedUpdateBatches"] == 0
         else:
-            # cached tables decode nothing; a string key takes the sort
-            # form of the keyed update, under a filter that compacts
+            # cached tables decode nothing; the two string keys arrive
+            # dictionary-encoded through a filter that compacts their
+            # codes, so every update batch groups on the slot contraction
             assert c["scanDecodeWallNs"] == 0 and c["scanBytesDecoded"] == 0
             assert c["keyedUpdateBatches"] > 0
-            assert c["mxuAggBatches"] <= c["keyedUpdateBatches"]
+            assert c["mxuAggBatches"] == c["keyedUpdateBatches"]
             assert c["filterCompactedBatches"] > 0
             assert c["keylessUpdateBatches"] == 0
     if cell == "tpch_sf1_parquet.q6":
@@ -79,7 +80,7 @@ def test_new_cell_is_correct_and_drives_what_it_measures(
         assert read("compacted_batches_per_query") == 0
         assert read("keyed_contraction_pct") is None
     else:
-        assert read("keyed_contraction_pct") == 0       # the sort form
+        assert read("keyed_contraction_pct") == 100     # by the codes
         assert read("compacted_batches_per_query") >= 1
         assert read("scan_bytes_per_row") is None
         assert read("keyless_reduce_pct") is None

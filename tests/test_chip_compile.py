@@ -253,3 +253,49 @@ def test_keyless_update_batch_compiles_to_reductions(on_chip):
     assert " dot(" not in text and " convolution(" not in text
     assert "8194" not in entry
     assert " reduce(" in text or "reduce_fusion" in entry
+
+
+def test_q1_update_batch_compiles_to_a_narrow_contraction(on_chip):
+    """Q1's update batch at SF1's shape — a million rows, the filter's
+    compaction, two dictionary-encoded string keys of a few entries,
+    seven DOUBLE sums/averages and a count — through
+    ``hash_group_aggregate``: the v5e program sorts nothing, and its slot
+    table is the one lane tile the dictionaries allow, not 8,194 wide."""
+    from spark_rapids_tpu.batch import ColumnBatch, DeviceColumn
+    from spark_rapids_tpu.exprs.aggregates import (
+        Average, Sum, count_star,
+    )
+    from spark_rapids_tpu.exprs.base import ColumnRef
+    from spark_rapids_tpu.kernels.hashagg import hash_group_aggregate
+    from spark_rapids_tpu.kernels.layout import compact
+    cap, entries, n_f = BRANCH_ROWS, 8, 4
+    D = [ColumnRef(f"d{i}", T.DOUBLE) for i in range(n_f)]
+    fns = [Sum(D[0]), Sum(D[1]), Sum(D[2]), Sum(D[3]), Average(D[0]),
+           Average(D[1]), Average(D[2]), count_star()]
+    keys = T.Schema([("flag", T.STRING), ("status", T.STRING)])
+    cols = T.Schema(list(keys.fields) + [(d.column, T.DOUBLE) for d in D])
+
+    def update(dict_bytes, dict_offsets, codes_a, codes_b, ok, keep, rows,
+               *doubles):
+        batch = compact(ColumnBatch(cols, [
+            DeviceColumn(T.STRING, dict_bytes, ok, dict_offsets, c, cap)
+            for c in (codes_a, codes_b)] + [
+            DeviceColumn(T.DOUBLE, d, ok) for d in doubles], rows, cap),
+            keep, keep_encoded=True)
+        vals = [DevVal.from_column(c) for c in batch.columns[2:]]
+        inputs = vals + vals[:3] + [DevVal(
+            T.INT, jnp.ones(cap, jnp.int32), jnp.ones(cap, jnp.bool_))]
+        group_keys, bufs, n, flag = hash_group_aggregate(
+            batch, [DevVal.from_column_encoded(c)
+                    for c in batch.columns[:2]], inputs, fns, keys, keys)
+        return ([(c.data, c.validity, c.offsets) for c in group_keys.columns],
+                [(b.data, b.validity) for bs in bufs for b in bs], n, flag)
+
+    i32, flags = on_chip((cap,), jnp.int32), on_chip((cap,), jnp.bool_)
+    text = _compile(
+        update, on_chip((16,), jnp.uint8), on_chip((entries + 1,), jnp.int32),
+        i32, i32, flags, flags, on_chip((), jnp.int32),
+        *[on_chip((cap,), jnp.float64)] * n_f).as_text()
+    assert " sort(" not in text
+    assert "8194" not in text[text.index("ENTRY"):]
+    assert " convolution(" in text or " dot(" in text   # the contraction

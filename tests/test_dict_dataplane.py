@@ -323,6 +323,77 @@ def test_encoded_join_warm_repeat_compiles_nothing(tmp_path):
     assert s.last_metrics.get("compileCount", 0) == 0, s.last_metrics
 
 
+# -- a filter hands on what a scan hands on: codes ----------------------------
+
+
+def _after_filter(consumer):
+    """A query whose filter sits over encoded columns, under ``consumer``."""
+    def q(s, out, dim):
+        df = s.read.parquet(out).filter(F.col("l") >= 30)
+        if consumer == "collect":
+            return df
+        if consumer == "project":       # the bytes of every kept row
+            return df.select(F.concat(F.col("s"), F.lit("|")).alias("t"),
+                             F.length(F.col("s")).alias("n"), F.col("i"))
+        if consumer == "sort":
+            return df.order_by("s", "l")
+        if consumer == "limit":
+            return df.order_by("l", "s").limit(50)
+        if consumer == "union":
+            return df.union(s.read.parquet(out).filter(F.col("i") == 1))
+        if consumer == "string_predicate":  # a second filter, on the string
+            return df.filter(F.col("s") != "bb")
+        assert consumer.startswith("join")
+        return df.join(s.read.parquet(dim).filter(F.col("w") < 40), on="s")
+    return q
+
+
+@pytest.mark.parametrize("consumer", [
+    "collect", "project", "sort", "limit", "union", "string_predicate",
+    "join", "join_dict_keys_off", "join_dict_shuffle_off"])
+def test_filter_keeps_codes_and_every_consumer_sees_its_rows(tmp_path,
+                                                             consumer):
+    """``TpuFilterExec`` compacts an encoded column's codes and decodes
+    nothing; ``DeviceColumn``'s invariant holds all the same — a consumer
+    that is not encode-aware materializes at its own entry, as it does
+    over a scan — so every consumer returns the oracle's rows."""
+    out = _write_dict_parquet(tmp_path, "fact")
+    dim = _write_dict_parquet(tmp_path, "dim", {
+        "s": (T.STRING, ["bb", "cc", "zz", None, ""] * 4),
+        "w": (T.INT, list(range(20)) * 1)}, rows_per_group=7)
+    q = _after_filter(consumer)
+    confs = {"spark.sql.autoBroadcastJoinThreshold": -1,
+             "spark.sql.shuffle.partitions": 3}
+    if consumer == "join_dict_keys_off":
+        confs.update(JOIN_KEYS_OFF)
+    if consumer == "join_dict_shuffle_off":
+        confs.update(DICT_AWARE_OFF)
+    s = _v2_session(**confs)
+    got = _rows(s, lambda s2: q(s2, out, dim))
+    want = _rows(_cpu_session(), lambda s2: q(s2, out, dim))
+    assert got == want and len(want) > 0
+    m = s.last_metrics
+    assert m.get("scanDictColumns", 0) > 0, m   # the filter saw codes
+
+    # and the filter itself: codes in, codes out, the dictionary shared
+    from spark_rapids_tpu.ops.tpu_exec import TpuFilterExec
+    from spark_rapids_tpu.plan.physical import PhysicalOp
+    from spark_rapids_tpu.exprs.base import ColumnRef, Literal
+    from spark_rapids_tpu.exprs.predicates import GreaterThanOrEqual
+    db = _encoded_batch(["aa", None, "bb", "aa", "cc"], {
+        "l": pa.array([10, 30, 40, None, 50], type=pa.int64())})
+    flt = TpuFilterExec(
+        GreaterThanOrEqual(ColumnRef("l", T.LONG, True),
+                           Literal(30, T.LONG)),
+        PhysicalOp([], db.schema))
+    kept = flt.batch_fn(db)
+    col = kept.columns[0]
+    assert col.codes is not None and col.data is db.columns[0].data
+    assert col.mat_byte_cap == db.columns[0].mat_byte_cap
+    assert device_to_host(kept).to_pydict() == {
+        "s": [None, "bb", "cc"], "l": [30, 40, 50]}
+
+
 # -- D2H invariant: codes never leak into collected results ------------------
 
 

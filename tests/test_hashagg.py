@@ -653,3 +653,211 @@ def test_a_stage_that_may_rerun_does_not_donate_its_sources():
     assert all(masks["sort"]) == donation_supported(), masks
     verify_plan(root)
 
+
+
+# -- string keys that arrive dictionary-encoded are digits --------------------
+
+
+def _encoded(columns):
+    """Device batch from ``{name: values}``: a list of str/None becomes a
+    dictionary-encoded string column (``(entries, codes)`` fixes the
+    dictionary by hand), anything else a plain column."""
+    import pyarrow as pa
+    from spark_rapids_tpu.batch import host_to_device
+    from spark_rapids_tpu.io.arrow_convert import arrow_to_host_batch
+    arrays = {}
+    for name, vals in columns.items():
+        if isinstance(vals, tuple):
+            entries, codes = vals
+            arrays[name] = pa.DictionaryArray.from_arrays(
+                pa.array(codes, type=pa.int32()),
+                pa.array(entries, type=pa.string()))
+        elif isinstance(vals, pa.Array):
+            arrays[name] = vals
+        else:
+            arrays[name] = pa.array(vals, type=pa.string()) \
+                .dictionary_encode()
+    db = host_to_device(arrow_to_host_batch(pa.table(arrays),
+                                            keep_dictionary=True))
+    assert all(c.codes is not None for c in db.columns if c.is_string)
+    return db
+
+
+def _key_case(name):
+    """(key names, [(batch columns, keep-mask or None), ...]): the update
+    batches of one aggregate, each with the filter above it."""
+    import pyarrow as pa
+    rng = np.random.RandomState(41)
+
+    def values(n):
+        v = [None if i % 7 == 0 else int(x) for i, x in
+             enumerate(rng.randint(-2**40, 2**40, n))]
+        d = [None if i % 5 == 0 else float(x) for i, x in
+             enumerate((rng.rand(n) * 2e5 - 1e5).round(2))]
+        return {"v": pa.array(v, type=pa.int64()),
+                "d": pa.array(d, type=pa.float64())}
+
+    def batch(n, **keys):
+        return dict(keys, **values(n))
+
+    def pick(words, n):
+        return [words[i] for i in rng.randint(0, len(words), n)]
+
+    if name == "one_key":
+        return ["a"], [(batch(900, a=pick(["A", "N", "R"], 900)), None)]
+    if name == "two_keys":
+        return ["a", "b"], [(batch(900, a=pick(["A", "N", "R"], 900),
+                                   b=pick(["F", "O"], 900)), None)]
+    if name == "null_keys":
+        return ["a", "b"], [(batch(
+            700, a=pick(["A", None, "R", ""], 700),
+            b=pick([None, "O", "a longer status"], 700)), None)]
+    if name == "unused_entry":
+        # entries 0 and 3 of the dictionary name no row
+        return ["a"], [(batch(500, a=(
+            ["unused", "N", "R", "never"],
+            [int(c) for c in rng.randint(1, 3, 500)])), None)]
+    if name == "filter_drops_one_group":
+        a = pick(["A", "N", "R"], 800)
+        return ["a"], [(batch(800, a=a), np.array([x != "N" for x in a]))]
+    if name == "filter_drops_every_row":
+        return ["a", "b"], [(batch(300, a=pick(["A", "N"], 300),
+                                   b=pick(["F", "O"], 300)),
+                             np.zeros(300, np.bool_))]
+    if name == "empty_batch":
+        return ["a"], [(batch(0, a=[]), None),
+                       (batch(200, a=pick(["A", "N"], 200)), None)]
+    if name == "two_dictionaries":
+        # the same strings under other codes, and strings of their own
+        return ["a", "b"], [
+            (batch(600, a=pick(["A", "N", "R"], 600),
+                   b=pick(["F", "O"], 600)), None),
+            (batch(400, a=pick(["R", "X", "A"], 400),
+                   b=(["O", "Q", "F"],
+                      [int(c) for c in rng.randint(0, 3, 400)])), None)]
+    raise AssertionError(name)
+
+
+def _rows_by_key(batch, n_keys):
+    from spark_rapids_tpu.batch import device_to_host
+    cols = list(device_to_host(batch).to_pydict().values())
+    return sorted(zip(*cols), key=lambda r: tuple(
+        (k is None, k or "") for k in r[:n_keys])) if cols else []
+
+
+def _assert_same_groups(got, want, what):
+    assert len(got) == len(want), (what, got, want)
+    for g, w in zip(got, want):
+        assert len(g) == len(w)
+        for x, y in zip(g, w):
+            if isinstance(y, float):
+                assert x == pytest.approx(y, rel=1e-12, abs=0), (what, g, w)
+            else:           # keys, counts, integer sums, NULLs: exact
+                assert x == y and type(x) is type(y), (what, g, w)
+
+
+@pytest.mark.parametrize("name", [
+    "one_key", "two_keys", "null_keys", "unused_entry",
+    "filter_drops_one_group", "filter_drops_every_row", "empty_batch",
+    "two_dictionaries"])
+def test_encoded_string_keys_group_by_their_codes_like_the_sort_form(name):
+    """A string key that arrives dictionary-encoded is a digit of the slot
+    index: every update batch answers through the contraction what the
+    sort form answers on the SAME batch (string keys in row layout, one
+    row a group), and the partials of batches with different dictionaries
+    merge to the sort form's answer."""
+    from spark_rapids_tpu.exprs.aggregates import (
+        AggregateExpression, count_star,
+    )
+    from spark_rapids_tpu.kernels.layout import compact
+    from spark_rapids_tpu.ops import tpu_exec as X
+    from spark_rapids_tpu.plan.physical import PhysicalOp
+    key_names, batches = _key_case(name)
+    keys = [ColumnRef(k, T.STRING, True) for k in key_names]
+    L, D = ColumnRef("v", T.LONG, True), ColumnRef("d", T.DOUBLE, True)
+    aggs = [AggregateExpression(fn, f"o{i}") for i, fn in enumerate(
+        [Sum(L), Count(L), Sum(D), Average(D), count_star(), Max(L)])]
+    child = PhysicalOp([], T.Schema(
+        [(k, T.STRING) for k in key_names] + [("v", T.LONG),
+                                              ("d", T.DOUBLE)]))
+    update = X.TpuHashAggregateExec(
+        "update", keys, key_names, aggs, child,
+        X._buffer_schema(key_names, keys, aggs))
+    merge = X.TpuHashAggregateExec(
+        "merge", keys, key_names, aggs, update, T.Schema(
+            [(k, T.STRING) for k in key_names] +
+            [(a.output_name, a.dtype) for a in aggs]))
+    assert update._hash_capable and not merge._hash_capable
+
+    fast, slow = [], []
+    for columns, keep in batches:
+        b = _encoded(columns)
+        if keep is not None:
+            mask = jnp.zeros(b.capacity, jnp.bool_).at[:len(keep)].set(
+                jnp.asarray(keep))
+            b = compact(b, mask, keep_encoded=True)
+            assert all(c.codes is not None for c in b.columns[:len(keys)])
+        partial, flag = jax.jit(update._aggregate_batch_hash)(b)
+        assert flag is not None and not bool(flag), name
+        # a partial leaves as the sort form's does: row-layout strings
+        assert all(c.codes is None and c.offsets is not None
+                   for c in partial.columns[:len(keys)])
+        want = jax.jit(update._aggregate_batch)(b)
+        _assert_same_groups(_rows_by_key(partial, len(keys)),
+                            _rows_by_key(want, len(keys)), name)
+        fast.append(partial)
+        slow.append(want)
+
+    def merged(partials):
+        one = X._concat_all(partials, update.output_schema)
+        return _rows_by_key(jax.jit(merge._aggregate_batch)(one), len(keys))
+
+    answer = merged(fast)
+    _assert_same_groups(answer, merged(slow), name)
+    if name == "filter_drops_one_group":
+        assert [r[0] for r in answer] == ["A", "R"]
+    if name == "filter_drops_every_row":
+        assert answer == []
+    if name == "unused_entry":
+        assert [r[0] for r in answer] == ["N", "R"]
+    if name == "two_dictionaries":
+        assert sorted({r[0] for r in answer}) == ["A", "N", "R", "X"]
+        assert sorted({r[1] for r in answer}) == ["F", "O", "Q"]
+
+
+@pytest.mark.parametrize("key", ["plain", "computed"])
+def test_string_key_without_codes_takes_the_sort_form_and_speculates_nothing(
+        tmp_path, key):
+    """A string key that arrives in row layout (a source that never
+    encoded it) or is computed (no column to carry codes) is no digit: the
+    update answers by the sort form in its first and only dispatch — no
+    contraction counted, no flag raised, no rerun."""
+    from spark_rapids_tpu import functions as F
+    data = {"s": (T.STRING, [None if i % 11 == 0 else "ANR"[i % 3]
+                             for i in range(2000)]),
+            "v": (T.LONG, list(range(2000))),
+            "f": (T.DOUBLE, [i * 0.25 for i in range(2000)])}
+    path = str(tmp_path / "sk.parquet")
+    tpu_session().create_dataframe(data, num_partitions=2).write_parquet(path)
+
+    def q(s):
+        if key == "plain":      # host rows, staged in row layout
+            df = s.create_dataframe(data, num_partitions=2)
+            return df.group_by("s").agg(F.sum("v").alias("sv"),
+                                        F.sum("f").alias("sf"))
+        df = s.read.parquet(path)   # encoded, until the key is computed
+        return df.group_by(F.concat(F.col("s"), F.lit("-x")).alias("k")) \
+            .agg(F.sum("v").alias("sv"), F.sum("f").alias("sf"))
+
+    assert_tpu_cpu_equal(q, approx=True, confs=FLOAT_AGG)
+    tpu = tpu_session(**FLOAT_AGG)
+    q(tpu).collect()
+    assert _flat(tpu, "keyedUpdateBatches") > 0, tpu.last_metrics
+    assert _flat(tpu, "mxuAggBatches") == 0 and not _mxu_engaged(tpu)
+    assert _fallbacks(tpu) == 0
+    assert "flagReruns" not in _pipeline(tpu), _pipeline(tpu)
+    (update,) = _update_aggs(tpu)
+    assert not update._hash_disabled
+    # only a bare column can carry codes: a computed key names no fast
+    # variant, a bare one that arrived plain was asked at the trace
+    assert update._hash_capable == (key == "plain")

@@ -160,7 +160,8 @@ def test_keyed_and_compacted_batches_are_counted_by_every_dispatch(
         tmp_path, key, mxu):
     """A keyed aggregate's update batches (``keyedUpdateBatches``; of them
     ``mxuAggBatches`` through the slot contraction: all with an integer
-    key, none in the sort variant, which a string key always takes) and
+    key or a string key the scan left dictionary-encoded, none in the
+    sort variant) and
     the batches its filter compacted (``filterCompactedBatches``) are
     noted when the update stage is traced and counted by every collect.
     A keyless plan's filter sits inside the aggregate's arguments: it
@@ -186,12 +187,99 @@ def test_keyed_and_compacted_batches_are_counted_by_every_dispatch(
         m = s.last_metrics
         assert m["compileCount"] == 0
         assert m["keyedUpdateBatches"] == keyed, m["keyedUpdateBatches"]
-        assert m["mxuAggBatches"] == \
-            (keyed if mxu and key == "kd_code" else 0)
+        assert m["mxuAggBatches"] == (keyed if mxu else 0)
         assert m["filterCompactedBatches"] == keyed
         assert m["keylessUpdateBatches"] == n_batches - keyed
         # the keyed update is its stage's root; the filter notes no update
         assert ("inlinedUpdates" in m["pipeline"]) == (key is None)
+
+
+def _scatters_over(text, rows):
+    """The operand types of every ``stablehlo.scatter`` of a lowered
+    module that scatters into an operand as long as an input batch (the
+    type signature follows the operation's region)."""
+    sigs = re.findall(r'"stablehlo\.scatter".*?\}\) : \((.*?)\) ->', text,
+                      flags=re.S)
+    return [sig for sig in sigs if sig.startswith(f"tensor<{rows}x")]
+
+
+def _q1_shaped(s, path, where=True):
+    """Eight aggregates by two string keys under a filter, sorted: Q1,
+    over a CACHED parquet table, whose batches hold the keys encoded."""
+    df = s.read.parquet(path).cache()
+    if where:
+        df = df.filter(F.col("q1_ship") <= 70)
+    return (df.group_by("q1_flag", "q1_status").agg(
+        F.sum("q1_qty").alias("sum_qty"),
+        F.sum(F.col("q1_price") * (1 - F.col("q1_disc"))).alias("sum_disc"),
+        F.avg("q1_qty").alias("avg_qty"), F.avg("q1_disc").alias("avg_disc"),
+        F.count("*").alias("n")).order_by("q1_flag", "q1_status"))
+
+
+@pytest.mark.parametrize("where", [True, False])
+def test_q1_shaped_update_groups_by_codes_on_the_contraction(
+        monkeypatch, tmp_path, where):
+    """Q1's two string keys reach the keyed update dictionary-encoded —
+    through the filter's compaction, which moves codes — and every update
+    batch goes through the slot contraction, in every query; still two
+    programs and the break's ``host_sizes`` beside the root's.  The
+    update program sorts nothing and scatters over no batch-sized operand
+    but the compaction's own rank inversion, one a batch; its table is as
+    narrow as the two dictionaries allow."""
+    from spark_rapids_tpu.kernels.hashagg import TABLE_SLOTS
+    n_batches, rows = 4, 2048
+    path = str(tmp_path / "q1.parquet")
+    tpu_session().create_dataframe({
+        "q1_flag": ["ANR"[(i * 7) % 3] for i in range(rows)],
+        "q1_status": ["OF"[(i // 5) % 2] for i in range(rows)],
+        "q1_qty": [float(i % 50) for i in range(rows)],
+        "q1_price": [float(900 + i % 1000) for i in range(rows)],
+        "q1_disc": [0.01 * (i % 10) for i in range(rows)],
+        "q1_ship": [i % 100 for i in range(rows)]}).write_parquet(path)
+    confs = dict(FLOAT_AGG, **{
+        "spark.rapids.sql.tpu.pipeline.shrinkBytes": 0,
+        "spark.rapids.sql.reader.batchSizeRows": rows // n_batches})
+    held = {}
+
+    def build(s):
+        held["df"] = _q1_shaped(s, path, where)
+        return held["df"]
+
+    s, texts = lowered_stage_texts(monkeypatch, build, **confs)
+    want = _q1_shaped(tpu_session(**{"spark.rapids.sql.enabled": False}),
+                      path, where).collect()
+    for _ in range(2):      # warm queries: the cache read, nothing traced
+        got = held["df"].collect()
+        assert len(got) == len(want) == 6
+        for g, w in zip(got, want):
+            assert g[:2] == w[:2] and g[-1] == w[-1]
+            assert g[2:-1] == pytest.approx(w[2:-1], rel=1e-12)
+        m = s.last_metrics
+        assert m["compileCount"] == 0
+        assert m["keyedUpdateBatches"] == n_batches
+        assert m["mxuAggBatches"] == n_batches        # what the pct reads
+        assert m["filterCompactedBatches"] == (n_batches if where else 0)
+        assert m["pipeline"]["programs"] == 2, m["pipeline"]
+        assert "flagReruns" not in m["pipeline"], m["pipeline"]
+        assert not any(ms.get("hashAggFallback") for ms in m.values()
+                       if isinstance(ms, dict))
+        waits = [e.name for e in s.query_history()[-1].events
+                 if e.kind == "span" and e.site == "device_wait"]
+        assert waits.count("host_sizes") == 2, waits
+    update = next(t for t in texts.values()
+                  if "k.hashagg.hash_group_aggregate" in t)
+    assert "k.groupby.group_segments" not in update
+    assert "k.strings.string_hash2" not in update
+    assert "k.layout.dict_decode_column" in update  # the key column it emits
+    assert "stablehlo.sort" not in update
+    cap = rows // n_batches
+    scatters = _scatters_over(update, cap)
+    assert len(scatters) == (n_batches if where else 0), scatters
+    contraction = re.search(r"stablehlo\.dot_general.*", update)
+    # (3 + 1) * (2 + 1) key tuples at most, at the dictionaries' padded
+    # sizes: one lane tile of slots where the integer-key table has 8,194
+    assert contraction and "x128xf32>" in contraction.group(0)
+    assert f"x{TABLE_SLOTS + 2}xf32>" not in update
 
 
 def test_filter_operator_has_its_own_scope(monkeypatch):
