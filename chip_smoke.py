@@ -144,10 +144,13 @@ def ref_q12(t):
             & (li.l_commitdate < li.l_receiptdate)
             & (li.l_shipdate < li.l_commitdate)
             & (li.l_receiptdate >= 8766) & (li.l_receiptdate < 9131)]
-    j = t["orders"][["o_orderkey"]].merge(
+    j = t["orders"][["o_orderkey", "o_orderpriority"]].merge(
         li[["l_orderkey", "l_shipmode"]],
         left_on="o_orderkey", right_on="l_orderkey")
-    g = j.groupby("l_shipmode", sort=True).size().reset_index(name="n")
+    high = j.o_orderpriority.isin(["1-URGENT", "2-HIGH"]).astype("int64")
+    j = j.assign(high_line_count=high, low_line_count=1 - high)
+    g = j.groupby("l_shipmode", sort=True)[
+        ["high_line_count", "low_line_count"]].sum().reset_index()
     return _rows(g)
 
 

@@ -335,12 +335,26 @@ def eval_maybe_encoded(expr: "Expression", ctx: TpuEvalCtx) -> DevVal:
     column reference.  Only hash/eq-based consumers (string equality
     predicates, group keys) may call this — every other path goes through
     ``tpu_eval`` → ``from_column`` which materializes."""
+    column = column_as_it_is(expr, ctx)
+    if column is not None:
+        return DevVal.from_column_encoded(column)
+    return expr.tpu_eval(ctx)
+
+
+def column_as_it_is(expr: "Expression", ctx: TpuEvalCtx):
+    """The input batch's own :class:`DeviceColumn` where ``expr`` is a
+    bare column reference, else None: a projection that only carries a
+    column on hands it on as it came — its dictionary codes with it, as a
+    filter does — where ``tpu_eval(...).to_column()`` would materialize
+    every row of an encoded string column (a million-row decode to hand
+    a join a column of which it reads a few thousand rows: PERF.md,
+    PR 36)."""
     expr = _unaliased(expr)
     if isinstance(expr, ColumnRef):
-        return DevVal.from_column_encoded(ctx.batch.column(expr.column))
+        return ctx.batch.column(expr.column)
     if isinstance(expr, BoundRef):
-        return DevVal.from_column_encoded(ctx.batch.columns[expr.ordinal])
-    return expr.tpu_eval(ctx)
+        return ctx.batch.columns[expr.ordinal]
+    return None
 
 
 class Literal(Expression):

@@ -20,7 +20,9 @@ Join types: inner, left, right, full, left_semi, left_anti, cross.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import threading
 from typing import List, Optional, Tuple
 
 import jax
@@ -35,6 +37,46 @@ from spark_rapids_tpu.exprs.base import DevVal
 from spark_rapids_tpu.kernels.layout import (
     compaction_indices, ensure_row_layout, gather_rows,
 )
+
+_TALLY = threading.local()
+
+
+class JoinTally:
+    """What the joins run inside a :func:`tally` block read back to the
+    host: ``pairs``, the candidate pairs the probes counted (the totals
+    the host needs to size phase 2 anyway), and ``reads``, the
+    ``device_read``s named ``join_*``, each a round trip the host waits
+    out.  The operator that runs the join adds them to its metrics
+    (``joinPairs``, ``joinSizeReads``)."""
+
+    __slots__ = ("pairs", "reads")
+
+    def __init__(self):
+        self.pairs = 0
+        self.reads = 0
+
+
+@contextlib.contextmanager
+def tally():
+    outer = getattr(_TALLY, "open", None)
+    t = _TALLY.open = JoinTally()
+    try:
+        yield t
+    finally:
+        _TALLY.open = outer
+
+
+def _size_read(name: str, tree):
+    """``device_read`` of a size the join needs on the host, counted; the
+    read named ``join_pairs`` is the candidate-pair total."""
+    value = device_read(name, tree)
+    t = getattr(_TALLY, "open", None)
+    if t is not None:
+        t.reads += 1
+        if name == "join_pairs":
+            t.pairs += int(value)
+    return value
+
 
 _C1 = np.uint32(0xCC9E2D51)
 _C2 = np.uint32(0x1B873593)
@@ -323,6 +365,45 @@ _build_sort_jit = instrumented_jit(_build_sort, label="join:build_sort")
 
 
 @kernel_scope
+def _phase2(lo, counts, perm, l_keys, r_keys, code_pairs, total, pair_cap):
+    """Expand the candidate ranges into ``pair_cap`` (static: the host's
+    bucket of the phase-1 total) pairs, verify them, compact the matches
+    to the front.  One program a shape, kept by the process like phase 1:
+    a closure jitted inside :func:`join_pairs` was traced, lowered and
+    compiled (or loaded) again by every join of every query."""
+    l_cap = int(l_keys[0].validity.shape[0])
+    r_cap = int(r_keys[0].validity.shape[0])
+    cum = jnp.cumsum(counts)
+    starts = cum - counts
+    k = jnp.arange(pair_cap, dtype=jnp.int32)
+    probe_row = jnp.searchsorted(cum, k, side="right").astype(jnp.int32)
+    probe_row = jnp.clip(probe_row, 0, l_cap - 1)
+    ordinal = (k - starts[probe_row]).astype(jnp.int32)
+    build_pos = jnp.clip(lo[probe_row] + ordinal, 0, r_cap - 1)
+    build_row = perm[build_pos]
+    in_range = k < total
+    match = in_range & _exact_eq(l_keys, probe_row, r_keys, build_row,
+                                 code_pairs)
+    # compact matches to the front
+    order = jnp.argsort(jnp.where(match, 0, 1), stable=True)
+    n_pairs = jnp.sum(match).astype(jnp.int32)
+    l_idx = probe_row[order]
+    r_idx = build_row[order]
+    # per-left-row match counts + right matched flags (for outer joins)
+    ones = match.astype(jnp.int32)
+    l_counts = jax.ops.segment_sum(ones, probe_row, num_segments=l_cap,
+                                   indices_are_sorted=True)
+    r_matched = jax.ops.segment_max(
+        ones, build_row, num_segments=r_cap) > 0
+    return l_idx.astype(jnp.int32), r_idx.astype(jnp.int32), n_pairs, \
+        l_counts, r_matched
+
+
+_phase2_jit = instrumented_jit(_phase2, label="join:phase2",
+                               static_argnames=("pair_cap",))
+
+
+@kernel_scope
 def join_pairs(left_keys: List[DevVal], left_num_rows,
                right_keys: List[DevVal], right_num_rows,
                pair_cap_hint: Optional[int] = None):
@@ -360,7 +441,7 @@ def join_pairs(left_keys: List[DevVal], left_num_rows,
     lo, counts, total = _phase1_jit(l_h1, l_ok, l_live, r_sorted,
                                     right_num_rows)
 
-    total_pairs = int(device_read("join_pairs", total))
+    total_pairs = int(_size_read("join_pairs", total))
     pair_cap = round_up_capacity(max(total_pairs, 1))
     if pair_cap_hint is not None:
         pair_cap = max(pair_cap, pair_cap_hint)
@@ -371,35 +452,8 @@ def join_pairs(left_keys: List[DevVal], left_num_rows,
     code_pairs = [None if a is None else (a, b)
                   for a, b in zip(l_over, r_over)] if any_over else None
 
-    @jax.jit
-    def phase2(lo, counts, perm, l_keys, r_keys, code_pairs, total):
-        cum = jnp.cumsum(counts)
-        starts = cum - counts
-        k = jnp.arange(pair_cap, dtype=jnp.int32)
-        probe_row = jnp.searchsorted(cum, k, side="right").astype(jnp.int32)
-        probe_row = jnp.clip(probe_row, 0, l_cap - 1)
-        ordinal = (k - starts[probe_row]).astype(jnp.int32)
-        build_pos = jnp.clip(lo[probe_row] + ordinal, 0, r_cap - 1)
-        build_row = perm[build_pos]
-        in_range = k < total
-        match = in_range & _exact_eq(l_keys, probe_row, r_keys, build_row,
-                                     code_pairs)
-        # compact matches to the front
-        order = jnp.argsort(jnp.where(match, 0, 1), stable=True)
-        n_pairs = jnp.sum(match).astype(jnp.int32)
-        l_idx = probe_row[order]
-        r_idx = build_row[order]
-        # per-left-row match counts + right matched flags (for outer joins)
-        ones = match.astype(jnp.int32)
-        l_counts = jax.ops.segment_sum(ones, probe_row, num_segments=l_cap,
-                                       indices_are_sorted=True)
-        r_matched = jax.ops.segment_max(
-            ones, build_row, num_segments=r_cap) > 0
-        return l_idx.astype(jnp.int32), r_idx.astype(jnp.int32), n_pairs, \
-            l_counts, r_matched
-
-    return phase2(lo, counts, perm, left_keys, right_keys, code_pairs,
-                  total)
+    return _phase2_jit(lo, counts, perm, left_keys, right_keys, code_pairs,
+                       total, pair_cap=pair_cap)
 
 
 @kernel_scope
@@ -667,7 +721,7 @@ def _string_byte_caps(batch: ColumnBatch, indices, live) -> List[int]:
                 lens = (c.offsets[1:] - c.offsets[:-1]).astype(jnp.int64)
             total = jnp.sum(jnp.where(live, lens[jnp.clip(
                 indices, 0, batch.capacity - 1)], 0))
-            caps.append(round_up_capacity(int(device_read("join_bytes", total)),
+            caps.append(round_up_capacity(int(_size_read("join_bytes", total)),
                                           minimum=16))
     return caps
 
@@ -774,7 +828,7 @@ def stitch_join_output(left: ColumnBatch, right: ColumnBatch, l_idx, r_idx,
         n_un_l = jnp.sum(un_l_mask).astype(jnp.int32)
         n_un_r = jnp.sum(un_r_mask).astype(jnp.int32)
         total = n_pairs + n_un_l + n_un_r
-        total_h = int(device_read("join_rows", total))
+        total_h = int(_size_read("join_rows", total))
         out_cap = round_up_capacity(max(total_h, 1))
 
         un_l_idx, _ = compaction_indices(un_l_mask, left.num_rows)
@@ -832,7 +886,7 @@ def _cross_pairs(left: ColumnBatch, right: ColumnBatch, condition):
     (l_idx, r_idx, n_pairs, l_counts, r_matched).  Pair capacity is
     n_l * n_r — callers bound it by chunking the left side."""
     l_cap, r_cap = left.capacity, right.capacity
-    n_l, n_r = (int(n) for n in device_read(
+    n_l, n_r = (int(n) for n in _size_read(
         "join_sides", (left.num_rows, right.num_rows)))
     total = n_l * n_r
     pair_cap = round_up_capacity(max(total, 1))

@@ -24,15 +24,20 @@ from spark_rapids_tpu.batch import (
     round_up_capacity,
 )
 from spark_rapids_tpu.exprs.aggregates import AggregateExpression
-from spark_rapids_tpu.exprs.base import DevVal, Expression, SortOrder, TpuEvalCtx
+from spark_rapids_tpu.exprs.base import (
+    DevVal, Expression, SortOrder, TpuEvalCtx, column_as_it_is,
+)
 from spark_rapids_tpu.kernels.groupby import groupby_aggregate
-from spark_rapids_tpu.kernels.join import cross_join, hash_join
+from spark_rapids_tpu.kernels.join import (
+    cross_join, hash_join, tally as join_tally,
+)
 from spark_rapids_tpu.kernels.layout import (
     compact, gather_rows, take_head,
 )
 from spark_rapids_tpu.kernels.sort import sort_batch
 from spark_rapids_tpu.plan.physical import ExecContext, PhysicalOp, TpuExec
-from spark_rapids_tpu.utils.compile_registry import plan_jit
+from spark_rapids_tpu.plan.pipeline import BatchProgram, _spec_of
+from spark_rapids_tpu.utils.compile_registry import instrumented_jit, plan_jit
 from spark_rapids_tpu.utils.tracing import device_read
 
 
@@ -96,6 +101,32 @@ def _release_build_staging(ctx: ExecContext, depth0: int) -> None:
         ctx._pipeline_h2d = max(0, ctx._pipeline_h2d - extra)
 
 
+def _shrink_keeping_codes(batches, caps, bcapss):
+    """Each batch re-bucketed to its own ``(row cap, varlen byte caps)``,
+    a dictionary-encoded column keeping its codes: a prefix gather cannot
+    grow what the column materializes to, and that bound is now the
+    host-read total's bucket, not the source batch's (a decode costs its
+    ``mat_byte_cap``, whatever is live)."""
+    out = []
+    for b, cap, bcaps in zip(batches, caps, bcapss):
+        g = gather_rows(b, jnp.arange(cap, dtype=jnp.int32), b.num_rows,
+                        out_capacity=cap, out_byte_caps=list(bcaps) or None,
+                        keep_encoded=True)
+        varlen = iter(bcaps)
+        cols = []
+        for c in g.columns:
+            bcap = next(varlen) if c.is_varlen else 0
+            cols.append(c if c.codes is None else DeviceColumn(
+                c.dtype, c.data, c.validity, c.offsets, c.codes, bcap))
+        out.append(ColumnBatch(g.schema, cols, g.num_rows, g.capacity))
+    return tuple(out)
+
+
+_shrink_keeping_codes_jit = instrumented_jit(
+    _shrink_keeping_codes, label="kernels:shrinkSparse",
+    static_argnames=("caps", "bcapss"))
+
+
 def _concat_all(batches: List[ColumnBatch], schema: T.Schema,
                 sizes: Optional[List[tuple]] = None
                 ) -> Optional[ColumnBatch]:
@@ -115,6 +146,22 @@ def _concat_all(batches: List[ColumnBatch], schema: T.Schema,
     batches = list(colocate_batches(batches))
     if sizes is None:
         sizes = host_sizes(batches)
+    # a filter's output keeps its input's capacity: the concat (and the
+    # decode of every encoded column inside it) would pay for a million
+    # rows a batch to move the few thousand that passed.  The sizes are
+    # on the host already, so the sparse inputs are re-bucketed first, in
+    # one program, codes kept (TPC-H Q12: six lineitem batches of 5 k rows
+    # in million-row buffers, 1.4 s a decode: PERF.md, PR 36)
+    specs = _spec_of(sizes)
+    sparse = [i for i, (b, (cap, _)) in enumerate(zip(batches, specs))
+              if b.capacity > 2 * cap]
+    if sparse:
+        shrunk = _shrink_keeping_codes_jit(
+            tuple(batches[i] for i in sparse),
+            caps=tuple(specs[i][0] for i in sparse),
+            bcapss=tuple(specs[i][1] for i in sparse))
+        for i, b in zip(sparse, shrunk):
+            batches[i] = b
     total_rows = sum(n for n, _ in sizes)
     cap = round_up_capacity(max(total_rows, 1))
     n_str = sum(1 for f in schema.fields
@@ -124,6 +171,38 @@ def _concat_all(batches: List[ColumnBatch], schema: T.Schema,
         for j in range(n_str)
     ]
     return concat_kway_run(batches, cap, out_byte_caps=byte_caps or None)
+
+
+def _concat_sized(batches: List[ColumnBatch], schema: T.Schema):
+    """:func:`_concat_all` and the rows the host learnt on the way: the
+    concatenation of two or more batches reads their sizes, a side that
+    arrived as one batch (or none) is handed on unread — ``None``."""
+    if len(batches) < 2:
+        return _concat_all(batches, schema), None
+    from spark_rapids_tpu.batch import colocate_batches, host_sizes
+    batches = list(colocate_batches(batches))
+    sizes = host_sizes(batches)
+    return _concat_all(batches, schema, sizes), sum(n for n, _ in sizes)
+
+
+def _counted_hash_join(ctx: ExecContext, op_id: str, lb, lkeys, rb, rkeys,
+                       how: str, schema: T.Schema, condition,
+                       probe_rows: Optional[int] = None,
+                       build_rows: Optional[int] = None) -> ColumnBatch:
+    """``hash_join`` (the right side is the build: sorted by key hash; the
+    left probes it) with what it read back added to the operator's
+    metrics: ``joinPairs`` and ``joinSizeReads`` from the kernel's own
+    tally, ``joinProbeRows`` / ``joinBuildRows`` where the host already
+    holds a side's row count (it concatenated the side); no read of its
+    own."""
+    with join_tally() as t:
+        out = hash_join(lb, lkeys, rb, rkeys, how, schema,
+                        condition=condition)
+    ctx.metric(op_id, "joinPairs").add(t.pairs)
+    ctx.metric(op_id, "joinSizeReads").add(t.reads)
+    ctx.metric(op_id, "joinProbeRows").add(probe_rows or 0)
+    ctx.metric(op_id, "joinBuildRows").add(build_rows or 0)
+    return out
 
 
 class TpuRangeExec(TpuExec):
@@ -168,7 +247,11 @@ class TpuProjectExec(TpuExec):
 
         def run(batch: ColumnBatch) -> ColumnBatch:
             ctx = TpuEvalCtx(batch)
-            cols = [e.tpu_eval(ctx).to_column() for e in self.exprs]
+            # a column that is only carried on stays as it came (encoded
+            # where it was: what the projection hands on is what a scan
+            # or a filter hands on, and consumers hold to the same rule)
+            cols = [column_as_it_is(e, ctx) or e.tpu_eval(ctx).to_column()
+                    for e in self.exprs]
             return ColumnBatch(schema, cols, batch.num_rows, batch.capacity)
 
         self.batch_fn = run
@@ -219,7 +302,7 @@ class TpuFilterExec(TpuExec):
             return compact(batch, keep, keep_encoded=True)
 
         self.batch_fn = run
-        self._run = plan_jit(run, label="TpuFilter")
+        self._run = BatchProgram(run, "TpuFilter")
 
     def describe(self):
         return f"TpuFilter({self.condition!r})"
@@ -233,7 +316,7 @@ class TpuFilterExec(TpuExec):
         return lambda args: [self.batch_fn(b) for b in cf(args)]
 
     def partitions(self, ctx):
-        return [map(self._run, p)
+        return [(self._run(ctx, b) for b in p)
                 for p in self.children[0].partitions(ctx)]
 
 
@@ -397,7 +480,7 @@ class TpuFusedMapExec(TpuExec):
             return batch
 
         self.batch_fn = composed
-        self._run = plan_jit(composed, label="TpuFusedMap")
+        self._run = BatchProgram(composed, "TpuFusedMap")
 
     def describe(self):
         return f"TpuFusedMap({' -> '.join(self.labels)})"
@@ -407,7 +490,7 @@ class TpuFusedMapExec(TpuExec):
         return lambda args: [self.batch_fn(b) for b in cf(args)]
 
     def partitions(self, ctx):
-        return [map(self._run, p)
+        return [(self._run(ctx, b) for b in p)
                 for p in self.children[0].partitions(ctx)]
 
 
@@ -1093,9 +1176,9 @@ class TpuShuffledHashJoinExec(TpuExec):
             if skewed and self.how != "full":
                 yield from self._join_skewed(ctx, lbs, rbs)
                 return
-            lb = _concat_all(lbs, self.children[0].output_schema)
-            rb = _concat_all(rbs, self.children[1].output_schema)
-            out = self._join_pair(lb, rb)
+            lb, l_rows = _concat_sized(lbs, self.children[0].output_schema)
+            rb, r_rows = _concat_sized(rbs, self.children[1].output_schema)
+            out = self._join_pair(ctx, lb, rb, l_rows, r_rows)
             if out is not None:
                 yield out
 
@@ -1191,7 +1274,7 @@ class TpuShuffledHashJoinExec(TpuExec):
             sb = _concat_all(sbs, stream_schema)
             b = bh.get() if bh is not None else None
             lb, rb = (sb, b) if side == "right" else (b, sb)
-            out = self._join_pair(lb, rb)
+            out = self._join_pair(ctx, lb, rb)
             if out is not None:
                 yield out
 
@@ -1239,18 +1322,19 @@ class TpuShuffledHashJoinExec(TpuExec):
             # no live stream rows: only a build-only shape can produce
             # output (it cannot for the non-'full' hows chunked here)
             out = self._join_pair(
-                *((None, build_b) if split_left else (build_b, None)))
+                ctx, *((None, build_b) if split_left else (build_b, None)))
             if out is not None:
                 yield out
             return
         for piece, rows, rows_per in plan:
             for sb in row_slices(piece, rows, rows_per):
                 lb, rb = (sb, build_b) if split_left else (build_b, sb)
-                out = self._join_pair(lb, rb)
+                out = self._join_pair(ctx, lb, rb)
                 if out is not None:
                     yield out
 
-    def _join_pair(self, lb, rb) -> Optional[ColumnBatch]:
+    def _join_pair(self, ctx, lb, rb, l_rows: Optional[int] = None,
+                   r_rows: Optional[int] = None) -> Optional[ColumnBatch]:
         lsch = self.children[0].output_schema
         rsch = self.children[1].output_schema
         if lb is None and self.how in ("inner", "left", "left_semi",
@@ -1271,8 +1355,9 @@ class TpuShuffledHashJoinExec(TpuExec):
         # the residual condition runs INSIDE the join (it gates matches
         # before null-padding — GpuHashJoin.scala:265-271), so outer and
         # semi/anti joins with conditions are correct on device
-        return hash_join(lb, lkeys, rb, rkeys, self.how, self.output_schema,
-                         condition=self.condition)
+        return _counted_hash_join(
+            ctx, self.op_id, lb, lkeys, rb, rkeys, self.how,
+            self.output_schema, self.condition, l_rows, r_rows)
 
 
 class TpuNestedLoopJoinExec(TpuExec):
@@ -1498,6 +1583,7 @@ class TpuBroadcastHashJoinExec(TpuExec):
         self.broadcast_side = broadcast_side
         self.condition = condition
         self._bc_cache = None  # (weakref(ctx), SpillableBatch | None)
+        self._bc_rows = None   # the build's rows, where its concat read them
 
     def describe(self):
         return f"TpuBroadcastHashJoin({self.how}, bc={self.broadcast_side})"
@@ -1595,7 +1681,8 @@ class TpuBroadcastHashJoinExec(TpuExec):
         batches = []
         for p in self.children[1].partitions(ctx):
             batches.extend(p)
-        bc = _concat_all(batches, self.children[1].output_schema)
+        bc, self._bc_rows = _concat_sized(
+            batches, self.children[1].output_schema)
         handle = None
         if bc is not None:
             from spark_rapids_tpu.runtime.device import DeviceRuntime
@@ -1625,9 +1712,13 @@ class TpuBroadcastHashJoinExec(TpuExec):
                     lb, rb = bc_local, sb
                 lkeys = _eval_join_keys(self.left_keys, lb, dict_keys)
                 rkeys = _eval_join_keys(self.right_keys, rb, dict_keys)
-                yield hash_join(lb, lkeys, rb, rkeys, self.how,
-                                self.output_schema,
-                                condition=self.condition)
+                # the broadcast side's rows, where its concatenation
+                # read them, under the name of the role it plays
+                yield _counted_hash_join(
+                    ctx, self.op_id, lb, lkeys, rb, rkeys, self.how,
+                    self.output_schema, self.condition,
+                    *((None, self._bc_rows) if self.broadcast_side == "right"
+                      else (self._bc_rows, None)))
 
         return [gen(p) for p in self.children[0].partitions(ctx)]
 

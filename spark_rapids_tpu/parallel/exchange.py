@@ -156,9 +156,10 @@ class TpuShuffleExchangeExec(TpuExec):
 
     def _ensure_fused_map(self):
         """Compile any absorbed map stages (filter/project) into ONE
-        program per batch; shared by the collapse-local and the adaptive
-        bypass paths, which both skip the split but must still apply the
-        absorbed stages."""
+        program per batch; shared by the collapse-local, the adaptive
+        bypass and the mesh paths, which all skip the split but must still
+        apply the absorbed stages (a :class:`BatchProgram`: a filter among
+        them counts its compactions here as in a stage program)."""
         if self._input_fns and self._fused_map is None:
             fns = list(self._input_fns)
 
@@ -167,8 +168,9 @@ class TpuShuffleExchangeExec(TpuExec):
                     b = f(b)
                 return b
 
-            self._fused_map = plan_jit(
-                composed, label="TpuShuffleExchange:map")
+            from spark_rapids_tpu.plan.pipeline import BatchProgram
+            self._fused_map = BatchProgram(composed,
+                                           "TpuShuffleExchange:map")
 
     def has_materialized_split(self, ctx) -> bool:
         """True when this exchange's split already ran for ``ctx`` on the
@@ -199,7 +201,7 @@ class TpuShuffleExchangeExec(TpuExec):
 
         def gen(part):
             for db in part:
-                yield self._fused_map(db) if self._fused_map else db
+                yield self._fused_map(ctx, db) if self._fused_map else db
 
         return [gen(p) for p in self.children[0].partitions(ctx)]
 
@@ -357,18 +359,9 @@ class TpuShuffleExchangeExec(TpuExec):
         batches: List[ColumnBatch] = []
         for part in self.children[0].partitions(ctx):
             batches.extend(part)
-        if self._input_fns:
-            if self._fused_map is None:
-                fns = list(self._input_fns)
-
-                def composed(b):
-                    for f in fns:
-                        b = f(b)
-                    return b
-
-                self._fused_map = plan_jit(
-                    composed, label="TpuShuffleExchange:map")
-            batches = [self._fused_map(b) for b in batches]
+        self._ensure_fused_map()
+        if self._fused_map:
+            batches = [self._fused_map(ctx, b) for b in batches]
         if not batches:
             return [iter([]) for _ in range(n)]
         # re-key the partitioning onto the mesh: one output partition per
@@ -436,8 +429,8 @@ class TpuShuffleExchangeExec(TpuExec):
             def gen():
                 for part in in_parts:
                     for db in part:
-                        yield self._fused_map(db) if self._fused_map \
-                            else db
+                        yield self._fused_map(ctx, db) \
+                            if self._fused_map else db
 
             return [gen()]
         all_batches: List[List[ColumnBatch]] = [list(p) for p in in_parts]

@@ -16,7 +16,7 @@ Per-operator conf gates mirror the reference's generated keys
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.config import RapidsConf
@@ -28,6 +28,11 @@ from spark_rapids_tpu.exprs.aggregates import (
 )
 from spark_rapids_tpu.exprs.conditional import If
 from spark_rapids_tpu.plan import logical as L
+from spark_rapids_tpu.plan.join_pushdown import (
+    JoinPush, deterministic, narrow_join_inputs,
+    push_filters_through_joins,
+    split_conjuncts as _split_conjuncts,
+)
 from spark_rapids_tpu.ops import cpu_exec as C
 from spark_rapids_tpu.ops import tpu_exec as X
 from spark_rapids_tpu.parallel.exchange import (
@@ -110,19 +115,48 @@ class PlanMeta:
         return lines
 
 
+class RewriteNotes:
+    """What :meth:`TpuOverrides.rewrite_logical` did to one plan object,
+    in that query's own literal values: one description a constant fold,
+    and one :class:`JoinPush` a join that was handed WHERE conjuncts or
+    keys.  A query publishes the counts (``foldedExprs``,
+    ``pushedJoinFilters``, ``joinKeysFromWhere``) and ``explain`` the
+    lines, on a plan-cache hit as on a miss."""
+
+    __slots__ = ("folded", "join_pushes")
+
+    def __init__(self, folded: Sequence[str] = (),
+                 join_pushes: Sequence[JoinPush] = ()):
+        self.folded = list(folded)
+        self.join_pushes = list(join_pushes)
+
+    @property
+    def pushed_join_filters(self) -> int:
+        """Conjuncts that moved below a join; one that went on down
+        through a second join is counted once."""
+        return len({id(c) for p in self.join_pushes for c in p.pushed})
+
+    @property
+    def join_keys_from_where(self) -> int:
+        return sum(len(p.keys) for p in self.join_pushes)
+
+
 class PlanExplain:
     """The explain text of a planned shape.  The tagging lines are the
-    shape's; what names literal values is rendered for one query: its own
-    folds, and the absorbed filter conditions with that query's bound
-    values in place of the lifted literals."""
+    shape's; what names literal values is rendered for one query: what
+    moved below its joins, its own folds, and the absorbed filter
+    conditions with that query's bound values in place of the lifted
+    literals."""
 
     def __init__(self, lines: List[str], absorbed: List[Expression]):
         self.lines = lines
         self.absorbed = absorbed
 
-    def render(self, folded: List[str], values: tuple = ()) -> str:
+    def render(self, notes: RewriteNotes, values: tuple = ()) -> str:
         from spark_rapids_tpu.utils import params
         lines = list(self.lines)
+        lines.extend(p.describe() for p in notes.join_pushes)
+        folded = notes.folded
         if folded:
             lines.append(f"folded {len(folded)}: " + ", ".join(folded))
         if self.absorbed:
@@ -240,16 +274,23 @@ class TpuOverrides:
     # -------------------------------------------------------------- convert
 
     def rewrite_logical(self, plan: L.LogicalPlan
-                        ) -> Tuple[L.LogicalPlan, List[str]]:
-        """The logical rewrites whose result depends on a query's literal
-        values, in planning order: UDF compilation, scan pushdown (on the
-        plan as written) and constant folding.  ``session.plan_bound`` runs
-        them for every new plan object, BEFORE it splits the plan into a
+                        ) -> Tuple[L.LogicalPlan, RewriteNotes]:
+        """The logical rewrites that run on every new plan object, in
+        planning order: UDF compilation, WHERE conjuncts and keys into the
+        joins below them and the joins' inputs narrowed to what is read
+        (``plan/join_pushdown.py``; a plan without a join comes back as it
+        went in), scan pushdown (so a conjunct that moved below a join
+        reaches the file scan under it) and constant folding.
+        ``session.plan_bound`` runs them BEFORE it splits the plan into a
         shape and values: ``to_date('1994-01-01')`` becomes a DATE literal
-        first and is lifted then.  Returns the plan and one description a
-        fold.  Non-mutating (but for the UDF compiler's in-place edit)."""
+        first and is lifted then.  Returns the plan and what was done to
+        it.  Non-mutating (but for the UDF compiler's in-place edit)."""
         if self.conf.get("spark.rapids.sql.udfCompiler.enabled", False):
             plan = _compile_plan_udfs(plan)
+        with span("plan", "pushdown") as sp:
+            plan, join_pushes = push_filters_through_joins(plan)
+            plan, narrowed = narrow_join_inputs(plan)
+            sp.set(joins=len(join_pushes), narrowed=narrowed)
         if self.conf.get("spark.rapids.sql.scan.pushdown.enabled", True) \
                 not in (False, "false"):
             plan = _pushdown_scan_filters(plan)
@@ -258,12 +299,12 @@ class TpuOverrides:
         with span("plan", "fold") as sp:
             plan, folded = _fold_constants(plan)
             sp.set(folded=len(folded))
-        return plan, folded
+        return plan, RewriteNotes(folded, join_pushes)
 
     def apply(self, plan: L.LogicalPlan) -> PhysicalOp:
         return self.lower(*self.rewrite_logical(plan))
 
-    def lower(self, plan: L.LogicalPlan, folded: List[str]) -> PhysicalOp:
+    def lower(self, plan: L.LogicalPlan, notes: RewriteNotes) -> PhysicalOp:
         """Tag and lower a plan :meth:`rewrite_logical` has been over
         (lifted literals, where the caller shares the result among the
         queries of a shape, already slotted)."""
@@ -271,7 +312,7 @@ class TpuOverrides:
         meta = PlanMeta(plan, self.conf)
         self.tag(meta)
         self.explain = PlanExplain(meta.explain_lines(), absorbed)
-        self.last_explain = self.explain.render(folded)
+        self.last_explain = self.explain.render(notes)
         if self.conf.explain_enabled:
             # routed through the obs sink (a logger by default) instead of
             # print(): library embedders and pytest capture aren't spammed,
@@ -287,7 +328,7 @@ class TpuOverrides:
         phys = assign_op_ids(phys)
         # what the plan was FIRST built with; a query publishes its own
         # count as last_metrics["foldedExprs"]
-        phys.folded_exprs = len(folded)
+        phys.folded_exprs = len(notes.folded)
         return phys
 
     def _shuffle_parts(self) -> int:
@@ -691,14 +732,6 @@ class _FakeNode:
         return self._schema
 
 
-def _split_conjuncts(e: Expression) -> List[Expression]:
-    from spark_rapids_tpu.exprs.predicates import And
-    if isinstance(e, And):
-        return _split_conjuncts(e.children[0]) + \
-            _split_conjuncts(e.children[1])
-    return [e]
-
-
 def _pushdown_scan_filters(plan: L.LogicalPlan) -> L.LogicalPlan:
     """Push Filter conjuncts into a child FileScan so the parquet reader can
     skip row groups on statistics and prune partition directories
@@ -836,14 +869,11 @@ def _filters_into_keyless_aggregates(plan: L.LogicalPlan
     import copy
     absorbed: List[Expression] = []
 
-    def context_free(e: Expression) -> bool:
-        return not e.collect(lambda x: not x.context_free)
-
     def absorbable(agg: L.Aggregate, cond: Expression) -> bool:
-        return bool(agg.aggs) and context_free(cond) and all(
+        return bool(agg.aggs) and deterministic(cond) and all(
             type(a.fn) in _NULL_SKIPPING_AGGS
             and not a.fn.child.dtype.is_string
-            and context_free(a.fn.child) for a in agg.aggs)
+            and deterministic(a.fn.child) for a in agg.aggs)
 
     def rewrite(node: L.LogicalPlan) -> L.LogicalPlan:
         children = tuple(rewrite(c) for c in node.children)

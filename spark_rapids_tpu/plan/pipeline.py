@@ -165,7 +165,8 @@ def note_stage_batches(op: PhysicalOp, batches: int, flag=None) -> None:
     Outside a collecting program body nobody would read the flag, and an
     unread flag is a wrong answer waiting: that is an error.  A note
     with no flag is a count only, and outside a stage program (an
-    operator's own per-batch program on the eager route) it is dropped."""
+    operator's own per-batch program on the eager route) a
+    :class:`BatchProgram` collects it the same way."""
     sink = getattr(_STAGE_NOTES, "sink", None)
     if sink is not None:
         sink.append((op, batches, flag))
@@ -184,6 +185,34 @@ def collect_stage_notes():
         yield sink
     finally:
         _STAGE_NOTES.sink = prev
+
+
+class BatchProgram:
+    """A per-batch program on the eager route (an operator's own
+    ``partitions``, an exchange's absorbed map stages): ``plan_jit(fn)``
+    that keeps what the operators inside it noted when it was traced and
+    counts it again on every call, as every dispatch of a stage program
+    does (``stage_ran``).  Nothing reads a flag out here, so an operator
+    that speculates must not be traced into one."""
+
+    def __init__(self, fn: Callable, label: str):
+        self._noted: Tuple[Tuple[PhysicalOp, int], ...] = ()
+
+        def body(batch):
+            with collect_stage_notes() as notes:
+                out = fn(batch)
+            if any(flag is not None for _op, _n, flag in notes):
+                raise RuntimeError(f"{label}: stage flag on the eager route")
+            self._noted = tuple((op, n) for op, n, _flag in notes)
+            return out
+
+        self._jit = plan_jit(body, label=label)
+
+    def __call__(self, ctx: ExecContext, batch):
+        out = self._jit(batch)
+        for op, n in self._noted:
+            op.stage_ran(ctx, n, False)
+        return out
 
 
 def _scoped(f: Callable, scope: str) -> Callable:

@@ -31,7 +31,7 @@ class _PlanNotes:
 
     def __init__(self):
         self.parse_ns = 0
-        self.shaped = None   # (rewrite flags, PlanShape, fold descriptions)
+        self.shaped = None   # (rewrite flags, PlanShape, RewriteNotes)
 
 
 _PLAN_NOTES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
@@ -201,7 +201,8 @@ class TpuSparkSession:
         BoundParams` — whoever drives ``phys`` does so under
         ``params.executing(bound)`` — and the plan facts a query
         publishes (``planShapeHit``, ``boundParams``, ``bakedLiterals``,
-        ``foldedExprs``, ``parseNs``, ``planShapeNs``, ``planBindNs``)."""
+        ``foldedExprs``, ``pushedJoinFilters``, ``joinKeysFromWhere``,
+        ``parseNs``, ``planShapeNs``, ``planBindNs``)."""
         from spark_rapids_tpu.plan.logical import plan_shape
         from spark_rapids_tpu.plan.overrides import TpuOverrides
         from spark_rapids_tpu.serve.excache import (
@@ -219,10 +220,10 @@ class TpuSparkSession:
                  lift)
         with span("plan", "shape") as shape_span:
             if notes.shaped is None or notes.shaped[0] != flags:
-                rewritten, folded = overrides.rewrite_logical(plan)
+                rewritten, rewrites = overrides.rewrite_logical(plan)
                 notes.shaped = (flags, plan_shape(rewritten, lift=lift),
-                                folded)
-            _flags, shape, folded = notes.shaped
+                                rewrites)
+            _flags, shape, rewrites = notes.shaped
         # obs knobs never change the plan: excluding them keeps the memo
         # (and therefore every compiled kernel) hittable when a
         # measurement run toggles the observability bus
@@ -231,7 +232,7 @@ class TpuSparkSession:
             if not k.startswith("spark.rapids.sql.tpu.obs.")))
 
         def _build():
-            phys = overrides.lower(shape.plan, folded)
+            phys = overrides.lower(shape.plan, rewrites)
             return PlanEntry(phys, overrides.explain, shape.dtypes,
                              shape.pinned)
 
@@ -239,10 +240,13 @@ class TpuSparkSession:
             shape.fingerprint, conf_state, _build)
         with span("plan", "bind") as bind_span:
             bound = entry.bind(shape.values)
-        self._explained = (entry.explain, folded, shape.values)
+        self._explained = (entry.explain, rewrites, shape.values)
         return entry.phys, bound, {
             "planShapeHit": int(hit), "boundParams": len(shape.values),
-            "bakedLiterals": shape.baked, "foldedExprs": len(folded),
+            "bakedLiterals": shape.baked,
+            "foldedExprs": len(rewrites.folded),
+            "pushedJoinFilters": rewrites.pushed_join_filters,
+            "joinKeysFromWhere": rewrites.join_keys_from_where,
             "parseNs": notes.parse_ns,
             "planShapeNs": shape_span.elapsed_ns,
             "planBindNs": bind_span.elapsed_ns}
@@ -255,8 +259,8 @@ class TpuSparkSession:
         explained = getattr(self, "_explained", None)
         if explained is None:
             return ""
-        explain, folded, values = explained
-        return explain.render(folded, values)
+        explain, rewrites, values = explained
+        return explain.render(rewrites, values)
 
     def _plan_and_context(self, plan):
         """Everything between entry and the first dispatch: conf-shaped
@@ -471,6 +475,8 @@ class TpuSparkSession:
         frame.last_metrics["boundParams"] = facts["boundParams"]
         frame.last_metrics["bakedLiterals"] = facts["bakedLiterals"]
         frame.last_metrics["foldedExprs"] = facts["foldedExprs"]
+        frame.last_metrics["pushedJoinFilters"] = facts["pushedJoinFilters"]
+        frame.last_metrics["joinKeysFromWhere"] = facts["joinKeysFromWhere"]
         frame.last_metrics["parseNs"] = facts["parseNs"]
         frame.last_metrics["planShapeNs"] = facts["planShapeNs"]
         frame.last_metrics["planBindNs"] = facts["planBindNs"]
@@ -559,10 +565,14 @@ class TpuSparkSession:
         # aggregate economics (TpuHashAggregateExec): update batches that
         # took the slot contraction (keyed) or the reduction (keyless), of
         # the update batches keyed / keyless aggregates saw in all; and
-        # the batches a TpuFilterExec compacted (kernels/layout.compact)
+        # the batches a TpuFilterExec compacted (kernels/layout.compact);
+        # and what the equi-joins read back to size their outputs: the
+        # candidate pairs, the number of such reads, and a side's rows
+        # where the host held them already (it concatenated the side)
         for key in ("mxuAggBatches", "keyedUpdateBatches",
                     "keylessAggBatches", "keylessUpdateBatches",
-                    "filterCompactedBatches"):
+                    "filterCompactedBatches", "joinPairs", "joinSizeReads",
+                    "joinProbeRows", "joinBuildRows"):
             frame.last_metrics[key] = _scan_sum(key)
         frame.last_metrics["scanDecodeWallNs"] = _scan_sum("scanDecodeWallNs")
         frame.last_metrics["scanH2dOverlapNs"] = _scan_sum("scanH2dOverlapNs")

@@ -4,8 +4,13 @@ Grammar (enough for the TPC-H/TPC-DS-style workloads the reference
 benchmarks with, SURVEY.md section 4.5):
 
   query     := select [UNION ALL select]* [ORDER BY ...] [LIMIT n]
-  select    := SELECT [DISTINCT] proj (, proj)* FROM source (join)*
+  select    := SELECT [DISTINCT] proj (, proj)* FROM from_item (, from_item)*
                [WHERE expr] [GROUP BY expr*] [HAVING expr]
+  from_item := source (join)*     (a comma is a cross join of the items on
+               its two sides, binding looser than JOIN: TPC-H's own
+               ``FROM orders, lineitem WHERE o_orderkey = l_orderkey``; the
+               planner turns the WHERE equalities into join keys,
+               plan/join_pushdown.py)
   source    := ident [[AS] alias] | ( query ) [AS] alias
   join      := [INNER|LEFT [OUTER]|RIGHT [OUTER]|FULL [OUTER]|LEFT SEMI|
                LEFT ANTI|CROSS] JOIN source (ON expr | USING (cols))
@@ -190,8 +195,9 @@ class Parser:
             if not self.accept("op", ","):
                 break
         self.expect("keyword", "from")
-        df = self.parse_source()
-        df = self.parse_joins(df)
+        df = self.parse_joins(self.parse_source())
+        while self.accept("op", ","):
+            df = df.cross_join(self.parse_joins(self.parse_source()))
         where = None
         if self.accept("keyword", "where"):
             where = self.parse_expr()

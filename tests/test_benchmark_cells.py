@@ -84,3 +84,51 @@ def test_new_cell_is_correct_and_drives_what_it_measures(
         assert read("compacted_batches_per_query") >= 1
         assert read("scan_bytes_per_row") is None
         assert read("keyless_reduce_pct") is None
+
+
+def test_join_cell_is_correct_and_drives_what_it_measures(
+        harness, monkeypatch):
+    """``tpch_sf1_join.q12``: Q12's source text through the planner's join
+    rules, the equi-join and the keyed aggregate behind it; the three
+    readers this cell brings read what its plan counts."""
+    result, run = _rehearse(harness, monkeypatch, "tpch_sf1_join.q12")
+    assert result["correct"] and result["failed"] == 0, result["compared"]
+    assert result["compared"]["wrong_answers"]["value"] == 0
+    assert result["compared"]["max_rel_gap"] == {"value": 0.0, "limit": 0.0}
+    assert set(result["metrics"]) == {
+        "rehearsal.rows_per_s", "rehearsal.query_p95_ms",
+        "rehearsal.setup_s"}
+    assert result["info"]["rows"] == {"lineitem": 12002, "orders": 3000}
+    records = run["records"]
+    assert records and all(r["answered"] for r in records)
+    frames = harness.reference_frames(
+        run["config"], ("lineitem", "orders"), run["rows"], 34)
+    joined = len(run["queries"]["q12"].joined(frames))
+    for r in records:
+        c = r["counters"]
+        assert c["compileCount"] == 0           # the window compiles nothing
+        assert (c["pushedJoinFilters"], c["joinKeysFromWhere"]) == (5, 1)
+        assert c["joinPairs"] == joined > 0
+        assert c["filterCompactedBatches"] >= 1  # lineitem, under the join
+        assert c["keyedUpdateBatches"] >= 1      # l_shipmode, after the join
+        assert c["keylessUpdateBatches"] == 0
+        assert sum(len(row) for row in r["rows"]) == 3 * len(r["rows"])
+
+    def read(metric):
+        return harness.load_reader("layer_metrics", metric).read(run)
+
+    assert read("join_filters_pushed") == 5
+    assert read("join_pairs_per_query") == joined
+    assert read("join_size_reads_per_query") == 3   # pairs + two string columns
+    assert read("compacted_batches_per_query") >= 1
+    assert read("keyed_contraction_pct") is not None
+    assert read("keyless_reduce_pct") is None
+    rows = run["queries"]["q12"].scanned_rows(run["rows"])
+    assert rows == 12002 + 3000
+    # the entries this cell added keep to the file's limits
+    import json
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for entry in bench["configs"] + bench["workloads"]:
+        assert len(entry["why"]) <= 200, entry["name"]
+        assert len(entry.get("source", "")) <= 200, entry["name"]

@@ -838,16 +838,24 @@ def test_benchmark_json_gained_only_the_seven_entries():
                                  # PR 34's five, each listing its cells
                                  "scan_decode_ms", "scan_bytes_per_row",
                                  "scan_overlap_pct", "keyed_contraction_pct",
-                                 "compacted_batches_per_query"]
+                                 "compacted_batches_per_query",
+                                 # PR 36's three, of the join cell alone
+                                 "join_filters_pushed",
+                                 "join_pairs_per_query",
+                                 "join_size_reads_per_query"]
     q6_cells = ["tpch_sf1_cached.q6", "tpch_sf1_qgen.q6_text",
                 "tpch_sf1_parquet.q6"]       # those with a keyless aggregate
     for m in bench["per_layer"][first:first + 12]:
         assert m.get("workloads", ["tpch_sf1_qgen.q6_text"]) == (
             q6_cells if m["name"] == "keyless_reduce_pct"
             else ["tpch_sf1_qgen.q6_text"])
-    for m in bench["per_layer"][first + 12:]:
+    for m in bench["per_layer"][first + 12:first + 17]:
         assert set(m["workloads"]) <= {"tpch_sf1_cached.q1",
-                                       "tpch_sf1_parquet.q6"}
+                                       "tpch_sf1_parquet.q6",
+                                       "tpch_sf1_join.q12"}
+    for m in bench["per_layer"][first + 17:]:
+        assert m["workloads"] == ["tpch_sf1_join.q12"]
+        assert m["moves"] == "rows_per_s"
     for m in bench["per_layer"][first:]:
         assert os.path.exists(os.path.join(
             REPO_ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
