@@ -407,13 +407,14 @@ def _array_host_to_buffers(dtype: T.ArrayType, values: np.ndarray,
     return offsets, data
 
 
-def host_column_to_device(col: HostColumn, capacity: int,
-                          device=None) -> DeviceColumn:
+def _pack_column(col: HostColumn, capacity: int) -> DeviceColumn:
+    """The host's half of staging a column: its buffers packed at
+    ``capacity`` as numpy arrays (validity, strings' offsets and bytes,
+    dictionary codes), in the layout :func:`_put_column` copies."""
     n = len(col)
     assert capacity >= n
     validity = np.zeros(capacity, dtype=np.bool_)
     validity[:n] = col.validity
-    put = (lambda x: jax.device_put(x, device)) if device is not None else jax.device_put
     if col.dictionary is not None and col.dtype.is_string:
         # dictionary-encoded staging: ship int32 codes plus the (small)
         # dictionary buffers instead of per-row string bytes
@@ -431,8 +432,7 @@ def host_column_to_device(col: HostColumn, capacity: int,
         ent_lens = (d_offsets[1:] - d_offsets[:-1]).astype(np.int64)
         mat_total = int(ent_lens[safe[col.validity]].sum()) \
             if len(entries) and n else 0
-        return DeviceColumn(col.dtype, put(d_data), put(validity),
-                            put(full_d_off), put(codes),
+        return DeviceColumn(col.dtype, d_data, validity, full_d_off, codes,
                             BUCKETS.elems(mat_total))
     if col.dtype.is_string or col.dtype.is_array:
         if col.dtype.is_string:
@@ -442,38 +442,54 @@ def host_column_to_device(col: HostColumn, capacity: int,
                                                    col.validity)
         full_offsets = np.full(capacity + 1, offsets[-1], dtype=np.int32)
         full_offsets[: n + 1] = offsets
-        return DeviceColumn(col.dtype, put(data), put(validity), put(full_offsets))
+        return DeviceColumn(col.dtype, data, validity, full_offsets)
     data = np.zeros(capacity, dtype=col.dtype.np_dtype)
     data[:n] = col.values
-    return DeviceColumn(col.dtype, put(data), put(validity), None)
+    return DeviceColumn(col.dtype, data, validity, None)
+
+
+def _put_column(packed: DeviceColumn, device=None) -> DeviceColumn:
+    """The copy's half: every buffer of a :func:`_pack_column` result
+    handed to ``jax.device_put``."""
+    def put(x):
+        return None if x is None else jax.device_put(x, device)
+    return DeviceColumn(packed.dtype, put(packed.data),
+                        put(packed.validity), put(packed.offsets),
+                        put(packed.codes), packed.mat_byte_cap)
 
 
 def host_to_device(batch: HostBatch, capacity: Optional[int] = None,
                    device=None) -> ColumnBatch:
+    """Stage ``batch`` on the device, a column at a time: the host packs
+    the column (``srt/pack/column``), then its buffers are copied
+    (``srt/h2d/transfer``, the ``device_put`` calls), so the runtime's
+    copy of one column runs while the host packs the next.
+    ``device_put`` is asynchronous on a TPU, so a copy's span is its
+    enqueue side; the df.cache() staging path, where nothing overlaps
+    it, ends its own ``h2d`` span in a sync (ops.tpu_exec.
+    TpuCachedScanExec)."""
     from spark_rapids_tpu.fault import inject
     from spark_rapids_tpu.utils.compile_registry import record_transfer
     from spark_rapids_tpu.utils.tracing import span
     inject.maybe_fire("h2d")
+    cap = capacity if capacity is not None \
+        else round_up_capacity(batch.num_rows)
+    cols, nbytes = [], 0
+    for c in batch.columns:
+        with span("pack", "column"):
+            packed = _pack_column(c, cap)
+        with span("h2d", "transfer") as sp:
+            cols.append(_put_column(packed, device))
+            sp.set(bytes=sum(leaf.nbytes for leaf in
+                             jax.tree_util.tree_leaves(cols[-1])))
+        nbytes += sp.payload["bytes"]
     with span("h2d", "transfer") as sp:
-        cap = capacity if capacity is not None \
-            else round_up_capacity(batch.num_rows)
-        cols = [host_column_to_device(c, cap, device) for c in batch.columns]
         num_rows = jnp.asarray(batch.num_rows, dtype=jnp.int32)
         if device is not None:
             num_rows = jax.device_put(num_rows, device)
-        out = ColumnBatch(batch.schema, cols, num_rows, cap)
-        nbytes = sum(getattr(leaf, "nbytes", 0)
-                     for leaf in jax.tree_util.tree_leaves(out))
-        sp.set(bytes=nbytes)
-    # enqueue-side wall: device_put is async on real TPUs, so h2dTimeNs
-    # is host-pack + transfer-enqueue time (h2d_gb_per_sec reads as an
-    # upper bound there; exact on the synchronous CPU backend).  Blocking
-    # here for accuracy would serialize staging against device compute —
-    # the overlap this layer exists to create.  The df.cache() staging
-    # path, where nothing overlaps it, ends its own h2d span in a sync
-    # (ops.tpu_exec.TpuCachedScanExec).
-    record_transfer("h2d", nbytes, sp.elapsed_ns)
-    return out
+        sp.set(bytes=num_rows.nbytes)
+    record_transfer("h2d", nbytes + num_rows.nbytes)
+    return ColumnBatch(batch.schema, cols, num_rows, cap)
 
 
 def device_to_host_many(batches: Sequence[ColumnBatch],
@@ -500,17 +516,19 @@ def device_to_host_with(batches: Sequence[ColumnBatch], riders,
     )
     from spark_rapids_tpu.utils.tracing import device_wait, span
     inject.maybe_fire("d2h")
-    guard_check(list(batches), "device_to_host_many")
-    tree = [
-        (b.num_rows,
-         [(c.data, c.validity, c.offsets, c.codes) if c.codes is not None
-          else (c.data, c.validity, c.offsets) if c.offsets is not None
-          else (c.data, c.validity) for c in b.columns])
-        for b in batches]
-    for leaf in jax.tree_util.tree_leaves((tree, riders)):
-        start = getattr(leaf, "copy_to_host_async", None)
-        if start is not None:
-            start()
+    with span("d2h", "start"):
+        guard_check(list(batches), "device_to_host_many")
+        tree = [
+            (b.num_rows,
+             [(c.data, c.validity, c.offsets, c.codes)
+              if c.codes is not None
+              else (c.data, c.validity, c.offsets) if c.offsets is not None
+              else (c.data, c.validity) for c in b.columns])
+            for b in batches]
+        for leaf in jax.tree_util.tree_leaves((tree, riders)):
+            start = getattr(leaf, "copy_to_host_async", None)
+            if start is not None:
+                start()
     device_wait("d2h_ready", (tree, riders))
     with span("d2h", "transfer") as sp:
         host, riders = jax.device_get((tree, riders))
@@ -519,7 +537,7 @@ def device_to_host_with(batches: Sequence[ColumnBatch], riders,
             for _num_rows, col_bufs in host
             for bufs in col_bufs for buf in bufs)
         sp.set(bytes=nbytes)
-    record_transfer("d2h", nbytes, sp.elapsed_ns)
+        record_transfer("d2h", nbytes)
     with span("result", "assemble"):
         return _host_batches(batches, host, keep_dictionary), riders
 

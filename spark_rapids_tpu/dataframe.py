@@ -818,9 +818,24 @@ class DataFrame:
     # -- actions ------------------------------------------------------------
 
     def collect(self) -> List[tuple]:
-        hb = self.session.execute(self.plan)
-        cols = [c.to_list() for c in hb.columns]
-        return [tuple(c[i] for c in cols) for i in range(hb.num_rows)]
+        """The rows; the query's metrics (``session.last_metrics``) hold
+        this call's wall as ``collectWallNs``, the row tuples' making
+        included (the ``srt/result/rows`` span)."""
+        import time
+        from spark_rapids_tpu.utils.tracing import span
+        t0 = time.monotonic_ns()
+        hb, metrics = self.session.execute_with_metrics(self.plan)
+        # after the query's scope closed: a span of the profiler alone
+        with span("result", "rows", ring=False) as rows:
+            cols = [c.to_list() for c in hb.columns]
+            out = [tuple(c[i] for c in cols) for i in range(hb.num_rows)]
+        # the call's wall holds execute_with_metrics'; what of the rest
+        # the rows' span does not cover is under no span
+        wall = time.monotonic_ns() - t0
+        metrics["unspannedNs"] += \
+            wall - metrics["collectWallNs"] - rows.elapsed_ns
+        metrics["collectWallNs"] = wall
+        return out
 
     def to_pydict(self) -> Dict[str, List[Any]]:
         return self.session.execute(self.plan).to_pydict()

@@ -69,18 +69,22 @@ class partition_deadline:
     def __enter__(self):
         if self.timeout <= 0:
             return self
-        self._tid = threading.get_ident()
-        self._cancel = threading.Event()
-        self._lock = threading.Lock()
-        self._done = False
         from spark_rapids_tpu.obs import events as obs_events
-        # adopt the arming query's scope on the monitor so the fire
-        # event lands in the right query's timeline under concurrency
-        self._scope = obs_events.current_scope()
-        self._thread = threading.Thread(
-            target=self._watch, daemon=True,
-            name=f"partition-deadline:{self.label}")
-        self._thread.start()
+        from spark_rapids_tpu.utils.tracing import span
+        # the monitor's start is the arming half of the hand-off the
+        # join below is the other half of
+        with span("fault", "watchdog_start"):
+            self._tid = threading.get_ident()
+            self._cancel = threading.Event()
+            self._lock = threading.Lock()
+            self._done = False
+            # adopt the arming query's scope on the monitor so the fire
+            # event lands in the right query's timeline under concurrency
+            self._scope = obs_events.current_scope()
+            self._thread = threading.Thread(
+                target=self._watch, daemon=True,
+                name=f"partition-deadline:{self.label}")
+            self._thread.start()
         return self
 
     def _watch(self):
@@ -100,13 +104,13 @@ class partition_deadline:
     def __exit__(self, exc_type, exc, tb):
         if self._thread is None:
             return False
-        with self._lock:
-            self._done = True
-        self._cancel.set()
         # the monitor wakes and exits: a thread hand-off the query waits
         # for on every partition/collect, so it is on the timeline
         from spark_rapids_tpu.utils.tracing import span
         with span("fault", "watchdog_join"):
+            with self._lock:
+                self._done = True
+            self._cancel.set()
             self._thread.join(timeout=1.0)
         if self.fired:
             if exc_type is None:

@@ -17,12 +17,16 @@ Priority encodes the blocking chain: ``device_wait`` — the host blocked
 on the chip (a size read-back, the wait before a D2H copy, an
 exchange's sync) — first, so an exchange's credit is its span wall
 MINUS the waits nested inside it, i.e. the host-side shuffle cost.
-``enqueue`` (the host wall of an asynchronous jitted call; never device
-time) ranks below every site that names real work, and the outer host
-parts of ``session.execute_with_metrics`` (``stage``, ``stage_inputs``,
-``result``, ``plan``, ``bookkeeping``) last, innermost first: each is
-credited only what nothing inside it claims.  The window is the whole
-of ``execute_with_metrics``, so the segments are ``queryWallNs``.
+``pack`` (the host's packing of a staged batch) ranks just below the
+``h2d`` copy it feeds.  ``enqueue`` (the host wall of an asynchronous
+jitted call; never device time) ranks below every site that names real
+work, and the outer host parts of ``session.execute_with_metrics``
+(``stage``, ``stage_inputs``, ``result``, ``plan``, ``bookkeeping``)
+last, innermost first: each is credited only what nothing inside it
+claims.  The window is ``execute_with_metrics`` from its scope's
+opening to the stamp before the ring is drained, so the segments are
+``queryWallNs``; ``unspannedNs`` is ``wait`` plus what of the call's
+wall outside the window no span covers.
 
 Engine-free (stdlib only, duck-typed events) so ``rapidsprof
 --critpath`` reconstructs the same decomposition offline from a JSONL
@@ -31,14 +35,15 @@ event log.
 
 from __future__ import annotations
 
+import heapq
 from typing import Any, Dict, List, Optional, Tuple
 
-from .events import SPAN, field
+from .events import SPAN, fields
 
 #: Site attribution priority, highest first.  ``wait`` (uncovered wall)
 #: is not a site — it is the remainder.
 SITE_PRIORITY: Tuple[str, ...] = (
-    "device_wait", "h2d", "d2h", "spill", "unspill", "exchange", "mesh",
+    "device_wait", "h2d", "pack", "d2h", "spill", "unspill", "exchange", "mesh",
     "scan", "io", "pallas", "retry", "fault", "enqueue",
     "stage", "stage_inputs", "result", "plan", "bookkeeping",
 )
@@ -47,11 +52,12 @@ WAIT = "wait"
 OTHER = "other"
 
 
+_RANK = {site: i for i, site in enumerate(SITE_PRIORITY)}
+
+
 def _rank(site: str) -> int:
-    try:
-        return SITE_PRIORITY.index(site)
-    except ValueError:
-        return len(SITE_PRIORITY)  # unknown sites: lowest known priority
+    # unknown sites: lowest known priority
+    return _RANK.get(site, len(SITE_PRIORITY))
 
 
 class CritPath:
@@ -71,7 +77,7 @@ class CritPath:
     @property
     def attributed_ns(self) -> int:
         """Nanoseconds attributed to concrete sites (window minus the
-        ``wait`` remainder) — the ``critpathAttributedNs`` metric."""
+        ``wait`` remainder)."""
         return self.total_ns - self.segments.get(WAIT, 0)
 
     def top_site(self) -> str:
@@ -109,38 +115,37 @@ def compute(events: List[Any], t0: int, t1: int) -> CritPath:
     spans: List[Tuple[int, int, int, str]] = []  # (start, end, rank, site)
     cuts = {t0, t1}
     for ev in events:
-        if field(ev, "kind") != SPAN:
+        kind, site, _name, _op, raw_t0, raw_t1, _pay = fields(ev)
+        if kind != SPAN:
             continue
-        raw_t0 = int(field(ev, "t0", 0) or 0)
+        raw_t0 = int(raw_t0 or 0)
         if raw_t0 <= 0:
             continue  # unstamped span: no defensible placement
         s = max(t0, raw_t0)
-        e = min(t1, int(field(ev, "t1", 0) or 0))
+        e = min(t1, int(raw_t1 or 0))
         if e <= s:
             continue
-        site = field(ev, "site") or OTHER
+        site = site or OTHER
         spans.append((s, e, _rank(site), site))
         cuts.add(s)
         cuts.add(e)
     bounds = sorted(cuts)
-    # active-span sweep: spans sorted by start; a heap-free variant is
-    # fine at per-query event counts (ring-bounded)
+    # active-span sweep: spans sorted by start, the covering ones in a
+    # heap by rank; a span that has ended leaves it when it reaches the
+    # top (only the top is read)
     spans.sort()
     segments: Dict[str, int] = {}
     chain: List[Tuple[str, int, int]] = []
-    si = 0
+    si, n = 0, len(spans)
     active: List[Tuple[int, int, str]] = []  # (rank, end, site)
-    for i in range(len(bounds) - 1):
-        lo, hi = bounds[i], bounds[i + 1]
-        while si < len(spans) and spans[si][0] <= lo:
-            s, e, rank, site = spans[si]
-            active.append((rank, e, site))
+    for lo, hi in zip(bounds, bounds[1:]):
+        while si < n and spans[si][0] <= lo:
+            _s, e, rank, site = spans[si]
+            heapq.heappush(active, (rank, e, site))
             si += 1
-        active = [a for a in active if a[1] > lo]
-        if active:
-            site = min(active)[2]
-        else:
-            site = WAIT
+        while active and active[0][1] <= lo:
+            heapq.heappop(active)
+        site = active[0][2] if active else WAIT
         segments[site] = segments.get(site, 0) + (hi - lo)
         if chain and chain[-1][0] == site and chain[-1][2] == lo:
             chain[-1] = (site, chain[-1][1], hi)

@@ -49,6 +49,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+from operator import attrgetter
 from typing import Any, Dict, List, Optional, Tuple
 
 from . import timeseries as _ts
@@ -103,12 +104,19 @@ class Event:
                 f"op={self.op_id or '-'} dur={self.t1 - self.t0}ns)")
 
 
-def field(ev, key: str, default=None):
-    """Duck-typed event accessor: works on :class:`Event` objects and on
-    the plain dicts a JSONL event log round-trips through."""
+_FIELDS = ("kind", "site", "name", "op_id", "t0", "t1", "payload")
+_EVENT_FIELDS = attrgetter(*_FIELDS)
+
+
+def fields(ev) -> tuple:
+    """``(kind, site, name, op_id, t0, t1, payload)`` of an event in one
+    call, duck-typed: an :class:`Event` object or the plain dict a JSONL
+    event log round-trips through (a missing key reads None).  What a
+    per-query pass over every event (``critpath``, the profile's fold)
+    reads of each."""
     if isinstance(ev, dict):
-        return ev.get(key, default)
-    return getattr(ev, key, default)
+        return tuple(ev.get(k) for k in _FIELDS)
+    return _EVENT_FIELDS(ev)
 
 
 class EventBus:

@@ -11,7 +11,7 @@ operator ate the DEVICE's time is the device trace's to say:
 
 Engine-free (stdlib only): ``tools/rapidsprof.py`` builds the same
 profiles from a JSONL event log, so events are accessed duck-typed via
-:func:`~spark_rapids_tpu.obs.events.field` (Event objects in-process,
+:func:`~spark_rapids_tpu.obs.events.fields` (Event objects in-process,
 plain dicts after a log round-trip).
 """
 
@@ -19,7 +19,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
-from .events import SPAN, field
+from .events import SPAN, fields
 
 
 def _new_rollup(name: str) -> Dict[str, Any]:
@@ -80,13 +80,13 @@ class QueryProfile:
 
     def _fold(self) -> None:
         for ev in self.events:
-            kind = field(ev, "kind")
-            site = field(ev, "site") or "?"
-            name = field(ev, "name") or ""
-            op_id = field(ev, "op_id") or ""
-            t0 = int(field(ev, "t0", 0) or 0)
-            t1 = int(field(ev, "t1", 0) or 0)
-            pay = field(ev, "payload") or {}
+            kind, site, name, op_id, t0, t1, pay = fields(ev)
+            site = site or "?"
+            name = name or ""
+            op_id = op_id or ""
+            t0 = int(t0 or 0)
+            t1 = int(t1 or 0)
+            pay = pay or {}
             st = self.site_totals.setdefault(
                 site, {"count": 0, "wall_ns": 0, "bytes": 0})
             st["count"] += 1

@@ -1,7 +1,9 @@
 """Read a ``jax.profiler`` trace (``.xplane.pb``) by operator: device
 self-time by stage program -> operator scope -> kernel or expression
-scope, and idle gaps by the program span (``srt/<site>/<name>``) the
-host was in.
+scope, idle gaps by the program span (``srt/<site>/<name>``) the host
+was in, and the host time inside each ``srt/enqueue/<program>`` span by
+the event it was spent in, the runtime's threads included (``(self)``:
+the span's own, under no event).
 
 ``tools/rapidsprof.py --xplane`` prints what :func:`reduce_xplane`
 returns.  The file is parsed with tsl's generated ``xplane_pb2`` (see
@@ -24,6 +26,7 @@ the harness; ROADMAP B3's follow-up makes the two share one reducer.
 
 from __future__ import annotations
 
+import bisect
 import importlib.util
 import os
 import re
@@ -33,6 +36,8 @@ PREFIX = "srt/"
 WINDOW_SPAN = "bench:query"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+ENQUEUE = PREFIX + "enqueue/"
+SELF = "(self)"
 #: a gap this short is the device's own turn-around between two
 #: operations, not the host's doing (benchmark/trace_reduce.py's limit)
 SHORT_GAP_PS = 20_000_000
@@ -157,15 +162,18 @@ def reduce_xplane(path: str) -> Optional[dict]:
                and _events(p, OPS_LINE)]
     if not devices:
         return None
-    host: List[Tuple[str, int, int]] = []
+    #: (thread line's name, [(event name, start ps, end ps)])
+    host_lines: List[Tuple[str, List[Tuple[str, int, int]]]] = []
     for p in planes:
         if p.name.startswith("/host:"):
             for ln in p.lines:
                 t0_ps = ln.timestamp_ns * 1000
-                host += [(p.event_metadata[ev.metadata_id].name,
-                          t0_ps + ev.offset_ps,
-                          t0_ps + ev.offset_ps + ev.duration_ps)
-                         for ev in ln.events]
+                host_lines.append((ln.name, [
+                    (p.event_metadata[ev.metadata_id].name,
+                     t0_ps + ev.offset_ps,
+                     t0_ps + ev.offset_ps + ev.duration_ps)
+                    for ev in ln.events]))
+    host = [h for _thread, line in host_lines for h in line]
     marks = [h for h in host if h[0] == WINDOW_SPAN] or \
         [h for h in host if h[0].startswith(PREFIX)]
     dev = devices[0]
@@ -231,7 +239,50 @@ def reduce_xplane(path: str) -> Optional[dict]:
         "operator_scope_share": under_operator / total,
         "srt_gap_share": long_idle / attributable if attributable else 1.0,
         "host_spans": _span_totals(spans, w0, w1),
+        "enqueue_parts": _enqueue_parts(host_lines, w0, w1),
     }
+
+
+def _enqueue_parts(host_lines, w0: int, w1: int
+                   ) -> List[Tuple[str, str, float, int]]:
+    """(enqueue span, part, seconds, events): the self time, on its own
+    line, of every host event that lies inside an ``srt/enqueue/``
+    span, by the event's name — prefixed ``<thread>: `` where the event
+    is on another thread's line (the runtime's: output allocation, the
+    launch) — and the span's own self time as ``(self)``."""
+    enq = sorted((s, e, name) for _thread, line in host_lines
+                 for name, s, e in line
+                 if name.startswith(ENQUEUE) and e > w0 and s < w1)
+    starts = [s for s, _e, _n in enq]
+    parts: Dict[Tuple[str, str], List[int]] = {}
+    for thread, line in host_lines:
+        # the enqueueing thread's own events bare, another's by thread
+        prefix = "" if any(n.startswith(ENQUEUE) for n, _s, _e in line) \
+            else thread.split("/")[0] + ": "
+        stack: List[List] = []   # [name, start, end, self]
+
+        def close(upto: int) -> None:
+            while stack and stack[-1][2] <= upto:
+                name, s, e, own_ps = stack.pop()
+                i = bisect.bisect_right(starts, s) - 1
+                if i < 0 or e > enq[i][1]:
+                    continue          # not inside an enqueue span
+                span = enq[i][2]
+                part = SELF if (name, s) == (span, enq[i][0]) \
+                    else prefix + name
+                acc = parts.setdefault((span, part), [0, 0])
+                acc[0] += max(own_ps, 0)
+                acc[1] += 1
+
+        for name, s, e in sorted(line, key=lambda h: (h[1], -h[2])):
+            close(s)
+            if stack:
+                stack[-1][3] -= min(e, stack[-1][2]) - s
+            stack.append([name, s, e, e - s])
+        close(1 << 62)
+    return sorted(((span, part, ps / 1e12, n)
+                   for (span, part), (ps, n) in parts.items()),
+                  key=lambda r: -r[2])
 
 
 def _span_totals(spans, w0: int, w1: int) -> List[Tuple[str, int, float]]:
@@ -288,4 +339,10 @@ def format_report(r: dict, top: int = 25) -> str:
     for k, c, s in r["host_spans"][:top]:
         lines.append(f"  {k}: {c} x, {s:.6f} s ({1e3 * s / q:.3f} ms "
                      f"a query)")
+    lines += ["", "== host time inside srt/enqueue/ spans, by the event "
+                  "it was spent in (<thread>: another thread's, the "
+                  "runtime's; (self): under no event) =="]
+    for span, part, s, n in r["enqueue_parts"][:top]:
+        lines.append(f"  {span} / {part}: {n} x, {s:.6f} s "
+                     f"({1e3 * s / q:.3f} ms a query)")
     return "\n".join(lines)

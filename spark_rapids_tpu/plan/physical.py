@@ -559,7 +559,7 @@ def concat_result(batches: List[HostBatch]) -> HostBatch:
 
 def collect_host(op: PhysicalOp, ctx: ExecContext) -> HostBatch:
     """Drive a plan to completion and concatenate all partitions on host."""
-    from spark_rapids_tpu.utils.tracing import trace_range
+    from spark_rapids_tpu.utils.tracing import span, trace_range
     try:
         if op.is_tpu:
             hb = _history_cached_collect(op, ctx)
@@ -568,34 +568,31 @@ def collect_host(op: PhysicalOp, ctx: ExecContext) -> HostBatch:
             from spark_rapids_tpu.fault.recovery import (
                 run_pipeline_with_recovery,
             )
-            with trace_range("collect", "pipeline",
-                             ctx.metric("collect", "wallTimeNs")):
+            with trace_range("collect", "pipeline"):
                 hb = run_pipeline_with_recovery(op, ctx)
             if hb is not None:
                 return hb
-        t0 = time.monotonic()
         # a device root that inlines nothing (a join at the root) is
         # collected in bulk; a root on the host drives its partitions
         batches: List[HostBatch] = _collect_device_bulk(op, ctx) \
             if op.is_tpu else _drive_partitions(
                 op, ctx, release_partial=False)
-        ctx.metric("collect", "wallTimeNs").add(
-            int((time.monotonic() - t0) * 1e9))
         if not batches:
             return HostBatch(op.output_schema, [
                 _empty_host_col(f) for f in op.output_schema.fields
             ])
         return concat_result(batches)
     finally:
-        ctx.close_deferred()
-        # Give back any staging acquires whose batches never reached a
-        # per-batch release (dropped-empty join pairs, n->1 concats):
-        # the query is over, so the outstanding count must drain to zero
-        # or the permit leaks for the process lifetime.  The plan
-        # verifier (analysis/plan_verify.py) asserts the resulting
-        # held_depth() == 0 after every suite query.
-        if ctx.semaphore is not None:
-            _release_admission(ctx, getattr(ctx, "_pipeline_h2d", 0))
+        with span("bookkeeping", "release"):
+            ctx.close_deferred()
+            # Give back any staging acquires whose batches never reached
+            # a per-batch release (dropped-empty join pairs, n->1
+            # concats): the query is over, so the outstanding count must
+            # drain to zero or the permit leaks for the process lifetime.
+            # The plan verifier (analysis/plan_verify.py) asserts the
+            # resulting held_depth() == 0 after every suite query.
+            if ctx.semaphore is not None:
+                _release_admission(ctx, getattr(ctx, "_pipeline_h2d", 0))
 
 
 def _empty_host_col(f: T.Field):

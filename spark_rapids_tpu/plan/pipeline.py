@@ -688,22 +688,23 @@ def _stands(run: StageRun, flags, ctx: ExecContext) -> bool:
     discards it: the operators that raised one are told
     (``stage_flagged``) and nothing is counted.  Else the run stands and
     every noting operator counts its batches (``stage_ran``)."""
-    read = iter(flags)
-    flagged = [op for op, _n, speculated in run.notes
-               if speculated and bool(next(read).any())]
-    for op in flagged:
-        op.stage_flagged(ctx)
-    if flagged:
-        ctx.metric("pipeline", "flagReruns").add(1)
-        return False
-    inlined = 0
-    for op, n, speculated in run.notes:
-        op.stage_ran(ctx, n, speculated)
-        # an update below the root (a filter notes compactions only)
-        inlined += op is not run.root and hasattr(op, "stage_variant")
-    if inlined:
-        ctx.metric("pipeline", "inlinedUpdates").add(inlined)
-    return True
+    with span("bookkeeping", "stands", run.root.op_id):
+        read = iter(flags)
+        flagged = [op for op, _n, speculated in run.notes
+                   if speculated and bool(next(read).any())]
+        for op in flagged:
+            op.stage_flagged(ctx)
+        if flagged:
+            ctx.metric("pipeline", "flagReruns").add(1)
+            return False
+        inlined = 0
+        for op, n, speculated in run.notes:
+            op.stage_ran(ctx, n, speculated)
+            # an update below the root (a filter notes compactions only)
+            inlined += op is not run.root and hasattr(op, "stage_variant")
+        if inlined:
+            ctx.metric("pipeline", "inlinedUpdates").add(inlined)
+        return True
 
 
 def _run_stage(root: PhysicalOp, ctx: ExecContext,
@@ -723,9 +724,10 @@ def _dispatch_stage(root: PhysicalOp, ctx: ExecContext,
                     shrink: bool = True) -> StageRun:
     """Dispatch ``root``'s stage as one program.  ``shrink=True`` (the
     default, for directly-collected stages) re-buckets the outputs."""
-    variant = _stage_variant(root, ctx)
-    sources, _fn = _stage_build(root, ctx, variant)
-    minfo = getattr(root, "_mesh_stage_info", None)
+    with span("stage_inputs", "variant", root.op_id):
+        variant = _stage_variant(root, ctx)
+        sources, _fn = _stage_build(root, ctx, variant)
+        minfo = getattr(root, "_mesh_stage_info", None)
     if isinstance(minfo, dict) and variant in minfo:
         # the build fused at least one exchange as an in-program
         # all_to_all (or a join as a per-shard static kernel): this
@@ -754,40 +756,42 @@ def run_stage_unfused(root: PhysicalOp, ctx: ExecContext, variant: str,
 def _run_stage_host(root: PhysicalOp, ctx: ExecContext, variant: str,
                     sources: List[PhysicalOp], shrink: bool,
                     unfused: bool = False) -> StageRun:
+    from spark_rapids_tpu.batch import colocate_batches
+    # the sources materialised, then handed to the program: colocated,
+    # and the donation mask decided
     with span("stage_inputs", root.name):
         mats = _materialize_sources(sources, ctx)
-    args = tuple(tuple(bs) for bs, _, _ in mats)
-    spec = tuple(sp for _, sp, _ in mats)
-    fused = sum(sp is not None for sp in spec)
-    if fused:
-        ctx.metric("pipeline", "fusedShrinks").add(fused)
-    from spark_rapids_tpu.batch import colocate_batches
-    args = tuple(tuple(bs) for bs in colocate_batches(args))
-    donate = donation_supported() and not _stage_may_rerun(root, ctx)
-    dmask = tuple(bool(donate and d) for _, _, d in mats)
-    if any(dmask):
-        leaves = jax.tree_util.tree_leaves(
-            tuple(a for a, m in zip(args, dmask) if m))
-        if len({id(leaf) for leaf in leaves}) != len(leaves):
-            # a duplicated leaf cannot be donated twice — keep everything
-            dmask = tuple(False for _ in dmask)
-    arity = tuple(len(bs) for bs in args)
+        args = tuple(tuple(bs) for bs, _, _ in mats)
+        spec = tuple(sp for _, sp, _ in mats)
+        fused = sum(sp is not None for sp in spec)
+        if fused:
+            ctx.metric("pipeline", "fusedShrinks").add(fused)
+        args = tuple(tuple(bs) for bs in colocate_batches(args))
+        donate = donation_supported() and not _stage_may_rerun(root, ctx)
+        dmask = tuple(bool(donate and d) for _, _, d in mats)
+        if any(dmask):
+            leaves = jax.tree_util.tree_leaves(
+                tuple(a for a, m in zip(args, dmask) if m))
+            if len({id(leaf) for leaf in leaves}) != len(leaves):
+                # a duplicated leaf cannot be donated twice — keep all
+                dmask = tuple(False for _ in dmask)
+        arity = tuple(len(bs) for bs in args)
 
     def dispatch(v: str) -> StageRun:
-        s2, jitted, noted = _stage_program(root, ctx, v, spec, dmask)
-        assert len(s2) == len(sources), "stage variants disagree"
-        ctx.metric("pipeline", "programs").add(1)
-        dargs = tuple(a for a, m in zip(args, dmask) if m)
-        kargs = tuple(a for a, m in zip(args, dmask) if not m)
-
         def call():
             outs, flags = jitted(dargs, kargs)
             outs = list(outs)
             return (_shrink_outputs(outs, ctx) if shrink else outs), flags
 
-        # the jitted calls inside open their own ``enqueue`` spans and
-        # inherit the stage root as their operator
+        # the program's lookup and its call; the jitted calls inside open
+        # their own ``enqueue`` spans and inherit the stage root as their
+        # operator
         with span("stage", root.name, root.op_id):
+            s2, jitted, noted = _stage_program(root, ctx, v, spec, dmask)
+            assert len(s2) == len(sources), "stage variants disagree"
+            ctx.metric("pipeline", "programs").add(1)
+            dargs = tuple(a for a, m in zip(args, dmask) if m)
+            kargs = tuple(a for a, m in zip(args, dmask) if not m)
             outs, flags = _run_oom_guarded(ctx, call, args,
                                            retryable=not any(dmask))
         return StageRun(root, outs, flags, noted[arity], redo)
@@ -844,15 +848,20 @@ def pipeline_collect(root: PhysicalOp, ctx: ExecContext
             _release_admission(ctx, getattr(ctx, "_pipeline_h2d", 0))
         else:
             ctx._pipeline_h2d = 0
-    frag_key = getattr(ctx, "_history_frag_key", None)
-    if frag_key is not None and getattr(ctx, "logical_plan", None) is not None:
-        # adopt the outputs into the cross-query fragment cache
-        # (history.fragcache) AFTER the D2H landed: registering first
-        # would let budget pressure spill a batch mid-transfer.  Only
-        # this path inserts — its outs are always fresh jitted-program
-        # outputs, never aliases of cached source batches.
-        from spark_rapids_tpu.history.fragcache import fragment_cache
-        fragment_cache().insert(frag_key, ctx.logical_plan, outs, ctx)
+    with span("bookkeeping", "release", root.op_id):
+        frag_key = getattr(ctx, "_history_frag_key", None)
+        if frag_key is not None and \
+                getattr(ctx, "logical_plan", None) is not None:
+            # adopt the outputs into the cross-query fragment cache
+            # (history.fragcache) AFTER the D2H landed: registering first
+            # would let budget pressure spill a batch mid-transfer.  Only
+            # this path inserts — its outs are always fresh jitted-program
+            # outputs, never aliases of cached source batches.
+            from spark_rapids_tpu.history.fragcache import fragment_cache
+            fragment_cache().insert(frag_key, ctx.logical_plan, outs, ctx)
+        # the answer is on the host: the stage's device outputs are let
+        # go here, under the span, not as this frame unwinds
+        del run, outs
     if not hbs:
         from spark_rapids_tpu.plan.physical import _empty_host_col
         return HostBatch(root.output_schema, [

@@ -342,7 +342,14 @@ class TpuSparkSession:
         assignment, so concurrent executes on a shared session never
         expose a half-written dict), but under concurrency only the
         returned dict is guaranteed to describe THIS call — the serving
-        scheduler uses it for per-tenant rollups."""
+        scheduler uses it for per-tenant rollups.
+
+        ``queryWallNs`` (and its partition ``critpath``) is entry to the
+        stamp taken before the obs ring is drained; ``collectWallNs`` is
+        the whole call, and ``unspannedNs`` the part of it that no span
+        covers (``DataFrame.collect`` widens both to its own entry and
+        exit)."""
+        t_call0 = time.monotonic_ns()
         from spark_rapids_tpu.config import (
             FAULTS_SPEC, OBS_ENABLED, OBS_RING_MAX_EVENTS,
         )
@@ -355,13 +362,14 @@ class TpuSparkSession:
         from spark_rapids_tpu.utils import params
         from spark_rapids_tpu.utils.tracing import span
         # the query wall is partitioned from HERE to the stamp taken just
-        # before critpath.compute: the scope (event ring, this query's
+        # before the ring is drained: the scope (event ring, this query's
         # counters and fault registry under concurrent serving) opens
         # first, so planning's span and everything after it land inside
         t_query0 = time.monotonic_ns()
-        obs_token = obs_events.begin_query(
-            enabled=OBS_ENABLED.get(self.conf),
-            max_events=OBS_RING_MAX_EVENTS.get(self.conf))
+        with span("bookkeeping", "scope"):
+            obs_token = obs_events.begin_query(
+                enabled=OBS_ENABLED.get(self.conf),
+                max_events=OBS_RING_MAX_EVENTS.get(self.conf))
         try:
             with span("plan", "physical"):
                 phys, ctx = self._plan_and_context(plan)
@@ -377,13 +385,14 @@ class TpuSparkSession:
         # the finally clears an armed one so persistent @N+ rules cannot
         # outlive the query and fire at sites with no recovery around
         # them (e.g. ml.to_device_batches staging outside execute)
-        spec = FAULTS_SPEC.get(self.conf)
-        fault_inject.install(spec)
-        before = CR.snapshot()
-        fm_before = FM.snapshot()
-        pt_before = pallas_tier.fallback_count()
-        cat_before = dict(self.runtime.catalog.metrics) \
-            if self.runtime is not None else {}
+        with span("bookkeeping", "snapshot"):
+            spec = FAULTS_SPEC.get(self.conf)
+            fault_inject.install(spec)
+            before = CR.snapshot()
+            fm_before = FM.snapshot()
+            pt_before = pallas_tier.fallback_count()
+            cat_before = dict(self.runtime.catalog.metrics) \
+                if self.runtime is not None else {}
         try:
             with params.executing(ctx.bound_params):
                 out = collect_host(phys, ctx)
@@ -403,32 +412,41 @@ class TpuSparkSession:
                 plan, phys, ctx, out, obs_token,
                 (before, fm_before, pt_before, cat_before),
                 bookkeeping.t0 - t_query0)
-        # drain the obs epoch and fold it into a bounded-history profile
-        # (obs.profile); the event counts become metrics so tests and
-        # bench can assert the bus's own economics
-        obs_events_list, obs_dropped, obs_dropped_by_site = \
-            obs_events.end_query(obs_token)
-        frame.last_metrics["obsEventCount"] = len(obs_events_list)
-        frame.last_metrics["obsEventsDropped"] = obs_dropped
         # exact wall decomposition (obs.critpath): the segments partition
         # [t_query0, t_query1) — entry to this stamp — so they sum to
         # queryWallNs EXACTLY
         from spark_rapids_tpu.obs import critpath as obs_critpath
         t_query1 = time.monotonic_ns()
-        cp = obs_critpath.compute(obs_events_list, t_query0, t_query1)
-        frame.last_metrics["critpathAttributedNs"] = cp.attributed_ns
-        frame.last_metrics["critpath"] = dict(cp.segments)
-        frame.last_metrics["queryWallNs"] = t_query1 - t_query0
+        # the ring is closed from here on: the drain, the decomposition
+        # and the profile are timed by a span of the profiler alone
+        with span("bookkeeping", "profile", ring=False) as profile:
+            # drain the obs epoch and fold it into a bounded-history
+            # profile (obs.profile); the event counts become metrics so
+            # tests and bench can assert the bus's own economics
+            obs_events_list, obs_dropped, obs_dropped_by_site = \
+                obs_events.end_query(obs_token)
+            frame.last_metrics["obsEventCount"] = len(obs_events_list)
+            frame.last_metrics["obsEventsDropped"] = obs_dropped
+            cp = obs_critpath.compute(obs_events_list, t_query0, t_query1)
+            frame.last_metrics["critpath"] = dict(cp.segments)
+            frame.last_metrics["queryWallNs"] = t_query1 - t_query0
+            if obs_token is not None and obs_token.bus is not None:
+                self._record_profile(obs_token.query_id, obs_events_list,
+                                     obs_dropped, t_query1 - t_query0,
+                                     frame.last_metrics,
+                                     dropped_by_site=obs_dropped_by_site,
+                                     qt0_ns=t_query0, qt1_ns=t_query1)
+        # the call's wall, and what of it no span covers: the window's
+        # ``wait`` and the head and tail outside the window, less the
+        # profile's span (spanned wall + unspannedNs == collectWallNs)
+        call_ns = time.monotonic_ns() - t_call0
+        frame.last_metrics["collectWallNs"] = call_ns
+        frame.last_metrics["unspannedNs"] = call_ns - profile.elapsed_ns \
+            - (t_query1 - t_query0 - cp.segments.get(obs_critpath.WAIT, 0))
         # publish by one reference assignment: a concurrent reader of
         # self.last_metrics sees the previous complete dict or this one,
         # never a half-filled frame
         self.last_metrics = frame.last_metrics
-        if obs_token is not None and obs_token.bus is not None:
-            self._record_profile(obs_token.query_id, obs_events_list,
-                                 obs_dropped, t_query1 - t_query0,
-                                 frame.last_metrics,
-                                 dropped_by_site=obs_dropped_by_site,
-                                 qt0_ns=t_query0, qt1_ns=t_query1)
         return out, frame.last_metrics
 
     def _query_metrics(self, plan, phys, ctx, out, obs_token, befores,
@@ -483,6 +501,9 @@ class TpuSparkSession:
         frame.last_metrics["compileCount"] = d["compiles"]
         frame.last_metrics["compileWallNs"] = d["compile_wall_ns"]
         frame.last_metrics["dispatchCount"] = d["dispatches"]
+        # the flat argument leaves those dispatches were handed: what
+        # their ``enqueue`` spans pay for, beside the runtime's own work
+        frame.last_metrics["dispatchArgLeaves"] = d["arg_leaves"]
         # the compile wall by phase (jax.monitoring, routed by event
         # name): tracing, MLIR lowering, XLA compiling, persistent-cache
         # loads — disjoint, and together no more than compileWallNs
@@ -494,12 +515,11 @@ class TpuSparkSession:
         frame.last_metrics["compileCacheMisses"] = d["cache_misses"]
         frame.last_metrics["compiledShapes"] = CR.compiled_shapes()
         # data-plane economics: input bytes donated to dispatches (HBM
-        # reused for outputs) and the host<->device staging volume/time
+        # reused for outputs) and the host<->device staging volume (its
+        # wall is critpath's ``pack``, ``h2d`` and ``d2h``)
         frame.last_metrics["donatedBytes"] = d["donated_bytes"]
         frame.last_metrics["h2dBytes"] = d["h2d_bytes"]
-        frame.last_metrics["h2dTimeNs"] = d["h2d_ns"]
         frame.last_metrics["d2hBytes"] = d["d2h_bytes"]
-        frame.last_metrics["d2hTimeNs"] = d["d2h_ns"]
         # shuffle split economics, summed over every exchange op: split
         # programs dispatched, blocking host syncs paid, catalog pieces
         # registered, and the bytes the split moved

@@ -30,10 +30,14 @@ Data-plane accounting rides the same snapshot/delta machinery:
   assertion for tests: once a buffer has been donated, presenting it to
   any later instrumented call (or sync site registered via
   :func:`guard_check`) raises.
-* :func:`record_transfer` accumulates host<->device staging bytes and
-  wall time (``h2d_bytes``/``h2d_ns``/``d2h_bytes``/``d2h_ns``) from the
-  batch staging layer (``last_metrics``' ``h2dBytes`` / ``h2dTimeNs`` /
-  ``d2hBytes`` / ``d2hTimeNs``).
+* :func:`record_transfer` accumulates host<->device staging bytes
+  (``h2d_bytes``/``d2h_bytes``) from the batch staging layer
+  (``last_metrics``' ``h2dBytes`` / ``d2hBytes``); the staging's wall is
+  its ``srt/pack``, ``srt/h2d`` and ``srt/d2h`` spans
+  (``last_metrics["critpath"]``).
+* every real dispatch adds its program's flat argument leaves to
+  ``arg_leaves`` (``dispatchArgLeaves``): what the enqueue is handed.
+  The count is noted when the program is traced, not taken per call.
 
 The compile wall is also split by phase from ``jax.monitoring``'s
 duration events, routed by exact event name (jax 0.9.0), per query scope
@@ -88,10 +92,9 @@ _STATS: Dict[str, int] = {
     "cache_hits": 0,        # persistent-cache hits
     "cache_misses": 0,      # persistent-cache entries written
     "donated_bytes": 0,     # input buffer bytes donated to dispatches
+    "arg_leaves": 0,        # flat argument leaves handed to dispatches
     "h2d_bytes": 0,         # host->device staging bytes
-    "h2d_ns": 0,            # host->device staging wall ns
     "d2h_bytes": 0,         # device->host bulk-copy bytes
-    "d2h_ns": 0,            # device->host bulk-copy wall ns
 }
 #: program name (the sanitised label, what ``XLA Modules`` shows after
 #: ``jit_``) -> {"compiles": n, "<phase>_ns": …, "cache_hits": …}
@@ -141,9 +144,11 @@ def program_name(label: str) -> str:
 
 
 def _record(label: str, compiled: bool, wall_ns: int,
-            donated_bytes: int = 0, bypassed_cache: bool = False) -> None:
+            donated_bytes: int = 0, bypassed_cache: bool = False,
+            arg_leaves: int = 0) -> None:
     with _LOCK:
         _STATS["dispatches"] += 1
+        _STATS["arg_leaves"] += arg_leaves
         _STATS["donated_bytes"] += donated_bytes
         if compiled:
             _STATS["compiles"] += 1
@@ -157,6 +162,7 @@ def _record(label: str, compiled: bool, wall_ns: int,
     sc = _obs_events.current_scope()
     if sc is not None:
         sc.add("dispatches", 1)
+        sc.add("arg_leaves", arg_leaves)
         if donated_bytes:
             sc.add("donated_bytes", donated_bytes)
         if compiled:
@@ -164,16 +170,14 @@ def _record(label: str, compiled: bool, wall_ns: int,
             sc.add("compile_wall_ns", wall_ns)
 
 
-def record_transfer(kind: str, nbytes: int, wall_ns: int) -> None:
-    """Accumulate one host<->device staging pass (kind: "h2d" | "d2h");
-    the caller's ``tracing.span`` around the pass is its timeline entry."""
+def record_transfer(kind: str, nbytes: int) -> None:
+    """Accumulate one host<->device staging pass's bytes (kind: "h2d" |
+    "d2h"); the caller's ``tracing.span`` around the pass is its wall."""
     with _LOCK:
         _STATS[kind + "_bytes"] += int(nbytes)
-        _STATS[kind + "_ns"] += int(wall_ns)
     sc = _obs_events.current_scope()
     if sc is not None:
         sc.add(kind + "_bytes", int(nbytes))
-        sc.add(kind + "_ns", int(wall_ns))
 
 
 # -- use-after-donate guard (tests) ------------------------------------------
@@ -367,12 +371,22 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
     if donate:
         _filter_donation_warning()
 
+    # the flat argument leaves of each trace of this program: noted when
+    # jax traces it, so a dispatch adds its count without a flatten of
+    # its own (a program traced with several counts flattens per call)
+    arg_leaves: set = set()
+
+    def note_leaves(args) -> None:
+        arg_leaves.add(sum(isinstance(leaf, jax.core.Tracer)
+                           for leaf in jax.tree_util.tree_leaves(args)))
+
     # the jitted function carries the program name, so the device
     # timeline's ``XLA Modules`` line reads ``jit_<program>(<hash>)`` and
     # jax.monitoring's ``fun_name`` is the key of per_label_compiles()
     if bound:
         @functools.wraps(fn)
         def named(bound_params, *args, **kwargs):
+            note_leaves((bound_params, args, kwargs))
             with _params.tracing(bound_params):
                 return fn(*args, **kwargs)
         # the hidden argument shifts the caller's positions by one; jax
@@ -389,6 +403,7 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
     else:
         @functools.wraps(fn)
         def named(*args, **kwargs):
+            note_leaves((args, kwargs))
             return fn(*args, **kwargs)
     named.__name__ = named.__qualname__ = program
     jitted = jax.jit(named, **jit_kwargs)
@@ -443,10 +458,18 @@ def instrumented_jit(fn: Optional[Callable] = None, *, label: str = "",
         finally:
             _COMPILE_PHASES.program = prev_program
         _record(program, compiled, sp.elapsed_ns, donated_bytes,
-                bypassed_cache=bool(donate))
+                bypassed_cache=bool(donate),
+                arg_leaves=dispatched_leaves(args, kwargs))
         if donated_leaves:
             _guard_mark(name, donated_leaves)
         return out
+
+    def dispatched_leaves(args, kwargs) -> int:
+        if len(arg_leaves) == 1:
+            return next(iter(arg_leaves))
+        head = (_params.dispatch_args(),) if bound else ()
+        return sum(hasattr(leaf, "shape") for leaf in
+                   jax.tree_util.tree_leaves((head, args, kwargs)))
 
     wrapper.jitted = jitted
     wrapper.label = name

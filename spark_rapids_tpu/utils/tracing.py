@@ -18,7 +18,10 @@ annotation is one flag test; the ring append is what it was.
 
 Sites (``obs.critpath.SITE_PRIORITY`` ranks them): ``device_wait`` — the
 host blocked on the chip (a size read-back, the wait before a D2H copy,
-an exchange's sync); ``h2d`` / ``d2h`` — staging copies; ``enqueue`` —
+an exchange's sync); ``h2d`` / ``d2h`` — staging copies (the
+``device_put`` calls; the start of the copies home and the copy);
+``pack`` — the host's packing of a staged batch into the buffers
+``h2d`` copies; ``enqueue`` —
 ONE span per jitted call (``compile_registry.instrumented_jit``), the
 host wall of an asynchronous enqueue (compile-inclusive on a first
 call), never device time; ``stage`` — a stage program's whole dispatch (enqueue + any size
@@ -31,7 +34,6 @@ of ``session.execute_with_metrics``; ``scan``, ``io``, ``exchange``,
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import threading
 import time
@@ -56,7 +58,8 @@ class span:
     the span to the nanosecond.  Only a ``with`` opens one: the profiler
     range and the thread's current operator are restored however the body
     leaves.  ``ring=False`` keeps a span off the ring: a pool worker's
-    side of an interval the consumer records with :func:`record_span`."""
+    side of an interval the consumer records with :func:`record_span`,
+    and the work of a query after its scope closed."""
 
     __slots__ = ("site", "name", "op", "payload", "t0", "elapsed_ns",
                  "_ann", "_ring", "_outer_op")
@@ -74,6 +77,9 @@ class span:
         self._outer_op = None
 
     def __enter__(self) -> "span":
+        # the interval holds the span's own entry and exit, so adjacent
+        # spans leave no gap of their own making between them
+        self.t0 = time.monotonic_ns()
         self._ann = jax.profiler.TraceAnnotation(
             f"{PREFIX}/{self.site}/{self.name}")
         self._ann.__enter__()
@@ -82,17 +88,16 @@ class span:
             # split programs, a stage's enqueue)
             self._outer_op = getattr(_CURRENT, "op", "")
             _CURRENT.op = self.op
-        self.t0 = time.monotonic_ns()
         return self
 
     def set(self, **payload) -> None:
         self.payload.update(payload)
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.monotonic_ns()
         self._ann.__exit__(None, None, None)
         if self._outer_op is not None:
             _CURRENT.op = self._outer_op
+        t1 = time.monotonic_ns()
         self.elapsed_ns = t1 - self.t0
         if exc_type is not None:
             self.payload["error"] = True
@@ -156,19 +161,11 @@ def device_read(name: str, tree, op: str = ""):
         return jax.device_get(tree)
 
 
-@contextlib.contextmanager
-def trace_range(site: str, name: str, metric=None):
-    """A container range (a partition's drive loop, the whole collect)
-    plus optional elapsed-nanos metric accumulation.  Profiler side
-    only: on the ring it would cover, and so rename, every host gap
-    ``critpath`` reports as ``wait``."""
-    sp = span(site, name, ring=False)
-    try:
-        with sp:
-            yield
-    finally:
-        if metric is not None:
-            metric.add(sp.elapsed_ns)
+def trace_range(site: str, name: str) -> span:
+    """A container range (a partition's drive loop, the whole collect).
+    Profiler side only: on the ring it would cover, and so rename, every
+    host gap ``critpath`` reports as ``wait``."""
+    return span(site, name, ring=False)
 
 
 def start_profile(logdir: str):

@@ -81,22 +81,23 @@ if _PER_TEST_TIMEOUT > 0:
 
 
 # Plan-invariant verification (RAPIDS_PLAN_VERIFY=1 — ci/run_ci.sh turns
-# it on): wrap TpuSparkSession.execute so every plan the suite runs is
-# structurally verified after collection — schema/transition consistency,
+# it on): wrap TpuSparkSession.execute_with_metrics (behind execute and
+# DataFrame.collect) so every plan the suite runs is structurally
+# verified after collection — schema/transition consistency,
 # donation-mask provenance, semaphore balance (analysis/plan_verify.py).
 # Runs on the executed plan objects, so it costs microseconds per query.
 if os.environ.get("RAPIDS_PLAN_VERIFY") == "1":
     from spark_rapids_tpu.analysis import plan_verify as _plan_verify
     from spark_rapids_tpu.session import TpuSparkSession as _TpuSession
 
-    _orig_execute = _TpuSession.execute
+    _orig_execute = _TpuSession.execute_with_metrics
 
     def _verified_execute(self, plan):
         out = _orig_execute(self, plan)
         _plan_verify.verify_session(self)
         return out
 
-    _TpuSession.execute = _verified_execute
+    _TpuSession.execute_with_metrics = _verified_execute
 
 
 @pytest.fixture

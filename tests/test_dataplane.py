@@ -289,11 +289,16 @@ def test_transfer_metrics_reported():
     q = _multi_part_query(s)
     q.collect()
     m = s.last_metrics
-    for key in ("h2dBytes", "h2dTimeNs", "d2hBytes", "d2hTimeNs",
-                "donatedBytes"):
+    for key in ("h2dBytes", "d2hBytes", "donatedBytes"):
         assert key in m, f"last_metrics missing {key}"
     assert m["h2dBytes"] > 0  # fresh (uncached) input staged this query
     assert m["d2hBytes"] > 0  # results came home
+    # the staging's wall is the critical path's, by site: the packing,
+    # the copies up, and the copies home (no host clock of its own)
+    cp = m["critpath"]
+    assert cp.get("pack", 0) > 0 and cp.get("h2d", 0) > 0, cp
+    assert cp.get("d2h", 0) > 0, cp
+    assert "h2dTimeNs" not in m and "d2hTimeNs" not in m
 
 
 # ---------------------------------------------------------------------------

@@ -547,30 +547,109 @@ def test_device_to_host_splits_the_wait_from_the_copy():
         events, _d, _s = obs_events.end_query(scope)
     assert out[0].to_pydict() == {"a": [1, None, 3]}
     order = [(e.site, e.name) for e in events]
-    assert order == [("device_wait", "d2h_ready"), ("d2h", "transfer"),
-                     ("result", "assemble")]
-    assert events[0].t1 <= events[1].t0   # the copy is timed after the wait
+    assert order == [("d2h", "start"), ("device_wait", "d2h_ready"),
+                     ("d2h", "transfer"), ("result", "assemble")]
+    assert events[0].t1 <= events[1].t0   # the copies start, then the wait
+    assert events[1].t1 <= events[2].t0   # the copy is timed after the wait
+
+
+def test_host_to_device_is_packing_then_copying():
+    """One staging is a column at a time, packing then copying: the host
+    packs a column (``pack``), ``device_put`` copies it (``h2d``), and
+    the spans tile the call end to end with nothing in two of them."""
+    import time
+    import jax
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.batch import HostBatch, device_to_host, \
+        host_to_device
+    from spark_rapids_tpu.obs import events as obs_events
+    from spark_rapids_tpu.obs import critpath
+    hb = HostBatch.from_pydict({
+        "a": (T.INT, [1, None, 3] * 100),
+        "s": (T.STRING, ["x", "yy", None] * 100),
+        "d": (T.DOUBLE, [0.5, 1.5, None] * 100)})
+    scope = obs_events.begin_query(enabled=True, max_events=100)
+    try:
+        t0 = time.monotonic_ns()
+        dev = host_to_device(hb)
+        t1 = time.monotonic_ns()
+    finally:
+        events, _d, _s = obs_events.end_query(scope)
+    assert device_to_host(dev).to_pydict() == hb.to_pydict()
+    assert [(e.site, e.name) for e in events] == \
+        [("pack", "column"), ("h2d", "transfer")] * 3 + \
+        [("h2d", "transfer")]                      # the row count
+    assert t0 <= events[0].t0 and events[-1].t1 <= t1
+    assert all(a.t1 <= b.t0 for a, b in zip(events, events[1:]))
+    assert sum(e.payload["bytes"] for e in events if e.site == "h2d") == \
+        sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(dev))
+    cp = critpath.compute(events, events[0].t0, events[-1].t1)
+    for site in ("pack", "h2d"):
+        assert cp.segments[site] == sum(
+            e.t1 - e.t0 for e in events if e.site == site)
+    assert critpath.SITE_PRIORITY.index("pack") == \
+        critpath.SITE_PRIORITY.index("h2d") + 1
 
 
 # -- the query wall, partitioned ----------------------------------------------
 
 
-def _assert_partition(m):
+#: the spans of a call that lie after its query's scope closed, so off
+#: the ring: the drain, decomposition and profile, and the row tuples
+_TAIL_SPANS = {("bookkeeping", "profile"), ("result", "rows")}
+
+
+@pytest.fixture
+def tail_spans(monkeypatch):
+    """Each thread's closed off-ring tail spans, as (site, name, ns)."""
+    kept = threading.local()
+
+    class Kept(tracing.span):
+        __slots__ = ()
+
+        def __exit__(self, *exc):
+            out = super().__exit__(*exc)
+            if (self.site, self.name) in _TAIL_SPANS:
+                kept.__dict__.setdefault("spans", []).append(
+                    (self.site, self.name, self.elapsed_ns))
+            return out
+
+    monkeypatch.setattr(tracing, "span", Kept)
+
+    def take():
+        spans, kept.spans = getattr(kept, "spans", []), []
+        return spans
+    return take
+
+
+def _assert_partition(m, tail):
+    """``critpath`` partitions ``queryWallNs``; the call's wall
+    ``collectWallNs`` is the spanned wall — ``critpath`` less ``wait``,
+    plus the tail's spans — and ``unspannedNs``, to the nanosecond."""
     cp = m["critpath"]
     assert sum(cp.values()) == m["queryWallNs"], cp
-    assert m["critpathAttributedNs"] == m["queryWallNs"] - cp.get("wait", 0)
     for site in ("plan", "device_wait", "d2h", "bookkeeping"):
         assert cp.get(site, 0) > 0, (site, cp)
+    assert ("bookkeeping", "profile") in {(s, n) for s, n, _ns in tail}
+    spanned = m["queryWallNs"] - cp.get("wait", 0) + sum(
+        ns for _s, _n, ns in tail)
+    assert spanned + m["unspannedNs"] == m["collectWallNs"], (m, tail)
+    assert m["queryWallNs"] < m["collectWallNs"]
+    assert cp.get("wait", 0) <= m["unspannedNs"]
 
 
-def test_critpath_partitions_the_query_wall_shuffle_spill_retry():
+def test_critpath_partitions_the_query_wall_shuffle_spill_retry(tail_spans):
+    from spark_rapids_tpu.dataframe import DataFrame
     from spark_rapids_tpu.runtime.device import DeviceRuntime
     DeviceRuntime.reset()
     try:
         s = _shuffle_spill_retry_session()
-        s.execute(_shuffle_spill_retry_plan(s))
+        rows = DataFrame(_shuffle_spill_retry_plan(s), s).collect()
         m = s.last_metrics
-        _assert_partition(m)
+        tail = tail_spans()
+        assert ("result", "rows") in {(s_, n) for s_, n, _ns in tail}
+        _assert_partition(m, tail)
+        assert len(rows) == 8192
         assert m["retryCount"] >= 1
         p = s.query_history()[-1]
         assert p.qt1_ns - p.qt0_ns == m["queryWallNs"] == p.wall_ns
@@ -578,7 +657,8 @@ def test_critpath_partitions_the_query_wall_shuffle_spill_retry():
         DeviceRuntime.reset()
 
 
-def test_critpath_partitions_the_query_wall_under_serve_concurrency():
+def test_critpath_partitions_the_query_wall_under_serve_concurrency(
+        tail_spans):
     from spark_rapids_tpu.serve import ServeScheduler
     s = tpu_session()
     dfs = [s.create_dataframe(
@@ -590,7 +670,7 @@ def test_critpath_partitions_the_query_wall_under_serve_concurrency():
 
     def keep(plan):
         out, m = real(plan)
-        got.append(m)
+        got.append((m, tail_spans()))     # on the executing thread
         return out, m
 
     s.execute_with_metrics = keep
@@ -598,8 +678,69 @@ def test_critpath_partitions_the_query_wall_under_serve_concurrency():
         for f in [sched.submit(df) for df in dfs]:
             f.result(timeout=120)
     assert len(got) == 6
-    for m in got:
-        _assert_partition(m)
+    for m, tail in got:
+        _assert_partition(m, tail)
+
+
+def test_collect_names_the_wall_that_had_no_span():
+    """The host work of a collect that ran under no span is named: the
+    obs scope, the snapshots, the watchdog's start, a stage's variant
+    and judgement, the admission's release, the start of the copies
+    home.  What is left uncovered is a small part of the call."""
+    from spark_rapids_tpu.obs import events as obs_events
+    s = tpu_session(**FLOAT_AGG,
+                    **{"spark.rapids.sql.tpu.partition.timeoutSec": 900.0})
+    df = _q6_shaped(s, n=4096, tag="named")
+    df.collect()
+    df.collect()
+    m = s.last_metrics
+    names = {(e.site, e.name) for e in s.query_history()[-1].events
+             if e.kind == obs_events.SPAN}
+    assert {("bookkeeping", "scope"), ("bookkeeping", "snapshot"),
+            ("fault", "watchdog_start"), ("fault", "watchdog_join"),
+            ("stage_inputs", "variant"), ("bookkeeping", "stands"),
+            ("bookkeeping", "release"), ("d2h", "start")} <= names, names
+    assert m["unspannedNs"] < m["collectWallNs"] / 2
+
+
+def test_dispatch_arg_leaves_are_the_stage_programs_arguments(monkeypatch):
+    """``dispatchArgLeaves`` of a Q6-shaped query over cached batches is
+    the flat argument count of its one stage program — the bound literal
+    values and every leaf of every source batch — and stays level over
+    repeated collects (the count is noted at the trace, not per call)."""
+    import jax
+    from spark_rapids_tpu.plan import pipeline
+    from spark_rapids_tpu.utils import params
+    s = tpu_session(**FLOAT_AGG)
+    n = 4096
+    src = s.create_dataframe({
+        "lv_price": [float(100 + i % 50) for i in range(n)],
+        "lv_discount": [0.01 * (i % 10) for i in range(n)],
+        "lv_quantity": [float(i % 50) for i in range(n)]},
+        num_partitions=3).cache()
+    df = (src.filter((F.col("lv_discount") >= 0.05)
+                     & (F.col("lv_quantity") < 24.0))
+          .agg(F.sum(F.col("lv_price") * F.col("lv_discount"))
+               .alias("revenue")))
+    seen = []
+    real = pipeline._run_oom_guarded
+
+    def guarded(ctx, thunk, args=(), retryable=True):
+        seen.append(len(jax.tree_util.tree_leaves(
+            (params.dispatch_args(), args))))
+        return real(ctx, thunk, args, retryable)
+
+    monkeypatch.setattr(pipeline, "_run_oom_guarded", guarded)
+    df.collect()                       # fills the cache
+    got = []
+    for _ in range(3):
+        seen.clear()
+        df.collect()
+        m = s.last_metrics
+        assert m["dispatchCount"] == 1 and len(seen) == 1, (m, seen)
+        got.append(m["dispatchArgLeaves"])
+    assert got == [seen[0]] * 3
+    assert seen[0] >= 3 * 2        # three DOUBLE columns and their masks
 
 
 def test_failed_plan_closes_its_scope():
@@ -735,6 +876,21 @@ def test_rapidsprof_xplane_on_a_recorded_chip_trace():
     spans = {name for name, _c, _s in r["host_spans"]}
     assert {"srt/plan/physical", "srt/device_wait/d2h_ready",
             "srt/d2h/transfer", "srt/bookkeeping/metrics"} <= spans
+    # an enqueue's host time, by the runtime event it was spent in: the
+    # parts of each span add up to the span
+    parts = {}
+    for span, part, s, n in r["enqueue_parts"]:
+        assert n >= 1
+        parts.setdefault(span, {})[part] = s
+    enqueues = {name: s for name, _c, s in r["host_spans"]
+                if name.startswith(obs_xplane.ENQUEUE)}
+    assert enqueues and set(parts) == set(enqueues)
+    for span, total in enqueues.items():
+        # the enqueueing thread's own parts add up to the span
+        mine = sum(s for part, s in parts[span].items() if ": " not in part)
+        assert mine == pytest.approx(total, rel=1e-9)
+        assert obs_xplane.SELF in parts[span]
+    assert "ParseArguments" in parts["srt/enqueue/stage:TpuHashAggregateExec"]
 
     driver = (
         "import runpy, sys\n"
@@ -751,6 +907,7 @@ def test_rapidsprof_xplane_on_a_recorded_chip_trace():
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "jit_stage_TpuHashAggregateExec" in proc.stdout
     assert "idle gaps by innermost srt/ span" in proc.stdout
+    assert "host time inside srt/enqueue/ spans" in proc.stdout
     assert "jit_run" not in proc.stdout
 
 
@@ -848,7 +1005,12 @@ def test_benchmark_json_gained_only_the_seven_entries():
                                  "join_pairs_per_query",
                                  "join_size_reads_per_query",
                                  # PR 37's, of the Q1 cell alone
-                                 "masked_batches_per_query"]
+                                 "masked_batches_per_query",
+                                 # the host wall by site: four in every
+                                 # cell, the staging's two in parquet's
+                                 "enqueue_ms", "arg_leaves_per_query",
+                                 "unspanned_ms", "d2h_ms", "h2d_ms",
+                                 "h2d_pack_ms"]
     q6_cells = ["tpch_sf1_cached.q6", "tpch_sf1_qgen.q6_text",
                 "tpch_sf1_parquet.q6"]       # those with a keyless aggregate
     for m in bench["per_layer"][first:first + 12]:
@@ -862,9 +1024,14 @@ def test_benchmark_json_gained_only_the_seven_entries():
     for m in bench["per_layer"][first + 17:first + 20]:
         assert m["workloads"] == ["tpch_sf1_join.q12"]
         assert m["moves"] == "rows_per_s"
-    for m in bench["per_layer"][first + 20:]:
+    for m in bench["per_layer"][first + 20:first + 21]:
         assert m["workloads"] == ["tpch_sf1_cached.q1"]
         assert m["moves"] == "rows_per_s"
+    for m in bench["per_layer"][first + 21:]:
+        assert m["moves"] == "rows_per_s" and m["better"] == "lower"
+        assert m.get("workloads", ["tpch_sf1_parquet.q6"]) == \
+            ["tpch_sf1_parquet.q6"]
+        assert ("workloads" in m) == m["name"].startswith("h2d")
     for m in bench["per_layer"][first:]:
         assert os.path.exists(os.path.join(
             REPO_ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
