@@ -3,7 +3,10 @@
 And/Or implement Kleene three-valued logic exactly as Spark does.  String
 equality is evaluated on device via dual 64-bit polynomial hashes plus length
 (config spark.rapids.sql.stringHashGroupJoin.enabled); ordering comparisons on
-strings fall back to CPU.
+strings fall back to CPU.  A string tested for equality with literals (``=``,
+``<>``, ``<=>``, ``IN``) never evaluates a literal on the device: the test is
+``strings.equals_any_literal`` on the other side, decided on its dictionary
+where it arrives encoded.
 """
 
 from __future__ import annotations
@@ -26,6 +29,28 @@ def _string_eq_dev(a: DevVal, b: DevVal):
     return (ha1 == hb1) & (ha2 == hb2) & (la == lb)
 
 
+def _baked_literal(e: Expression) -> bool:
+    """``e`` is a literal compiled into the program as a constant (no
+    ``slot``) that is NULL or a string: its value is known when the
+    program is traced."""
+    return isinstance(e, Literal) and e.slot is None and (
+        e.value is None or e.dtype.is_string)
+
+
+def _equals_string_literal(left: Expression, right: Expression, ctx):
+    """``(value, eq)`` where one side is a non-NULL baked string literal:
+    the other side evaluated, kept encoded where it is a bare column, and
+    ``eq`` its rows' equality with the literal
+    (``strings.equals_any_literal``).  None where neither side is one."""
+    from spark_rapids_tpu.exprs.base import eval_maybe_encoded
+    from spark_rapids_tpu.exprs.strings import equals_any_literal
+    for value, lit in ((left, right), (right, left)):
+        if _baked_literal(lit) and lit.value is not None:
+            v = eval_maybe_encoded(value, ctx)
+            return v, equals_any_literal(v, (str(lit.value),))
+    return None
+
+
 class _Comparison(BinaryExpression):
     def _resolve_type(self):
         self.dtype = T.BOOLEAN
@@ -45,6 +70,10 @@ class _Comparison(BinaryExpression):
 
     def tpu_eval(self, ctx) -> DevVal:
         if self.left.dtype.is_string and self._supports_string():
+            tested = _equals_string_literal(self.left, self.right, ctx)
+            if tested is not None:
+                v, eq = tested
+                return DevVal(T.BOOLEAN, self._from_string_eq(eq), v.validity)
             # Hash-based equality works directly on dictionary-encoded
             # columns — keep the encoding so the dictionary is hashed once.
             from spark_rapids_tpu.exprs.base import eval_maybe_encoded
@@ -53,7 +82,7 @@ class _Comparison(BinaryExpression):
         else:
             lv, rv = self.left.tpu_eval(ctx), self.right.tpu_eval(ctx)
         if lv.dtype.is_string:
-            data = self._compute_string_dev(lv, rv)
+            data = self._from_string_eq(_string_eq_dev(lv, rv))
             return DevVal(T.BOOLEAN, data, lv.validity & rv.validity)
         a, b, _ = promote_dev(lv, rv)
         return DevVal(T.BOOLEAN, self._compute(a.data, b.data),
@@ -72,7 +101,8 @@ class _Comparison(BinaryExpression):
                                             dtype=np.bool_),
                       a.validity & b.validity)
 
-    def _compute_string_dev(self, a: DevVal, b: DevVal):
+    def _from_string_eq(self, eq):
+        """The comparison's answer from the rows' string equality."""
         raise NotImplementedError
 
 
@@ -83,8 +113,8 @@ class Equals(_Comparison):
     def _compute(self, x, y):
         return x == y
 
-    def _compute_string_dev(self, a, b):
-        return _string_eq_dev(a, b)
+    def _from_string_eq(self, eq):
+        return eq
 
 
 class NotEquals(_Comparison):
@@ -94,8 +124,8 @@ class NotEquals(_Comparison):
     def _compute(self, x, y):
         return x != y
 
-    def _compute_string_dev(self, a, b):
-        return ~_string_eq_dev(a, b)
+    def _from_string_eq(self, eq):
+        return ~eq
 
 
 class LessThan(_Comparison):
@@ -130,6 +160,12 @@ class EqualNullSafe(BinaryExpression):
 
     def tpu_eval(self, ctx) -> DevVal:
         if self.left.dtype.is_string:
+            tested = _equals_string_literal(self.left, self.right, ctx)
+            if tested is not None:
+                # the literal is never NULL: a NULL row reads false
+                v, eq = tested
+                return DevVal(T.BOOLEAN, eq & v.validity,
+                              jnp.ones_like(v.validity, dtype=jnp.bool_))
             from spark_rapids_tpu.exprs.base import eval_maybe_encoded
             lv = eval_maybe_encoded(self.left, ctx)
             rv = eval_maybe_encoded(self.right, ctx)
@@ -218,7 +254,11 @@ class Not(UnaryExpression):
 
 
 class In(Expression):
-    """value IN (literals...) — OR of equality tests (GpuInSet analogue)."""
+    """value IN (literals...) — OR of equality tests (GpuInSet analogue).
+
+    A string value whose options are all baked literals is ONE set test
+    (``strings.equals_any_literal``) on the value evaluated once; a NULL
+    option leaves a row that matches nothing NULL, as Spark's answer is."""
 
     def __init__(self, value: Expression, options):
         opts = tuple(o if isinstance(o, Expression) else Literal(o) for o in options)
@@ -237,6 +277,16 @@ class In(Expression):
         return expr
 
     def tpu_eval(self, ctx):
+        value, opts = self.children[0], self.children[1:]
+        if value.dtype.is_string and all(_baked_literal(o) for o in opts):
+            from spark_rapids_tpu.exprs.base import eval_maybe_encoded
+            from spark_rapids_tpu.exprs.strings import equals_any_literal
+            lits = tuple(str(o.value) for o in opts if o.value is not None)
+            v = eval_maybe_encoded(value, ctx)
+            hit = equals_any_literal(v, lits)
+            if len(lits) < len(opts):
+                return DevVal(T.BOOLEAN, hit, v.validity & hit)
+            return DevVal(T.BOOLEAN, hit, v.validity)
         return self._as_or().tpu_eval(ctx)
 
     def cpu_eval(self, ctx):

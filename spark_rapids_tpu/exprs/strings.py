@@ -145,6 +145,61 @@ def hash_literal2(s: str) -> Tuple[int, int]:
     return out[0], out[1]
 
 
+class _DictLiteralCompares:
+    """The stage-notes entry (``plan/pipeline.note_stage_batches``) of a
+    test against literals that :func:`equals_any_literal` decided on a
+    column's dictionary: every dispatch that stands counts it again
+    (``dictLiteralCompares``)."""
+
+    name = "dictLiteralCompares"
+
+    def stage_ran(self, ctx, batches: int, speculated: bool) -> None:
+        ctx.metric("exprs", self.name).add(batches)
+
+
+DICT_LITERAL_COMPARES = _DictLiteralCompares()
+
+
+@kernel_scope
+def equals_any_literal(v: DevVal, lits: Tuple[str, ...]):
+    """bool[cap]: the row of ``v`` equals one of the Python strings
+    ``lits``, by its bytes; the row's validity is the caller's.  No literal
+    is evaluated on the device.
+
+    A dictionary-encoded ``v`` is decided on its ENTRIES: each is compared
+    byte by byte with each literal's UTF-8 bytes (O(entries x literal
+    bytes)), and the answer is gathered through the codes — no row's bytes
+    are read and nothing is hashed.  A plain ``v`` is hashed once
+    (:func:`string_hash2`) and its hashes and lengths are compared with
+    :func:`hash_literal2`'s, computed on the host: the test a literal
+    broadcast to the batch would get, without the broadcast."""
+    lits = tuple(dict.fromkeys(lits))
+    if v.codes is not None:
+        from spark_rapids_tpu.plan.pipeline import note_stage_batches
+        starts = v.offsets[:-1].astype(jnp.int32)
+        ent_lens = (v.offsets[1:] - starts).astype(jnp.int32)
+        nbytes = int(v.data.shape[0])
+        hit = jnp.zeros(ent_lens.shape[0], dtype=jnp.bool_)
+        for raw in (s.encode("utf-8") for s in lits):
+            m = ent_lens == len(raw)
+            if nbytes:      # else every entry is empty: the lengths decide
+                for k, b in enumerate(raw):
+                    idx = jnp.clip(starts + k, 0, nbytes - 1)
+                    m = m & (v.data[idx] == jnp.uint8(b))
+            hit = hit | m
+        codes_c = jnp.clip(v.codes, 0, max(int(hit.shape[0]) - 1, 0))
+        note_stage_batches(DICT_LITERAL_COMPARES, 1)
+        return hit[codes_c]
+    h1, h2 = string_hash2(v)
+    lens = string_lengths(v)
+    out = jnp.zeros(v.capacity, dtype=jnp.bool_)
+    for s in lits:
+        e1, e2 = hash_literal2(s)
+        out = out | ((h1 == jnp.uint32(e1)) & (h2 == jnp.uint32(e2))
+                     & (lens == len(s.encode("utf-8"))))
+    return out
+
+
 @kernel_scope
 def build_string(dtype, new_lens, src_index_fn, out_byte_cap: int,
                  validity) -> DevVal:
@@ -601,7 +656,7 @@ class Like(Expression):
         plan = self._plan()
         kind = plan[0]
         if kind in ("any", "exact"):
-            # Hash/length-only tests work on dictionary-encoded input.
+            # Length and literal tests work on dictionary-encoded input.
             from spark_rapids_tpu.exprs.base import eval_maybe_encoded
             v = eval_maybe_encoded(self.children[0], ctx)
         else:
@@ -610,9 +665,7 @@ class Like(Expression):
         if kind == "any":
             data = jnp.ones(v.capacity, dtype=jnp.bool_)
         elif kind == "exact":
-            h1, h2 = string_hash2(v)
-            e1, e2 = hash_literal2(plan[1])
-            data = (h1 == jnp.uint32(e1)) & (h2 == jnp.uint32(e2))
+            data = equals_any_literal(v, (plan[1],))
         elif kind == "prefix":
             data = _match_prefix(v, plan[1].encode())
         elif kind == "suffix":

@@ -589,12 +589,14 @@ class TpuSparkSession:
         # handed a keyed update's slot contraction as a row mask instead;
         # and what the equi-joins read back to size their outputs: the
         # candidate pairs, the number of such reads, and a side's rows
-        # where the host held them already (it concatenated the side)
+        # where the host held them already (it concatenated the side); and
+        # the string tests against literals decided on a column's dictionary
+        # (exprs/strings.equals_any_literal)
         for key in ("mxuAggBatches", "keyedUpdateBatches",
                     "keylessAggBatches", "keylessUpdateBatches",
                     "filterCompactedBatches", "filterMaskedBatches",
                     "joinPairs", "joinSizeReads",
-                    "joinProbeRows", "joinBuildRows"):
+                    "joinProbeRows", "joinBuildRows", "dictLiteralCompares"):
             frame.last_metrics[key] = _scan_sum(key)
         frame.last_metrics["scanDecodeWallNs"] = _scan_sum("scanDecodeWallNs")
         frame.last_metrics["scanH2dOverlapNs"] = _scan_sum("scanH2dOverlapNs")

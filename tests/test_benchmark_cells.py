@@ -63,6 +63,7 @@ def test_new_cell_is_correct_and_drives_what_it_measures(
             assert c["keylessAggBatches"] > 0
             assert c["filterCompactedBatches"] == 0
             assert c["keyedUpdateBatches"] == 0
+            assert c["dictLiteralCompares"] == 0    # no string literal
         else:
             # cached tables decode nothing; the two string keys arrive
             # dictionary-encoded, so every update batch groups on the slot
@@ -74,6 +75,7 @@ def test_new_cell_is_correct_and_drives_what_it_measures(
             assert c["filterMaskedBatches"] == c["keyedUpdateBatches"]
             assert c["filterCompactedBatches"] == 0
             assert c["keylessUpdateBatches"] == 0
+            assert c["dictLiteralCompares"] == 0    # a date filter
     if cell == "tpch_sf1_parquet.q6":
         assert 100 < read("scan_bytes_per_row") < 200   # all 16 columns
         assert 0 <= read("scan_overlap_pct") <= 100
@@ -113,6 +115,8 @@ def test_join_cell_is_correct_and_drives_what_it_measures(
         assert (c["pushedJoinFilters"], c["joinKeysFromWhere"]) == (5, 1)
         assert c["joinPairs"] == joined > 0
         assert c["filterCompactedBatches"] >= 1  # lineitem, under the join
+        # l_shipmode IN ('MAIL', 'SHIP') on each lineitem batch's dictionary
+        assert c["dictLiteralCompares"] == c["filterCompactedBatches"]
         assert c["keyedUpdateBatches"] >= 1      # l_shipmode, after the join
         assert c["keylessUpdateBatches"] == 0
         assert sum(len(row) for row in r["rows"]) == 3 * len(r["rows"])
@@ -124,6 +128,8 @@ def test_join_cell_is_correct_and_drives_what_it_measures(
     assert read("join_pairs_per_query") == joined
     assert read("join_size_reads_per_query") == 3   # pairs + two string columns
     assert read("compacted_batches_per_query") >= 1
+    assert read("dict_literal_compares_per_query") == \
+        read("compacted_batches_per_query")
     assert read("keyed_contraction_pct") is not None
     assert read("keyless_reduce_pct") is None
     rows = run["queries"]["q12"].scanned_rows(run["rows"])

@@ -1010,7 +1010,10 @@ def test_benchmark_json_gained_only_the_seven_entries():
                                  # cell, the staging's two in parquet's
                                  "enqueue_ms", "arg_leaves_per_query",
                                  "unspanned_ms", "d2h_ms", "h2d_ms",
-                                 "h2d_pack_ms"]
+                                 "h2d_pack_ms",
+                                 # the string tests decided on a
+                                 # dictionary, of the join cell alone
+                                 "dict_literal_compares_per_query"]
     q6_cells = ["tpch_sf1_cached.q6", "tpch_sf1_qgen.q6_text",
                 "tpch_sf1_parquet.q6"]       # those with a keyless aggregate
     for m in bench["per_layer"][first:first + 12]:
@@ -1027,11 +1030,14 @@ def test_benchmark_json_gained_only_the_seven_entries():
     for m in bench["per_layer"][first + 20:first + 21]:
         assert m["workloads"] == ["tpch_sf1_cached.q1"]
         assert m["moves"] == "rows_per_s"
-    for m in bench["per_layer"][first + 21:]:
+    for m in bench["per_layer"][first + 21:first + 27]:
         assert m["moves"] == "rows_per_s" and m["better"] == "lower"
         assert m.get("workloads", ["tpch_sf1_parquet.q6"]) == \
             ["tpch_sf1_parquet.q6"]
         assert ("workloads" in m) == m["name"].startswith("h2d")
+    for m in bench["per_layer"][first + 27:]:
+        assert m["workloads"] == ["tpch_sf1_join.q12"]
+        assert m["moves"] == "rows_per_s" and m["better"] == "higher"
     for m in bench["per_layer"][first:]:
         assert os.path.exists(os.path.join(
             REPO_ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
